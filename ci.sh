@@ -6,7 +6,7 @@ set -euo pipefail
 cd "$(dirname "$0")"
 
 echo "== lint: compileall =="
-python -m compileall -q synapseml_tpu tests bench.py __graft_entry__.py
+python -m compileall -q synapseml_tpu tests tools bench.py chip_smoke.py __graft_entry__.py
 
 echo "== lint: AST audit (undefined names / unused imports / import cycles) =="
 python tools/lint.py

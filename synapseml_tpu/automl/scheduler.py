@@ -550,11 +550,18 @@ class GangCandidatePool:
     Entries must be importable (``"pkg.mod:fn"``) — arbitrary closures do
     not cross process boundaries, which is why tune.py defaults to the
     in-process pool and the gang path is opt-in.
+
+    Gang workers are CPU workers: ``platform`` is exported to every worker
+    as ``JAX_PLATFORMS``, whatever the parent's environment says, because a
+    chip belongs to one process and ``world_size`` workers cannot share it.
+    The folds train where ``self.platform`` says; pass another platform only
+    when every worker has a device of its own.
     """
 
     def __init__(self, world_size: int = 2, spool_dir: Optional[str] = None,
                  max_respawns: int = 2, hb_timeout: float = 5.0,
-                 poll: float = 0.05, env: Optional[Dict[str, str]] = None):
+                 poll: float = 0.05, env: Optional[Dict[str, str]] = None,
+                 platform: str = "cpu"):
         import os
         import subprocess
         import sys
@@ -567,11 +574,12 @@ class GangCandidatePool:
         self.poll = float(poll)
         self._ids = 0
         self._lock = threading.Lock()
+        self.platform = platform
         self._env = dict(env or {})
 
         def _spawn(rank: int, world: int, attempt: int):
             e = dict(os.environ)
-            e.setdefault("JAX_PLATFORMS", "cpu")
+            e["JAX_PLATFORMS"] = self.platform
             e.update(self._env)
             # pre-beat from the parent: a missing heartbeat file reads as
             # stale, so without this a freshly-spawned (still importing)

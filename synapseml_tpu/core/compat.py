@@ -1,16 +1,11 @@
-"""Version-compat shims for the jax API surface.
+"""Small shims over the jax API surface the framework shares.
 
-jax promoted ``jax.experimental.shard_map.shard_map`` to ``jax.shard_map``
-and renamed its ``check_rep`` kwarg to ``check_vma``; the framework targets
-the new spelling everywhere. On a jax that predates the promotion this module
-maps the call back onto the experimental implementation so the whole
-distributed path (collectives, ring attention, VW sync passes, GBDT voting)
-still runs instead of collapsing with ``AttributeError`` at import/trace time.
+``shard_map`` is ``jax.shard_map`` (``check_vma`` spelling); the alias keeps
+one import site for the distributed path (collectives, ring attention, VW
+sync passes, GBDT voting).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 
@@ -30,16 +25,4 @@ def donate_argnums_if_supported(*argnums):
     return tuple(argnums)
 
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import (
-        shard_map as _experimental_shard_map,
-    )
-
-    def shard_map(f=None, **kw):
-        if f is None:  # decorator/partial form: shard_map(mesh=..., ...)
-            return functools.partial(shard_map, **kw)
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _experimental_shard_map(f, **kw)
+shard_map = jax.shard_map

@@ -274,12 +274,9 @@ class BucketedRunner:
             raise ValueError("warmup needs one template array per fn "
                              "argument (trailing dims + dtype)")
         if persistent_cache:
-            try:
-                from .compile_cache import enable_compile_cache
+            from .compile_cache import enable_compile_cache
 
-                enable_compile_cache()
-            except Exception:
-                pass   # cache dir unwritable etc. — warmup still compiles
+            enable_compile_cache()
         specs = tuple(self._spec_of(t) for t in templates)
         for bucket in self.buckets:
             self._executable(bucket, specs, warmup=True)

@@ -199,8 +199,8 @@ DEFAULT_LINK_BYTES_PER_S = {"ici": 1.0e11, "dcn": 1.25e10}
 # the model so the estimate is auditable against data.
 DEFAULT_SELECTION_FRACTION = 0.3
 # fallback engine throughput anchor (row-iters/sec/chip) when
-# docs/measurements.json is unreadable — the BENCH_r03 capture. Conservative:
-# a faster engine shrinks selection cost and favors voting.
+# docs/measurements.json is unreadable — its 2026-07-31 on-chip entry.
+# Conservative: a faster engine shrinks selection cost and favors voting.
 DEFAULT_ENGINE_ROW_ITERS_PER_S = 1.69e6
 
 #: effective wire bytes per histogram element for each

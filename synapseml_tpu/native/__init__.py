@@ -4,8 +4,9 @@ The NativeLoader analog (reference: core/.../core/env/NativeLoader.java
 extracts .so files from the jar and System.load()s them per executor;
 lightgbm/.../LightGBMUtils.scala:31-34). Here: the .so is compiled from
 src/synapseml_native.cpp on first use when a compiler is present (wheel builds
-ship it prebuilt), loaded via ctypes, and every binding has a pure-Python
-fallback — ``available()`` says which path is active.
+ship it prebuilt), rebuilt whenever it does not match that source, loaded via
+ctypes, and every binding has a pure-Python fallback — ``available()`` says
+which path is active.
 
 Bindings:
   murmur3_32_batch(names, seed(s), vw_numeric_names, mask) -> uint32[n]
@@ -15,6 +16,7 @@ Bindings:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,23 +25,50 @@ from typing import Optional, Sequence, Union
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC = os.path.join(_DIR, "src", "synapseml_native.cpp")
 _SO = os.path.join(_DIR, "libsynapseml_native.so")
+# sha256 of the source the .so was built from (the Makefile writes it too):
+# git ignores the .so, so one found on disk may predate the checked-out source
+_STAMP = _SO + ".srchash"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    src = os.path.join(_DIR, "src", "synapseml_native.cpp")
-    if not os.path.exists(src):
+def _source_hash() -> Optional[str]:
+    try:
+        with open(_SRC, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()
+    except OSError:
+        return None
+
+
+def _built_from(src_hash: str) -> bool:
+    try:
+        with open(_STAMP) as f:
+            return os.path.exists(_SO) and f.read().strip() == src_hash
+    except OSError:
         return False
+
+
+def _build(src_hash: str) -> bool:
+    """Compile to a private name, then rename: concurrent processes never
+    load a half-written library."""
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-o", _SO, src],
+            ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
+        with open(f"{_STAMP}.{os.getpid()}.tmp", "w") as f:
+            f.write(src_hash + "\n")
+        os.replace(f.name, _STAMP)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -78,18 +107,16 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) and not _build():
-            return None
+        src_hash = _source_hash()
+        if src_hash is None:
+            if not os.path.exists(_SO):
+                return None        # neither source nor library shipped
+        elif not _built_from(src_hash) and not _build(src_hash):
+            return None            # stale or missing, and no compiler
         try:
             lib = ctypes.CDLL(_SO)
         except OSError:
             return None
-        if not hasattr(lib, "csv_dims") and _build():
-            # stale .so predating the CSV reader: rebuilt above; reload
-            try:
-                lib = ctypes.CDLL(_SO)
-            except OSError:
-                pass  # keep the old lib — CSV falls back to numpy
         _lib = _bind(lib)
         return _lib
 

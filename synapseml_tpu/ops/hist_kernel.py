@@ -19,9 +19,11 @@ accumulation), which matches the precision story of LightGBM's GPU float
 histograms.
 
 Numerically the result equals a scatter-add with bf16-rounded grad/hess. The
-XLA fallback (`_hist_xla`) — used on CPU (tests' virtual mesh) and any
+XLA reference (`_hist_xla`) — used on CPU (tests' virtual mesh) and any
 non-TPU backend — applies the same bf16 rounding so both paths agree bit-wise
-in the accumulated sums up to f32 reduction order.
+in the accumulated sums up to f32 reduction order. On the TPU backend every
+kernel is checked against it once per process and a failure raises
+:class:`KernelError`; nothing falls back.
 """
 
 from __future__ import annotations
@@ -37,24 +39,64 @@ FEATURE_BLOCK = 8     # features per kernel step (i32 sublane tile)
 LANE = 128
 
 
-def _eager_selftest(fn):
-    """Escape any ambient trace for the duration of a selftest.
+class KernelError(RuntimeError):
+    """A Pallas kernel failed to compile or run, or disagreed with its XLA
+    reference, on the TPU backend. There is no fallback: a broken kernel
+    must stop the job, not show up as a slow one."""
 
-    Selftests compile+run tiny on-device programs and compare results as
+
+def check_kernel(kernel: str, shapes: dict, run, reference,
+                 rtol: float, atol: float) -> None:
+    """Run ``run()`` (the kernel) and ``reference()`` once and compare their
+    array leaves. Any failure raises :class:`KernelError` naming the kernel,
+    its static shapes and the compiler's own message."""
+    import numpy as _np
+
+    what = (f"Pallas kernel {kernel} ("
+            + ", ".join(f"{k}={v}" for k, v in shapes.items()) + ")")
+    try:
+        got = [_np.asarray(x) for x in jax.tree.leaves(run())]
+    except Exception as e:   # re-raised with the kernel named
+        raise KernelError(
+            f"{what} failed to compile or run on backend "
+            f"{jax.default_backend()!r}: {type(e).__name__}: {e}") from e
+    # the TPU's default f32 matmul is a bf16 pass: the reference is the
+    # full-precision answer, and the tolerance is the kernel's alone
+    with jax.default_matmul_precision("highest"):
+        want = [_np.asarray(x) for x in jax.tree.leaves(reference())]
+    for i, (g, w) in enumerate(zip(got, want)):
+        if g.shape != w.shape or not _np.allclose(g, w, rtol=rtol,
+                                                  atol=atol):
+            err = (float(_np.max(_np.abs(g - w))) if g.shape == w.shape
+                   else f"shape {g.shape} vs {w.shape}")
+            raise KernelError(
+                f"{what} disagrees with its XLA reference on backend "
+                f"{jax.default_backend()!r}: output {i} max |diff| = {err} "
+                f"(rtol={rtol}, atol={atol})")
+
+
+def _eager_selftest(fn):
+    """Run a kernel check outside any ambient trace: in a thread of its own.
+
+    The checks compile+run small on-device programs and compare results as
     numpy — but their FIRST call can happen during an outer jit trace
     (``child_histogram`` is reached while the grower's ``lax.switch``
-    branches trace). Under an active trace every jnp op — even on fresh
-    concrete arrays — produces tracers of that trace, so ``np.asarray``
-    raises TracerArrayConversionError (observed on-chip 2026-08-02: the
-    bench's first ``train_booster`` trace died here, and
-    ``_tpu_segmented_ok`` silently mis-cached False, degrading the
-    segmented kernel). ``ensure_compile_time_eval`` runs the body eagerly
-    regardless of tracing context; ``functools.cache`` stays outermost so
-    the certified mode is computed once per process."""
+    branches trace), where every jnp op produces tracers and ``np.asarray``
+    raises TracerArrayConversionError (observed on-chip 2026-08-02). jax's
+    trace stack and config contexts are thread-local, so a fresh thread is
+    exactly a top-level call. ``ensure_compile_time_eval`` is NOT enough: it
+    keeps the ambient trace and folds constants eagerly, and a Pallas index
+    map traced under it captures those constants, which the TPU lowering
+    refuses ("Index map function ... must not capture constants", v5e,
+    PR 21). ``functools.cache`` stays
+    outermost so a kernel is checked once per process (a raised KernelError
+    is not cached)."""
     @functools.wraps(fn)
     def wrapper(*a, **k):
-        with jax.ensure_compile_time_eval():
-            return fn(*a, **k)
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            return pool.submit(fn, *a, **k).result()
     return wrapper
 
 
@@ -314,7 +356,7 @@ def _level_kernel(starts_ref, bin_ref, g_ref, h_ref, m_ref, out_ref, *,
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    _packed_accumulate(bin_ref, out_ref.at[0], g_ref[:], h_ref[:], m_ref[:],
+    _packed_accumulate(bin_ref, out_ref, g_ref[:], h_ref[:], m_ref[:],
                        C=C, K1=K1, FB=FB, PACK=PACK)
 
 
@@ -358,7 +400,10 @@ def _hist_pallas_level(bT, g, h, m, start_chunks, num_bins_padded: int,
             pl.BlockSpec((C,), lambda f, c, st: (c,)),
             pl.BlockSpec((C,), lambda f, c, st: (c,)),
         ],
-        out_specs=pl.BlockSpec((1, FB, K1, 24),
+        # slot dimension squeezed: the kernel sees the same (FB, K1, 24)
+        # block as _kernel/_range_kernel (a `.at[0]` view of a (1, FB, K1,
+        # 24) block is a 24-wide minor-dimension slice Mosaic refuses)
+        out_specs=pl.BlockSpec((None, FB, K1, 24),
                                lambda f, c, st: (slot_of(c, st), f, 0, 0)),
     )
     out = pl.pallas_call(
@@ -373,7 +418,7 @@ def _hist_pallas_level(bT, g, h, m, start_chunks, num_bins_padded: int,
 
 def _hist_level_xla(bT, g, h, m, slot_of_row, num_bins_padded: int,
                     slots: int):
-    """Scatter fallback of :func:`_hist_pallas_level` (CPU/tests): one
+    """Scatter reference of :func:`_hist_pallas_level` (CPU/tests): one
     scatter-add into (SLOTS, FP, B, 3) keyed by each row's slot."""
     FP, n = bT.shape
     vals = jnp.stack([g, h, m], -1).astype(jnp.bfloat16).astype(jnp.float32)
@@ -385,52 +430,31 @@ def _hist_level_xla(bT, g, h, m, slot_of_row, num_bins_padded: int,
 
 @functools.cache
 @_eager_selftest
-def _tpu_level_ok(num_bins_padded: int, slots: int, pack=None) -> bool:
-    """On-device check of the multi-leaf level kernel (same insurance
-    contract as _tpu_segmented_ok): False (or SYNAPSEML_TPU_LEVEL=0)
-    degrades depthwise growth to the slot-keyed scatter fallback."""
-    import numpy as _np
-
-    try:
-        C = default_chunk()
-        caps = [2, 1, 3] + [1] * max(slots - 3, 0)
-        caps = caps[:slots]
-        total = sum(caps) * C
-        rng = _np.random.default_rng(2)
-        bT = _np.zeros((8, total), _np.int32)
-        g = _np.zeros(total, _np.float32)
-        h = _np.zeros(total, _np.float32)
-        m = _np.zeros(total, _np.float32)
-        starts, slot_row = [], _np.zeros(total, _np.int32)
-        off = 0
-        for i, cap in enumerate(caps):
-            starts.append(off // C)
-            ln = cap * C - 37 if cap else 0
-            bT[:, off:off + ln] = rng.integers(
-                0, num_bins_padded, size=(8, ln))
-            g[off:off + ln] = rng.normal(size=ln)
-            h[off:off + ln] = rng.uniform(0.5, 2.0, size=ln)
-            m[off:off + ln] = 1.0
-            slot_row[off:off + cap * C] = i
-            off += cap * C
-        got = _np.asarray(_hist_pallas_level(
-            jnp.asarray(bT), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
-            jnp.asarray(starts, jnp.int32), num_bins_padded, slots,
-            pack=pack))
-        want = _np.asarray(_hist_level_xla(
-            jnp.asarray(bT), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
-            jnp.asarray(slot_row), num_bins_padded, slots))
-        return bool(_np.allclose(got[:3], want[:3], rtol=1e-4, atol=1e-3))
-    except Exception:
-        return False
+def _check_level_kernel(num_bins_padded: int, slots: int) -> None:
+    """On-device check of the multi-leaf level kernel against the
+    slot-keyed scatter, at the production chunk; raises KernelError."""
+    C = default_chunk()
+    caps = ([2, 1, 3] + [1] * max(slots - 3, 0))[:slots]
+    bT, g, h, m, starts, slot_row = _level_check_inputs(
+        2, num_bins_padded, caps, C)
+    check_kernel(
+        "_hist_pallas_level",
+        dict(num_bins_padded=num_bins_padded, slots=slots, chunk=C,
+             feature_block=FEATURE_BLOCK, rows=sum(caps) * C),
+        lambda: _hist_pallas_level(bT, g, h, m, starts, num_bins_padded,
+                                   slots),
+        lambda: _hist_level_xla(bT, g, h, m, slot_row, num_bins_padded,
+                                slots),
+        rtol=1e-4, atol=1e-3)
 
 
 def level_histograms(bT, g, h, m, start_chunks, slot_of_row,
                      num_bins_padded: int, slots: int):
     """(SLOTS, FP, B, 3) histograms of slot-partitioned rows in ONE pass:
     the multi-leaf Pallas kernel on TPU (chunk-aligned slots required;
-    tail padding rows must carry zero g/h/m), the slot-keyed scatter
-    fallback elsewhere.
+    tail padding rows must carry zero g/h/m), the slot-keyed scatter on
+    other backends or when SYNAPSEML_TPU_LEVEL=0 asks for it. A kernel
+    that fails its check on TPU raises KernelError.
 
     CONTRACT (Pallas path; ADVICE r3): ``start_chunks`` must be strictly
     ascending with every slot owning >= 1 chunk of capacity — the kernel
@@ -439,20 +463,17 @@ def level_histograms(bT, g, h, m, start_chunks, slot_of_row,
     returns uninitialized VMEM garbage. Callers must mask outputs by their
     own shard-uniform existence vector (grower_depthwise does: its
     ``cap_chunks`` floors every live slot at 1 and ``exists`` masks the
-    gains). The XLA fallback has no such constraint."""
-    mode = (_tpu_kernel_selftest(num_bins_padded)
-            if jax.default_backend() == "tpu" else "xla")
-    pk = 1 if mode == "pack1" else None
-    if (mode != "xla"
-            and os.environ.get("SYNAPSEML_TPU_LEVEL", "1") != "0"
-            and _tpu_level_ok(num_bins_padded, slots, pk)):
+    gains). The XLA scatter has no such constraint."""
+    if (jax.default_backend() == "tpu"
+            and os.environ.get("SYNAPSEML_TPU_LEVEL", "1") != "0"):
+        _check_level_kernel(num_bins_padded, slots)
         return _hist_pallas_level(bT, g, h, m, start_chunks,
-                                  num_bins_padded, slots, pack=pk)
+                                  num_bins_padded, slots)
     return _hist_level_xla(bT, g, h, m, slot_of_row, num_bins_padded, slots)
 
 
 def _hist_xla(bT, g, h, m, num_bins_padded: int):
-    """Scatter-add fallback with the same bf16 value rounding as the kernel."""
+    """Scatter-add reference with the same bf16 value rounding as the kernel."""
     FP, n = bT.shape
     vals = jnp.stack([g, h, m], -1).astype(jnp.bfloat16).astype(jnp.float32)
     hist = jnp.zeros((FP, num_bins_padded, 3), jnp.float32)
@@ -461,76 +482,93 @@ def _hist_xla(bT, g, h, m, num_bins_padded: int):
         vals[None, :, :], mode="drop")
 
 
-@functools.cache
-@_eager_selftest
-def _tpu_kernel_selftest(num_bins_padded: int) -> str:
-    """One small on-device compile+run per bin width decides the kernel mode
-    for this process: packed dot → per-feature dot → XLA scatter. Insurance
-    for unattended bench windows — a Mosaic lowering regression must degrade
-    throughput, not kill the measurement. Runs at the PRODUCTION chunk and
-    the requested bin width (which sets K1/PACK — the lowering-relevant
-    shapes), with per-feature random bins and distinct g/h/m channels so
-    cross-feature contamination or channel swaps fail the check."""
+def _check_inputs(seed: int, num_bins_padded: int, n: int, fp: int = 8):
+    """Per-feature random bins and distinct g/h/m channels, so cross-feature
+    contamination or a channel swap fails the check. (The on-chip smoke and
+    the chip test-suite draw their inputs here too.)"""
     import numpy as _np
 
-    n = default_chunk()
-    rng = _np.random.default_rng(0)
-    bT = jnp.asarray(rng.integers(0, num_bins_padded, size=(8, n)),
+    rng = _np.random.default_rng(seed)
+    bT = jnp.asarray(rng.integers(0, num_bins_padded, size=(fp, n)),
                      jnp.int32)
     g = jnp.asarray(rng.normal(size=n).astype(_np.float32))
     h = jnp.asarray(rng.uniform(0.5, 2.0, size=n).astype(_np.float32))
     m = jnp.asarray((rng.uniform(size=n) > 0.25).astype(_np.float32))
-    want = _np.asarray(_hist_xla(bT, g * m, h * m, m, num_bins_padded))
-    for mode, pk in (("packed", None), ("pack1", 1)):
-        try:
-            got = _np.asarray(_hist_pallas(bT, g * m, h * m, m,
-                                           num_bins_padded, pack=pk))
-            if _np.allclose(got, want, rtol=1e-4, atol=1e-3):
-                return mode
-        except Exception:
-            continue
-    return "xla"
+    return bT, g * m, h * m, m
+
+
+def _level_check_inputs(seed: int, num_bins_padded: int, caps, chunk: int,
+                        fp: int = 8):
+    """Slot-partitioned rows for the level kernel: slot i owns ``caps[i]``
+    chunks and its last 37 rows are zero-valued tail padding. Returns
+    (bT, g, h, m, start_chunks, slot_of_row)."""
+    import numpy as _np
+
+    bT, g, h, m = _check_inputs(seed, num_bins_padded, sum(caps) * chunk, fp)
+    ends = _np.cumsum(caps)
+    live = _np.ones(int(ends[-1]) * chunk, _np.float32)
+    for e in ends:
+        live[e * chunk - 37:e * chunk] = 0.0
+    live = jnp.asarray(live)
+    return (bT, g * live, h * live, m * live,
+            jnp.asarray(ends - _np.asarray(caps), jnp.int32),
+            jnp.asarray(_np.repeat(_np.arange(len(caps)),
+                                   _np.asarray(caps) * chunk), jnp.int32))
 
 
 @functools.cache
 @_eager_selftest
-def _tpu_segmented_ok(num_bins_padded: int) -> bool:
-    """On-device check of the scalar-prefetch segmented kernel (same
-    insurance contract as _tpu_kernel_selftest): False degrades the grower
-    to the dynamic_slice + plain-kernel path."""
+def _check_hist_kernel(num_bins_padded: int) -> None:
+    """One small on-device compile+run of the packed MXU kernel per bin
+    width, at the PRODUCTION chunk and the requested bin width (which set
+    K1/PACK — the lowering-relevant shapes); raises KernelError."""
+    n = default_chunk()
+    bT, g, h, m = _check_inputs(0, num_bins_padded, n)
+    check_kernel(
+        "_hist_pallas",
+        dict(num_bins_padded=num_bins_padded, chunk=n,
+             feature_block=FEATURE_BLOCK,
+             pack=_pack_for(num_bins_padded // 8, FEATURE_BLOCK, None)),
+        lambda: _hist_pallas(bT, g, h, m, num_bins_padded),
+        lambda: _hist_xla(bT, g, h, m, num_bins_padded),
+        rtol=1e-4, atol=1e-3)
+
+
+@functools.cache
+@_eager_selftest
+def _check_range_kernel(num_bins_padded: int) -> None:
+    """On-device check of the scalar-prefetch segmented kernel; raises
+    KernelError."""
     import numpy as _np
 
-    try:
-        n = 4 * default_chunk()
-        rng = _np.random.default_rng(1)
-        bT = jnp.asarray(rng.integers(0, num_bins_padded, size=(8, n)),
-                         jnp.int32)
-        g = jnp.asarray(rng.normal(size=n).astype(_np.float32))
-        h = jnp.asarray(rng.uniform(0.5, 2.0, size=n).astype(_np.float32))
-        m = jnp.asarray((rng.uniform(size=n) > 0.25).astype(_np.float32))
-        # geometry satisfies the documented contract size >= length + chunk
-        start, length = 1234, 2 * default_chunk() - 57
-        size = 3 * default_chunk()
-        got = _np.asarray(_hist_pallas_range(bT, g * m, h * m, m, start,
-                                             length, num_bins_padded, size))
-        idx = _np.arange(n)
-        sel = jnp.asarray(((idx >= start) & (idx < start + length)
-                           ).astype(_np.float32))
-        want = _np.asarray(_hist_xla(bT, g * m * sel, h * m * sel, m * sel,
-                                     num_bins_padded))
-        return bool(_np.allclose(got, want, rtol=1e-4, atol=1e-3))
-    except Exception:
-        return False
+    C = default_chunk()
+    n = 4 * C
+    bT, g, h, m = _check_inputs(1, num_bins_padded, n)
+    # geometry satisfies the documented contract size >= length + chunk
+    start, length, size = 1234, 2 * C - 57, 3 * C
+    idx = _np.arange(n)
+    sel = jnp.asarray(((idx >= start) & (idx < start + length)
+                       ).astype(_np.float32))
+    check_kernel(
+        "_hist_pallas_range",
+        dict(num_bins_padded=num_bins_padded, chunk=C, size=size, rows=n,
+             feature_block=FEATURE_BLOCK),
+        lambda: _hist_pallas_range(bT, g, h, m, start, length,
+                                   num_bins_padded, size),
+        lambda: _hist_xla(bT, g * sel, h * sel, m * sel, num_bins_padded),
+        rtol=1e-4, atol=1e-3)
 
 
 def segmented_histograms_available(num_bins_padded: int) -> bool:
-    """Trace-time gate for the grower: TPU backend + env not disabling +
-    on-device selftest green."""
+    """Trace-time choice for the grower: the segmented kernel on the TPU
+    backend unless SYNAPSEML_TPU_SEGMENTED=0 asks for the sliced path. A
+    kernel that fails its check raises KernelError."""
     if jax.default_backend() != "tpu":
         return False
     if os.environ.get("SYNAPSEML_TPU_SEGMENTED", "1") == "0":
         return False
-    return _tpu_segmented_ok(num_bins_padded)
+    _check_range_kernel(num_bins_padded)
+    return True
 
 
 def range_histogram(bT, g, h, m, start, length, num_bins_padded: int,
@@ -549,12 +587,10 @@ def child_histogram(bT, g, h, m, num_bins_padded: int):
 
     Rows with m == 0 (outside the leaf range / bagged out / padding) contribute
     nothing PROVIDED g and h are also zeroed for those rows (callers mask all
-    three). Uses the Pallas MXU kernel on TPU, XLA scatter elsewhere.
+    three). The Pallas MXU kernel on TPU (KernelError if it fails its
+    check), XLA scatter on other backends.
     """
     if jax.default_backend() == "tpu":
-        mode = _tpu_kernel_selftest(num_bins_padded)
-        if mode == "packed":
-            return _hist_pallas(bT, g, h, m, num_bins_padded)
-        if mode == "pack1":
-            return _hist_pallas(bT, g, h, m, num_bins_padded, pack=1)
+        _check_hist_kernel(num_bins_padded)
+        return _hist_pallas(bT, g, h, m, num_bins_padded)
     return _hist_xla(bT, g, h, m, num_bins_padded)

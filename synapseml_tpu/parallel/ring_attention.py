@@ -117,22 +117,24 @@ def ring_self_attention(q, k, v, mesh: Mesh, causal: bool = False,
     ``use_flash`` runs each rank's per-step block update as the FUSED
     Pallas kernel (ops/attention_kernel.flash_attention_block — scores,
     masking, online-softmax rescale, and PV matmul in one VMEM program)
-    instead of the XLA ops below. None = auto: on TPU when the kernel's
-    on-device selftest passes; the XLA path otherwise — both compute the
-    identical update (equality-tested in tests/test_attention_kernel.py).
+    instead of the XLA ops below. None = by backend: the kernel on TPU
+    (KernelError if it fails its on-device check), the XLA ops elsewhere —
+    both compute the identical update (equality-tested in
+    tests/test_attention_kernel.py).
     """
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if use_flash is None:
-        from ..ops.attention_kernel import _tpu_flash_block_selftest
-
-        use_flash = (jax.default_backend() == "tpu"
-                     and _tpu_flash_block_selftest())
+        use_flash = jax.default_backend() == "tpu"
     if kv_len is not None:
         # padded (non-divisible) sequences need the global key-validity mask,
         # which the fused block kernel does not plumb — XLA path only
         use_flash = False
     if use_flash:
-        from ..ops.attention_kernel import flash_attention_block
+        from ..ops.attention_kernel import (_check_flash_block_kernel,
+                                            flash_attention_block)
+
+        if not flash_interpret:
+            _check_flash_block_kernel()
     ring = mesh.shape[axis]
     # batch rides the data axis when the mesh has one (dp × sp composition) —
     # each data-rank computes only its batch shard

@@ -44,8 +44,9 @@ def ulysses_self_attention(q, k, v, mesh: Mesh, causal: bool = False,
 
     ``use_flash`` runs the per-device full-sequence attention through the
     fused Pallas kernel (ops/attention_kernel.flash_attention) instead of
-    the lax-composed reference. None = auto: on TPU when the kernel's
-    on-device selftest passes. ``kv_len`` masks padded key positions when a
+    the lax-composed reference. None = by backend: the kernel on TPU
+    (checked on-device inside ``flash_attention``; KernelError if it fails),
+    the reference elsewhere. ``kv_len`` masks padded key positions when a
     non-divisible sequence was padded to the shard grid (forces the
     reference path, which plumbs the mask).
     """
@@ -56,10 +57,7 @@ def ulysses_self_attention(q, k, v, mesh: Mesh, causal: bool = False,
         raise ValueError(f"heads ({q.shape[2]}) must divide by the seq-axis "
                          f"size ({sp}) for Ulysses attention")
     if use_flash is None:
-        from ..ops.attention_kernel import _tpu_flash_selftest
-
-        use_flash = (jax.default_backend() == "tpu"
-                     and _tpu_flash_selftest())
+        use_flash = jax.default_backend() == "tpu"
     if kv_len is not None:
         use_flash = False
     if use_flash:

@@ -1,8 +1,8 @@
 """End-to-end rehearsal of the perf_tune tune -> flip -> persist pipeline.
 
 tools/perf_tune.py lands its measurements through an atexit handler; a bug
-there was historically only discovered DURING a scarce TPU window (a
-NameError at interpreter shutdown lost a whole window's results). These
+there was historically only discovered on the chip (a NameError at
+interpreter shutdown lost a whole run's results, 2026-08-02). These
 tests run the real script as a subprocess on CPU in rehearsal mode
 (PERF_TUNE_REHEARSAL=1: tiny data, 1-rep timings, trimmed variants, flip
 allowed off-chip) so the entire shutdown path — raw-results write, winner
@@ -81,7 +81,7 @@ def test_full_tune_flip_persist(tmp_path):
 
 
 @pytest.mark.slow
-def test_short_window_falls_back_to_phase_a(tmp_path):
+def test_short_budget_falls_back_to_phase_a(tmp_path):
     # a budget that only admits phase A (guards skip below 90 s left): the
     # flip must still land, decided by the phase-A fallback scores
     proc, tuned_path, results_path = _run(

@@ -2,7 +2,7 @@
 
 Every analyzer gets at least one must-flag and one must-not-flag fixture
 (the must-not cases encode the false-positive guards: static_argnames,
-``_eager_selftest``-style trace escapes, guarded-caller lock propagation,
+``ensure_compile_time_eval`` trace escapes, guarded-caller lock propagation,
 ``sorted()`` after ``os.listdir`` accumulation, ...). The live-tree test is
 the CI gate contract: the checked-in tree must be baseline-clean.
 """
@@ -87,8 +87,8 @@ def test_trace_safety_ignores_static_argnames_and_shapes(tmp_path):
 
 
 def test_trace_safety_respects_compile_time_eval_escape(tmp_path):
-    # the repo's @_eager_selftest pattern: a decorator whose wrapper enters
-    # jax.ensure_compile_time_eval() runs the body eagerly — never flagged
+    # a decorator whose wrapper enters jax.ensure_compile_time_eval() runs
+    # the body eagerly — never flagged
     ctx = _ctx(tmp_path, {"synapseml_tpu/mod.py": """\
         import functools
 

@@ -3,7 +3,8 @@ multi-node testing is its Databricks/Synapse notebook E2E jobs; the analog
 here is a small on-chip suite).
 
 Run with:  SYNAPSEML_TPU_E2E=1 python -m pytest tests/test_tpu_e2e.py -q
-(the normal suite pins the cpu platform, so these auto-skip there).
+(the normal suite pins the cpu platform, so these auto-skip there; with the
+variable set, finding no TPU is a failure, not a skip).
 """
 
 import os
@@ -21,8 +22,9 @@ def tpu():
     import jax
 
     devs = jax.devices()
-    if devs[0].platform == "cpu":
-        pytest.skip("no TPU device visible")
+    if devs[0].platform != "tpu":
+        pytest.fail(f"SYNAPSEML_TPU_E2E=1 but jax found {devs[0].platform!r} "
+                    f"({devs[0].device_kind}), not a TPU")
     return devs[0]
 
 
@@ -55,6 +57,28 @@ def test_gbdt_train_predict_on_chip(tpu):
                                                 num_iterations=10))
     acc = ((bst.predict(X[:2000]) > 0.5) == (y[:2000] > 0.5)).mean()
     assert acc > 0.9, acc
+
+
+def test_bucketed_runner_donates_on_chip(tpu):
+    """The serving runner donates its padded input buffer on TPU (CPU never
+    exercises donation): bucketed predict must equal plain predict, on every
+    bucket and after warmup."""
+    from synapseml_tpu.gbdt import BoosterConfig, train_booster
+
+    rng = np.random.default_rng(9)
+    X = rng.normal(size=(5_000, 8)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
+    bst = train_booster(X, y, BoosterConfig(objective="binary",
+                                            num_iterations=5))
+    serve = bst.serving_fn(max_batch_size=32)
+    stats = serve.warmup()
+    assert stats["total_compiles"] == len(stats["buckets"])
+    want = bst.predict(X[:100])
+    for n in (1, 3, 32, 100):
+        np.testing.assert_allclose(np.asarray(serve(X[:n])), want[:n],
+                                   rtol=1e-6, atol=1e-6)
+    after = serve.runner.stats()
+    assert after["total_compiles"] == after["warmup_compiles"]
 
 
 def test_grower_layouts_agree_on_chip(tpu):
@@ -155,16 +179,13 @@ def test_kernel_chunk_variants_agree_on_chip(tpu):
 
 def test_segmented_kernel_on_chip(tpu):
     """Scalar-prefetch segmented kernel on REAL hardware vs the scatter
-    fallback, plus the availability gate."""
+    reference; the gate is True on the chip or raises KernelError."""
     import jax.numpy as jnp
 
     from synapseml_tpu.ops.hist_kernel import (_hist_pallas_range, _hist_xla,
                                                segmented_histograms_available)
 
-    ok = segmented_histograms_available(256)
-    assert ok in (True, False)
-    if not ok:
-        pytest.skip("segmented kernel unavailable on this backend build")
+    assert segmented_histograms_available(256) is True
     rng = np.random.default_rng(0)
     FP, Np, B = 16, 16384, 256
     bT = jnp.asarray(rng.integers(0, B, size=(FP, Np)).astype(np.int32))
@@ -196,24 +217,59 @@ def test_grower_segmented_matches_sliced_on_chip(tpu):
                                    np.asarray(tl.leaf_value), rtol=1e-5)
 
 
-def test_kernel_selftest_modes_on_chip(tpu):
-    """Record which mode every kernel selftest chose on THIS chip — a Mosaic
-    lowering regression degrades silently (by design), so the chosen modes
-    must be visible in the e2e log for review (VERDICT r3 missing #3)."""
-    from synapseml_tpu.ops.hist_kernel import (_tpu_kernel_selftest,
-                                               _tpu_level_ok,
-                                               _tpu_segmented_ok, pad_bins)
+def test_kernel_checks_pass_on_chip(tpu):
+    """Every trace-time kernel check passes on THIS chip: each compiles its
+    kernel at the production chunk, runs it and compares with the XLA
+    reference, raising KernelError otherwise (there is no fallback)."""
+    from synapseml_tpu.ops.attention_kernel import (_check_flash_block_kernel,
+                                                    _check_flash_kernel)
+    from synapseml_tpu.ops.hist_kernel import (_check_hist_kernel,
+                                               _check_level_kernel,
+                                               _check_range_kernel, pad_bins)
 
     b = pad_bins(255)
-    mode = _tpu_kernel_selftest(b)
-    seg = _tpu_segmented_ok(b)
-    lvl = _tpu_level_ok(b, 8)
-    print(f"\nKERNEL MODES on {tpu}: packed={mode} segmented={seg} "
-          f"level={lvl}", flush=True)
-    assert mode in ("packed", "pack1", "xla")
-    # the packed MXU path must lower on real hardware — a degradation to
-    # XLA scatter is a regression worth failing the e2e suite over
-    assert mode != "xla", "packed kernel degraded to XLA scatter on chip"
+    _check_hist_kernel(b)
+    _check_range_kernel(b)
+    _check_level_kernel(b, 8)
+    _check_flash_kernel()
+    _check_flash_block_kernel()
+
+
+def test_level_kernel_on_chip(tpu):
+    """The multi-leaf level kernel (depthwise / streamed growth) vs the
+    slot-keyed scatter, 136 features wide, uneven slots with padded tails."""
+    from synapseml_tpu.ops import hist_kernel as hk
+
+    C, B = 2048, 256
+    caps = [3, 1, 2, 1, 1, 4, 2, 2]
+    bT, g, h, m, starts, slot_row = hk._level_check_inputs(7, B, caps, C,
+                                                           fp=136)
+    got = np.asarray(hk._hist_pallas_level(bT, g, h, m, starts, B, len(caps),
+                                           chunk=C))
+    want = np.asarray(hk._hist_level_xla(bT, g, h, m, slot_row, B,
+                                         len(caps)))
+    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
+
+
+def test_depthwise_growth_on_chip(tpu, monkeypatch):
+    """Depthwise growth through the level kernel trains a usable model and
+    agrees with the same policy on the scatter path (SYNAPSEML_TPU_LEVEL=0)."""
+    from synapseml_tpu.gbdt import BoosterConfig, train_booster
+    from synapseml_tpu.gbdt import boosting
+
+    rng = np.random.default_rng(8)
+    X = rng.normal(size=(30_000, 12)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] + 0.5 * X[:, 2] > 0).astype(np.float32)
+    cfg = dict(objective="binary", num_iterations=5,
+               growth_policy="depthwise")
+    b_k = train_booster(X, y, BoosterConfig(**cfg))
+    monkeypatch.setenv("SYNAPSEML_TPU_LEVEL", "0")
+    boosting._FUSED_RUNNERS.clear()      # the env is read at trace time
+    b_s = train_booster(X, y, BoosterConfig(**cfg))
+    boosting._FUSED_RUNNERS.clear()
+    p_k, p_s = b_k.predict(X[:2000]), b_s.predict(X[:2000])
+    assert ((p_k > 0.5) == (y[:2000] > 0.5)).mean() > 0.8
+    np.testing.assert_allclose(p_k, p_s, atol=5e-3)
 
 
 def test_tuned_defaults_flip_visible_on_chip(tpu):
@@ -237,21 +293,83 @@ def test_tuned_defaults_flip_visible_on_chip(tpu):
             assert getattr(cfg, key) == vals[key]
 
 
+def _highest(fn, *args, **kw):
+    """Reference at full f32 matmul precision (the TPU default is a bf16
+    pass, which is also what the kernels' own f32 matmuls use — hence the
+    1e-2 tolerances below; measured 2.6e-3 on a v5e)."""
+    import jax
+
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(fn(*args, **kw))
+
+
 def test_flash_attention_on_chip(tpu):
-    """The Pallas flash-attention kernel must pass its on-device selftest
-    and agree with the XLA reference on REAL hardware (CI only checks the
-    interpreter), causal and full, incl. non-divisible lengths."""
-    from synapseml_tpu.ops.attention_kernel import (
-        _tpu_flash_block_selftest, _tpu_flash_selftest, flash_attention)
+    """The Pallas flash-attention kernel agrees with the XLA reference on
+    REAL hardware (CI only checks the interpreter), causal and full, incl.
+    non-divisible lengths."""
+    from synapseml_tpu.ops.attention_kernel import flash_attention
     from synapseml_tpu.parallel.ring_attention import attention_reference
 
-    assert _tpu_flash_selftest(), "Mosaic lowering selftest failed on chip"
-    assert _tpu_flash_block_selftest(), \
-        "state-carrying (ring) lowering selftest failed on chip"
     rng = np.random.default_rng(0)
     q, k, v = (rng.normal(size=(2, 300, 4, 64)).astype(np.float32)
                for _ in range(3))
     for causal in (False, True):
         got = np.asarray(flash_attention(q, k, v, causal=causal))
-        want = np.asarray(attention_reference(q, k, v, causal=causal))
-        np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
+        want = _highest(attention_reference, q, k, v, causal=causal)
+        np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2)
+
+
+def test_flash_attention_block_on_chip(tpu):
+    """The ring's state-carrying kernel vs ring_attention._block_attention,
+    folding a second K/V block into carried state, compared after
+    normalization."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.ops.attention_kernel import (comparable_state,
+                                                    flash_attention_block)
+    from synapseml_tpu.parallel.ring_attention import _block_attention
+
+    rng = np.random.default_rng(1)
+    sq = sk = 1024
+    q = jnp.asarray(rng.normal(size=(1, sq, 12, 64)), jnp.float32)
+    k1, v1, k2, v2 = (jnp.asarray(rng.normal(size=(1, sk, 12, 64)),
+                                  jnp.float32) for _ in range(4))
+    m0 = jnp.full((1, 12, sq), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((1, 12, sq), jnp.float32)
+    o0 = jnp.zeros((1, sq, 12, 64), jnp.float32)
+    for causal in (False, True):
+        with jax.default_matmul_precision("highest"):
+            st = _block_attention(q, k1, v1, m0, l0, o0, sk, 0, causal, 0.125)
+            mw, lw, ow = _block_attention(q, k2, v2, *st, sk, sk, causal,
+                                          0.125)
+        mg, lg, og = flash_attention_block(q, k2, v2, *st, q_offset=sk,
+                                           k_offset=sk, causal=causal,
+                                           scale=0.125)
+        for got, want in zip(comparable_state(mg, lg, og),
+                             comparable_state(mw, lw, ow)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-2, atol=1e-2)
+
+
+def test_ring_attention_with_kernel_on_chip(tpu):
+    """ring_self_attention over every visible chip picks the fused block
+    kernel by backend and equals attention_reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import make_mesh
+    from synapseml_tpu.parallel.ring_attention import (attention_reference,
+                                                       ring_self_attention)
+
+    mesh = make_mesh({"data": 1, "seq": len(jax.devices())})
+    rng = np.random.default_rng(2)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 2048, 4, 64)), jnp.float32)
+               for _ in range(3))
+    for causal in (False, True):
+        ring = jax.jit(lambda a, b, c: ring_self_attention(
+            a, b, c, mesh, causal=causal))
+        assert "tpu_custom_call" in ring.lower(q, k, v).compile().as_text()
+        want = _highest(attention_reference, q, k, v, causal=causal)
+        np.testing.assert_allclose(np.asarray(ring(q, k, v)), want,
+                                   rtol=1e-2, atol=1e-2)

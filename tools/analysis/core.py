@@ -35,7 +35,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 PACKAGE = "synapseml_tpu"
 
-DEFAULT_TARGETS = ["synapseml_tpu", "tools", "bench.py",
+DEFAULT_TARGETS = ["synapseml_tpu", "tools", "bench.py", "chip_smoke.py",
                    "__graft_entry__.py", "tests"]
 
 #: ``# lint-ok`` suppresses every analyzer on that line;

@@ -214,7 +214,10 @@ class DtypeModel:
     def module_consts(self, sf: SourceFile) -> Dict[str, object]:
         cached = self._consts.get(sf.rel)
         if cached is None:
-            cached = {}
+            # registered before it is filled: parse_dtype_name looks names
+            # up here, and a top-level alias (``shard_map = jax.shard_map``)
+            # would otherwise recurse forever
+            cached = self._consts[sf.rel] = {}
             for node in getattr(sf.tree, "body", []):
                 if not (isinstance(node, ast.Assign)
                         and len(node.targets) == 1
@@ -229,7 +232,6 @@ class DtypeModel:
                     dt = self.parse_dtype_name(sf, v)
                     if dt is not None:
                         cached[node.targets[0].id] = dt
-            self._consts[sf.rel] = cached
         return cached
 
     def fold_int(self, sf: SourceFile, node: ast.AST) -> Optional[int]:

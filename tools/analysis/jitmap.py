@@ -152,8 +152,8 @@ class JitMap:
         ``jax.ensure_compile_time_eval()`` escapes the surrounding trace, so
         (a) a function whose body contains that with-block is an *escape
         provider*, and (b) a function decorated with an escape provider
-        (the repo's ``@_eager_selftest`` pattern — a decorator whose wrapper
-        enters the context manager) runs its body eagerly. Neither should be
+        (a decorator whose wrapper enters the context manager) runs its
+        body eagerly. Neither should be
         marked traced, and call edges must not propagate through them.
         """
         providers: Set[str] = set()
@@ -646,8 +646,9 @@ class TaintWalker:
             if canon.startswith(("jax.", "jax")) and not is_host_escape(
                     canon):
                 # under omnistaging EVERY jnp/lax op inside a trace stages
-                # into it, even on fresh concrete operands (the repo's
-                # _eager_selftest docstring records the observed failure)
+                # into it, even on fresh concrete operands (the docstring of
+                # ops/hist_kernel._eager_selftest records the observed
+                # failure)
                 return True
             if canon in {"len", "isinstance", "hasattr", "id", "type",
                          "repr", "str", "print", "range", "enumerate"}:

@@ -1,8 +1,8 @@
 """TPU perf-tuning harness for the v2 GBDT engine.
 
 Phases are ordered by information value and guarded by a wall-clock budget
-(PERF_TUNE_BUDGET_S, default 1800 s) so a short TPU-terminal window still
-yields the critical differentials:
+(PERF_TUNE_BUDGET_S, default 1800 s) so a run cut short by its chip-time
+budget still yields the critical differentials:
 
   A. grow_tree per hot-loop design (sort / scatter / masked) — the tree cost
   B. fused train 5-vs-25 iters per design — isolates steady-state marginal
@@ -18,7 +18,7 @@ VERDICT r3 #1): every phase's raw timings land in docs/perf_tune_results.json
 and the phase-B end-to-end winner (same 25-iteration accounting bench.py
 uses) is written to docs/tuned_defaults.json, which BoosterConfig /
 hist_kernel consume as engine defaults (core/tuned.py) — so the bench that
-follows this tune in the same terminal window measures the tuned DEFAULT.
+follows this tune measures the tuned DEFAULT.
 
 Run: python tools/perf_tune.py [--profile /tmp/jaxtrace]
   --profile wraps one grow_tree in jax.profiler.trace for op-level breakdown.
@@ -55,7 +55,7 @@ def budget_left() -> float:
 
 def guard(phase: str) -> bool:
     _persist_quiet()   # land everything measured so far before the next
-    #                    phase can hang into measure.py's process-group kill
+    #                    phase can run into the caller's timeout kill
     left = budget_left()
     if left < 90:
         print(f"[budget] skipping phase {phase} ({left:.0f}s left)",
@@ -68,8 +68,8 @@ def guard(phase: str) -> bool:
 # Rehearsal mode (PERF_TUNE_REHEARSAL=1): tiny data, single-rep timings,
 # trimmed variant set, and the tuned-defaults flip allowed off-chip — so CI
 # can exercise the ENTIRE tune -> flip -> persist pipeline on CPU
-# (tests/test_perf_tune_rehearsal.py) instead of first finding out during a
-# scarce TPU window that the shutdown path lost the measurements.
+# (tests/test_perf_tune_rehearsal.py) instead of first finding out on the
+# chip that the shutdown path lost the measurements.
 REHEARSAL = os.environ.get("PERF_TUNE_REHEARSAL") == "1"
 N = int(os.environ.get("PERF_TUNE_ROWS", 2048 if REHEARSAL else 500_000))
 F = int(os.environ.get("PERF_TUNE_FEATURES", 28))
@@ -183,7 +183,7 @@ def _flip(now, plat, VARIANTS=VARIANTS, RESULTS=RESULTS,
     scores = {k: v for k, v in RESULTS["phase_b_train25_row_iters"].items()
               if k in by_name}
     decided = "phase B train-25 end-to-end"
-    if not scores:                     # short window: fall back to phase A
+    if not scores:                     # short budget: fall back to phase A
         a = RESULTS["phase_a_ms_per_tree"]
         scores = {k: 1.0 / a[k] for k in by_name if k in a}
         decided = "phase A ms/tree (B never ran)"
@@ -207,7 +207,7 @@ def _flip(now, plat, VARIANTS=VARIANTS, RESULTS=RESULTS,
         vals["hist_chunk"] = int(RESULTS["phase_d_best_fb8"]["chunk"])
     if RESULTS["phase_d_best_pack"]:
         vals["hist_pack"] = int(RESULTS["phase_d_best_pack"])
-    # MERGE with the existing file: a short window that skipped phase D
+    # MERGE with the existing file: a short run that skipped phase D
     # must not silently drop a previously measured hist_chunk pin. Values
     # are re-validated (current_file_values) so a corrupt entry the reader
     # tolerates can't crash this write; and when THIS run measured the
@@ -247,7 +247,7 @@ def _persist_and_flip(_repo_dir=os.path.dirname(os.path.dirname(
         # every module-global the body reads, bound at def time under its
         # own name: at-interpreter-shutdown atexit calls can see module
         # globals (incl. __file__) already torn down (observed on-chip
-        # 2026-08-02: NameError lost a window's results); stdlib modules
+        # 2026-08-02: NameError lost a run's results); stdlib modules
         # re-import locally below for the same reason. The flip half used
         # to import synapseml_tpu.core.tuned INSIDE the body — the same
         # shutdown hazard in new clothes (sys.modules may already be
@@ -261,9 +261,9 @@ def _persist_and_flip(_repo_dir=os.path.dirname(os.path.dirname(
         _RESULTS_PATH_OVERRIDE=os.environ.get("PERF_TUNE_RESULTS_PATH")):
     """Persist RESULTS and flip docs/tuned_defaults.json to the measured
     winner (the flip half of VERDICT r3 #1 — the bench that follows this
-    tune in the same window must measure the tuned DEFAULT). Registered via
-    atexit so a TPU-terminal drop mid-phase still lands everything the
-    completed phases measured — a short window must still yield."""
+    tune must measure the tuned DEFAULT). Registered via atexit so a run
+    stopped mid-phase still lands everything the completed phases
+    measured."""
     import datetime as _dt
     import json
     import os
@@ -271,7 +271,7 @@ def _persist_and_flip(_repo_dir=os.path.dirname(os.path.dirname(
     if not (RESULTS["phase_a_ms_per_tree"]
             or RESULTS["phase_b_train25_row_iters"]
             or RESULTS["phase_d_chunk_ms"]):
-        return   # nothing measured yet: never clobber a prior window's file
+        return   # nothing measured yet: never clobber a prior run's file
     now = _dt.datetime.now(_dt.timezone.utc).isoformat(timespec="seconds")
     try:
         plat = jax.default_backend()
@@ -280,7 +280,7 @@ def _persist_and_flip(_repo_dir=os.path.dirname(os.path.dirname(
     RESULTS["captured_at"], RESULTS["platform"] = now, plat
     # the committed artifact holds ON-CHIP timings only (same policy
     # bench.py's record_measurement enforces): a CPU sanity run must not
-    # clobber numbers captured during a scarce TPU window
+    # clobber numbers captured on the chip
     if _RESULTS_PATH_OVERRIDE:
         res_path = _RESULTS_PATH_OVERRIDE
     elif plat == "tpu":
@@ -311,11 +311,10 @@ def _persist_and_flip(_repo_dir=os.path.dirname(os.path.dirname(
 
 
 def _persist_quiet():
-    """Incremental persistence after each completed phase: measure.py's
-    timeout kill is a process-group SIGKILL on the final escalation, and
-    atexit cannot survive that — so the on-disk artifacts are kept current
-    as the run progresses and a mid-phase kill loses only the phase in
-    flight."""
+    """Incremental persistence after each completed phase: a caller's
+    timeout kill ends in SIGKILL, and atexit cannot survive that — so the
+    on-disk artifacts are kept current as the run progresses and a
+    mid-phase kill loses only the phase in flight."""
     import contextlib
     import io
 
@@ -324,7 +323,7 @@ def _persist_quiet():
             _persist_and_flip()
     except Exception as e:
         # stderr: a swallowed persist failure would silently lose the
-        # window's measurements when the final escalation SIGKILLs us
+        # run's measurements when a timeout SIGKILLs us
         print(f"[persist] failed after phase: {e}", file=sys.stderr,
               flush=True)
 
@@ -336,7 +335,7 @@ atexit.register(_persist_and_flip)
 
 
 def _on_term(signum, frame):
-    # measure.py sends SIGTERM first (grace period before SIGKILL):
+    # a timeout sends SIGTERM first (grace period before SIGKILL):
     # exit through atexit so the final persist + flip still lands
     sys.exit(128 + signum)
 
@@ -352,7 +351,7 @@ if guard("A: grow_tree per design"):
     seg_ok = segmented_histograms_available(pad_bins(255))
     print(f"segmented kernel available: {seg_ok} "
           "(auto rows below use it when True)", flush=True)
-    # ordered by information value: a short window should still yield the
+    # ordered by information value: a short budget should still yield the
     # default's cost, the segmentation differential, the kernel-bound
     # masked bound, and the depthwise policy before the remaining primitives
     avariants = [VARIANTS[0],
@@ -398,7 +397,7 @@ if guard("A: grow_tree per design"):
                     print("\n-- by category --")
                     summarize(profile_dir, top=12, by="category")
             except Exception as e:
-                # a scarce TPU-window trace must survive a partial failure:
+                # an on-chip trace must survive a partial failure:
                 # whatever was computed before the exception still lands in
                 # stdout AND the committed artifact below
                 partial_err = e
@@ -501,7 +500,7 @@ if guard("B: fused train per design"):
                                     swept_by="perf_tune_phase_b")
             print(f"[{name:17s}] journaled gbdt_kernel/{arm} row "
                   f"({marg / N:.3e} s/row-iter)", flush=True)
-        except Exception as e:   # journaling must never sink a TPU window
+        except Exception as e:   # journaling must never sink a chip run
             print(f"[{name:17s}] perf-row journal failed: {e}", flush=True)
 
 # --- phase C: num_leaves sweep (fixed vs marginal split cost) ----------------

@@ -1,0 +1,96 @@
+"""The harness is driven by data: every name in BENCHMARK.json leads to its
+files, and nothing in the harness's code names a cell."""
+
+import json
+import os
+import re
+
+import pytest
+
+from benchmark import run as harness
+
+BENCH = harness._load_json(harness.ROOT, "BENCHMARK.json")
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def test_every_cell_finds_its_files():
+    for w in BENCH["workloads"]:
+        cfg = harness._load_json(harness.HERE, "configs",
+                                 w["config"] + ".json")
+        traffic = harness._load_json(harness.HERE, "traffic",
+                                     w["traffic"] + ".json")
+        assert harness._load_module("entries", traffic["entry"]) is not None
+        ref = harness._load_module("references", w["config"])
+        assert hasattr(ref, "check")
+        assert set(cfg["limits"]) >= {"gain_gap", "leaf_gap", "loss_gap"}
+        assert traffic["rate_metric"] in {m["name"]
+                                          for m in BENCH["end_to_end"]}
+
+
+def test_every_per_layer_metric_has_a_reader():
+    for m in BENCH["per_layer"]:
+        assert hasattr(harness._load_module("metrics", m["name"]), "read")
+
+
+def test_names_units_and_bounds():
+    names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
+    assert len(names) == len(set(names))
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+    for m in BENCH["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+    e2e = {m["name"] for m in BENCH["end_to_end"]}
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in e2e
+        assert set(m.get("workloads", cells)) <= cells
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(
+        1, len(BENCH["workloads"]) // 4)
+    for c in BENCH["configs"]:
+        held = harness._load_json(harness.ROOT, c["file"])
+        assert set(c["reduced"]) == set(held["reduced"])
+
+
+def test_no_cell_is_named_in_the_harness_code():
+    words = {w["name"] for w in BENCH["workloads"]} | {
+        c["name"] for c in BENCH["configs"]} | {
+        w["traffic"] for w in BENCH["workloads"]}
+    for name in ("run.py", "trace.py", "counts.py", "kernels.py",
+                 "tables.py", "peaks.py"):
+        with open(os.path.join(harness.HERE, name)) as f:
+            code = f.read()
+        for w in words:
+            assert w not in code, (name, w)
+
+
+def test_applies():
+    m = {"name": "x", "moves": "a", "workloads": ["c1"]}
+    assert harness._applies(m, "c1", {"a"})
+    assert not harness._applies(m, "c2", {"a"})
+    assert harness._applies({"name": "y", "moves": "a"}, "c2", {"a", "b"})
+    assert not harness._applies({"name": "y", "moves": "z"}, "c2", {"a"})
+    bench, cell, config, traffic = harness.load_cell(
+        BENCH["workloads"][0]["name"], rehearsal=True)
+    assert config["numIterations"] == config["rehearsal"]["numIterations"]
+    assert config["table"]["features"] == 28      # merged, not replaced
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_traced_rehearsal_reads_the_recorded_trace(capsys, workload):
+    rc = harness.main(["--workload", workload, "--seed", "77", "--seconds",
+                       "0.5", "--trace", "1", "--rehearsal"])
+    assert rc == harness.REHEARSAL_EXIT
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert {"correct", "attempted", "failed", "metrics", "device",
+            "breakdown", "compared"} <= set(line)
+    per_layer = {m["name"] for m in BENCH["per_layer"]}
+    assert set(line["metrics"]) <= per_layer and line["metrics"]
+    assert "setup_s" not in line["metrics"]
+    for v in line["metrics"].values():
+        assert set(v) == {"value", "unit"}
+    d = line["device"]
+    assert d["platform"] == "cpu" and 0 < d["busy_s"] <= d["window_s"]
+    assert len(line["breakdown"]["device_ops"]) <= 10

@@ -20,6 +20,7 @@
 #' @param seqAttention Sequence-attention variant: auto (perfmodel-routed) / ring / ulysses
 #' @param seqAxisSize Devices on the 'seq' mesh axis (0 = all local devices)
 #' @param seqParallel Shard attention over a mesh 'seq' axis (mask-free attention; attention dropout disabled)
+#' @param stepFn Step hook: fn(step_idx, loss, params, batch_stats, opt_state) after every accepted training step, device arrays as they are; params/opt_state are donated to the next step, so copy inside the call what is kept (FlaxTrainer.fit step_fn)
 #' @param textCol Input text column
 #' @param vocabSize Hash-bucket vocabulary size
 #' @export

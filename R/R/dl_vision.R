@@ -19,6 +19,7 @@
 #' @param pretrainedPath Local .msgpack/.npz checkpoint of backbone params
 #' @param seed Random seed
 #' @param smallImages CIFAR-style stem (3x3 conv, no max-pool)
+#' @param stepFn Step hook: fn(step_idx, loss, params, batch_stats, opt_state) after every accepted training step, device arrays as they are; params/opt_state are donated to the next step, so copy inside the call what is kept (FlaxTrainer.fit step_fn)
 #' @param storePrefixPath compat no-op (horovod store)
 #' @param validationFraction Holdout fraction for val metrics
 #' @export

@@ -27,7 +27,6 @@ from .pipeline import (  # noqa: F401
 )
 from .logging import (  # noqa: F401
     InstrumentationMeasures,
-    StopWatch,
     SynapseMLLogging,
     failure_counts,
     record_failure,

@@ -1,23 +1,31 @@
-"""Structured logging + phase instrumentation.
+"""Structured logging + the span record.
 
 Analog of the reference's ``SynapseMLLogging`` trait (core/.../logging/
 SynapseMLLogging.scala: every stage logs construction via logClass and wraps
 fit/transform in timed, structured log records) and of the LightGBM phase
 instrumentation (lightgbm/.../LightGBMPerformance.scala: InstrumentationMeasures /
-TaskInstrumentationMeasures with mark*Start/Stop spans). Spans integrate with the
-JAX profiler when active (jax.profiler.TraceAnnotation), so phase marks show up in
-TPU traces — the SURVEY §5.1 recommendation.
+TaskInstrumentationMeasures with mark*Start/Stop spans).
+
+``InstrumentationMeasures`` is the one span mechanism of the package: a fit
+(the booster's, the trainer's) owns one, opens named spans on it where the
+work happens and closes each when that work is done, and the estimator logs
+its ``report()`` as one ``trainingMeasures`` record. Each span keeps a record
+(name, start, end, parent) in memory and is at the same time a
+``jax.profiler.TraceAnnotation``, so in a profiler session the spans lie on
+the device trace's clock (SURVEY §5.1). docs/observability.md names every
+span and counter.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import logging
 import re
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Deque, Dict, NamedTuple, Optional
 
 logger = logging.getLogger("synapseml_tpu")
 
@@ -159,68 +167,123 @@ def _maybe_jax_annotation(name: str):
         yield
 
 
-class StopWatch:
-    """Reference: core/.../core/utils/StopWatch.scala — ad-hoc timing."""
+class SpanRecord(NamedTuple):
+    """One closed span: times from ``time.perf_counter_ns()``; ``parent`` is
+    the name of the span that was open on the same object when this one was
+    opened (``None`` at the top level)."""
 
-    def __init__(self):
-        self._t0 = None
-        self.elapsed_s = 0.0
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: Optional[str]
 
-    def start(self):
-        self._t0 = time.perf_counter()
+
+class _Span:
+    """One open span of an :class:`InstrumentationMeasures`: entered, it
+    starts the clock and the profiler annotation of the same name; left, it
+    stops both and files the record. ``discard()`` inside the block leaves
+    no record (a step that found its iterator empty)."""
+
+    __slots__ = ("_owner", "name", "_ann", "_parent", "start_ns", "_child_ns",
+                 "_keep")
+
+    def __init__(self, owner, name, ann):
+        self._owner, self.name, self._ann = owner, name, ann
+        self._child_ns = 0
+        self._keep = True
+
+    def discard(self) -> None:
+        self._keep = False
+
+    def __enter__(self):
+        owner = self._owner
+        self._parent = owner._open
+        owner._open = self
+        self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
         return self
 
-    def stop(self) -> float:
-        if self._t0 is not None:
-            self.elapsed_s += time.perf_counter() - self._t0
-            self._t0 = None
-        return self.elapsed_s
-
-    @contextlib.contextmanager
-    def measure(self):
-        self.start()
-        try:
-            yield self
-        finally:
-            self.stop()
+    def __exit__(self, *exc):
+        end = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        owner, parent = self._owner, self._parent
+        if owner._open is self:
+            # (not so when a watchdog gave up on the thread that opened it
+            # and the caller has unwound past it: the record is still filed)
+            owner._open = parent
+        if self._keep:
+            owner._close(self, end, parent)
+        return False
 
 
 class InstrumentationMeasures:
-    """Named phase spans, aggregatable across hosts — the LightGBMPerformance
-    analog. Usage::
+    """The span record of one fit — the LightGBMPerformance analog. Usage::
 
         m = InstrumentationMeasures()
-        with m.span("dataPreparation"): ...
-        m.report()  # {"dataPreparation": seconds, ...}
+        with m.span("dataPreparation"):
+            with m.span("binning"): ...
+        m.report()        # {"dataPreparation": s, "dataPreparation/binning": s}
+        m.self_seconds()  # each key's seconds less what its children cover
+        m.records         # SpanRecord(name, start_ns, end_ns, parent), newest 4,096
+
+    A span closes when its ``with`` block is left, so the block has to end
+    where the work is done (``jax.block_until_ready``), not where it is
+    dispatched. Every span is also a ``jax.profiler.TraceAnnotation`` of the
+    same name (``StepTraceAnnotation`` with ``step_num``): one context
+    manager opens and closes both, so in a profiler session the spans lie on
+    the device trace's clock. Nothing is written anywhere while a fit runs;
+    sums and counts hold every occurrence, ``records`` the newest
+    ``MAX_RECORDS``. One object belongs to one fit and one thread at a time.
     """
 
-    def __init__(self):
-        self.spans: Dict[str, float] = {}
-        self.counters: Dict[str, int] = {}
+    MAX_RECORDS = 4096
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            with _maybe_jax_annotation(name):
-                yield
-        finally:
-            self.spans[name] = self.spans.get(name, 0.0) + time.perf_counter() - t0
+    def __init__(self):
+        self.spans: Dict[str, float] = {}      # key -> seconds, all occurrences
+        self.occurrences: Dict[str, int] = {}  # key -> spans closed
+        self.counters: Dict[str, int] = {}
+        self.records: Deque[SpanRecord] = collections.deque(
+            maxlen=self.MAX_RECORDS)
+        self._self_s: Dict[str, float] = {}
+        self._open: Optional[_Span] = None
+
+    def span(self, name: str, step_num: Optional[int] = None) -> _Span:
+        import jax.profiler     # here, so that importing this module stays light
+
+        if step_num is None:
+            ann = jax.profiler.TraceAnnotation(name)
+        else:
+            ann = jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+        return _Span(self, name, ann)
+
+    def _close(self, span: _Span, end_ns: int, parent: Optional[_Span]):
+        dur = end_ns - span.start_ns
+        key = span.name if parent is None else f"{parent.name}/{span.name}"
+        self.spans[key] = self.spans.get(key, 0.0) + dur / 1e9
+        self.occurrences[key] = self.occurrences.get(key, 0) + 1
+        self._self_s[key] = (self._self_s.get(key, 0.0)
+                             + max(dur - span._child_ns, 0) / 1e9)
+        if parent is not None:
+            parent._child_ns += dur
+        self.records.append(SpanRecord(
+            span.name, span.start_ns, end_ns,
+            None if parent is None else parent.name))
 
     def count(self, name: str, n: int = 1):
         self.counters[name] = self.counters.get(name, 0) + n
 
     def report(self) -> Dict[str, float]:
+        """Seconds summed over the occurrences of every span, a span opened
+        inside another under ``<parent's name>/<its name>``, and
+        ``count:<name>`` for the counters."""
         out: Dict[str, Any] = dict(self.spans)
         out.update({f"count:{k}": v for k, v in self.counters.items()})
         return out
 
-    def merge(self, other: "InstrumentationMeasures") -> "InstrumentationMeasures":
-        for k, v in other.spans.items():
-            self.spans[k] = self.spans.get(k, 0.0) + v
-        for k, v in other.counters.items():
-            self.counters[k] = self.counters.get(k, 0) + v
-        return self
+    def self_seconds(self) -> Dict[str, float]:
+        """``report()``'s span keys with the time their child spans cover
+        taken out: what a layer spent that no span inside it names."""
+        return dict(self._self_s)
 
 
 # --- structured failure counters --------------------------------------------

@@ -119,6 +119,12 @@ class DeepTextClassifier(Estimator, HasLabelCol, HasPredictionCol):
     seqAttention = Param(
         "seqAttention", "Sequence-attention variant: auto (perfmodel-routed) "
         "/ ring / ulysses", str, "auto")
+    stepFn = Param(
+        "stepFn", "Step hook: fn(step_idx, loss, params, batch_stats, "
+        "opt_state) after every accepted training step, device arrays as "
+        "they are; params/opt_state are donated to the next step, so copy "
+        "inside the call what is kept (FlaxTrainer.fit step_fn)",
+        is_complex=True)
 
     def _fit(self, df: Table) -> "DeepTextModel":
         texts = list(df[self.getTextCol()])
@@ -149,7 +155,9 @@ class DeepTextClassifier(Estimator, HasLabelCol, HasPredictionCol):
                           compute_dtype=self.getPrecision(), seed=self.getSeed(),
                           seq_parallel=seq_on, seq_attention=self.getSeqAttention())
         trainer = FlaxTrainer(model, cfg, mesh=mesh)
-        trainer.fit(ids, y, log_fn=lambda ep: self._log_base("epoch", ep))
+        trainer.fit(ids, y, log_fn=lambda ep: self._log_base("epoch", ep),
+                    step_fn=self.get("stepFn"))
+        self._log_base("trainingMeasures", trainer.stats["measures"])
 
         m = DeepTextModel(trainer=trainer, classes=classes)
         m.set("seqParallel", seq_on)

@@ -42,7 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.checkpoint import (CheckpointStore, NonFiniteGuard,
                                NonFiniteLossError, preemption_point)
 from ..core.compat import donate_argnums_if_supported
-from ..core.logging import record_failure
+from ..core.logging import InstrumentationMeasures, record_failure
 from ..parallel.elastic import current_watchdog
 from ..parallel.mesh import DATA_AXIS, apply_tree_shardings, tree_shardings
 
@@ -189,6 +189,7 @@ class FlaxTrainer:
         self.loss = loss
         self.params = None
         self.batch_stats = None
+        self.measures = InstrumentationMeasures()   # a new one per fit
 
     # --- setup ----------------------------------------------------------
     def init(self, sample_x):
@@ -361,7 +362,20 @@ class FlaxTrainer:
 
     # --- train ----------------------------------------------------------
     def fit(self, X, y, valid: Optional[tuple] = None,
-            log_fn: Optional[Callable] = None):
+            log_fn: Optional[Callable] = None,
+            step_fn: Optional[Callable] = None):
+        """Train; ``self.history`` gets one entry an epoch, ``self.measures``
+        the fit's span record (``self.stats["measures"]`` its report).
+
+        ``step_fn(step_idx, loss, params, batch_stats, opt_state)`` is called
+        after every accepted step, before the next is dispatched, with the
+        device arrays as they are: the trainer neither waits nor copies for
+        it. ``params`` and ``opt_state`` are DONATED to the next step
+        (``donate_buffers``), so a hook that keeps them copies them inside
+        the call (``jax.tree.map(jnp.copy, ...)`` or ``jax.device_get``).
+        With ``step_fn=None`` the loop dispatches exactly what it did
+        without the hook. Not available with ``param_sharding="pipeline"``.
+        """
         cfg = self.cfg
         # seq routing is scoped around the WHOLE fit body: every jit traced
         # inside (train_step, the per-stage pipeline programs) picks up the
@@ -372,12 +386,20 @@ class FlaxTrainer:
             if cfg.param_sharding == "pipeline":
                 from .pipeline import fit_pipeline
 
+                if step_fn is not None:
+                    raise NotImplementedError(
+                        "step_fn is not threaded through the pipeline "
+                        "schedule (per-stage parameter trees); use "
+                        "param_sharding replicated | zero")
                 return fit_pipeline(self, X, y, valid=valid, log_fn=log_fn)
-            return self._fit_spmd(X, y, valid=valid, log_fn=log_fn)
+            return self._fit_spmd(X, y, valid=valid, log_fn=log_fn,
+                                  step_fn=step_fn)
 
     def _fit_spmd(self, X, y, valid: Optional[tuple] = None,
-                  log_fn: Optional[Callable] = None):
+                  log_fn: Optional[Callable] = None,
+                  step_fn: Optional[Callable] = None):
         cfg = self.cfg
+        measures = self.measures = InstrumentationMeasures()
         X = np.asarray(X)
         y = np.asarray(y)
         if self.params is None:
@@ -546,6 +568,14 @@ class FlaxTrainer:
                     xb, yb = hook(base_step + i, xb, yb)
                 yield xb, yb
 
+        compile_steps = self.stats["compile_steps"] = []
+        cache_size = train_step._cache_size()
+
+        def _synced_step(*a):
+            out = train_step(*a)
+            jax.block_until_ready(out[3])
+            return out
+
         epoch = start_epoch
         while epoch < cfg.max_epochs:
             preemption_point("dl.epoch", epoch)
@@ -557,70 +587,104 @@ class FlaxTrainer:
             nsteps = 0
             t0 = time.perf_counter()
             rolled_back = False
-            for xb, yb in self._prefetch(
-                    batches_with_chaos(rng_e, epoch * steps_per_epoch)):
-                prev = (params, batch_stats, opt_state) if keep_prev else None
-                wd = current_watchdog()
-                if wd is not None:
-                    # elastic mode: the step AND its host sync (the blocking
-                    # point a hung peer's psum actually stalls) run under the
-                    # collective watchdog, so a lost rank surfaces as
-                    # PeerLostError instead of an indefinite stall
-                    def _synced_step(*a):
-                        out = train_step(*a)
-                        jax.block_until_ready(out[3])
-                        return out
-                    params, batch_stats, opt_state, loss, acc = wd.run(
-                        _synced_step, params, batch_stats, opt_state, xb, yb,
-                        step_idx, op="dl.step")
-                    wd.beat("dl.step", step_idx)
-                else:
-                    params, batch_stats, opt_state, loss, acc = train_step(
-                        params, batch_stats, opt_state, xb, yb, step_idx)
-                action = guard.check(float(loss), step_idx)
-                if action == "skip":
-                    # drop the poisoned update; the step index still advances
-                    # so the dropout stream stays aligned with the data order
-                    params, batch_stats, opt_state = prev
-                    step_idx += 1
-                    continue
-                if action == "rollback":
-                    restored = (_restore_checkpoint(store, *prev,
-                                                    shardings=shardings)
-                                if store is not None else None)
-                    if restored is None:
-                        raise NonFiniteLossError(
-                            "nonfinite_policy='rollback' found no checkpoint "
-                            "to restore (set checkpoint_dir and let at least "
-                            "one epoch complete, or use policy 'skip'/'raise')")
-                    params, batch_stats, opt_state, epoch, placed = restored
-                    batch_stats = batch_stats or {}
-                    if shardings is not None and not placed:
-                        params = apply_tree_shardings(params, param_sh)
-                        batch_stats = apply_tree_shardings(batch_stats, bs_sh)
-                        opt_state = apply_tree_shardings(opt_state, opt_sh)
-                    step_idx = epoch * steps_per_epoch
-                    rolled_back = True
-                    break
-                step_idx += 1
-                nsteps += 1
-                losses.append(float(loss))
+            batches = self._prefetch(
+                batches_with_chaos(rng_e, epoch * steps_per_epoch))
+            parts_before = {k: measures.spans.get(k, 0.0) for k in _STEP_PARTS}
+            with measures.span("trainer.epoch") as epoch_span:
+                while True:
+                    with measures.span("trainer.step",
+                                       step_num=step_idx) as step_span:
+                        with measures.span("dataWait") as wait_span:
+                            batch = next(batches, None)
+                            if batch is None:    # the epoch's data is used up
+                                wait_span.discard()
+                                step_span.discard()
+                                break
+                        xb, yb = batch
+                        prev = ((params, batch_stats, opt_state)
+                                if keep_prev else None)
+                        wd = current_watchdog()
+                        with measures.span("dispatch"):
+                            if wd is not None:
+                                # elastic mode: the step AND its host sync
+                                # (the blocking point a hung peer's psum
+                                # actually stalls) run under the collective
+                                # watchdog, so a lost rank surfaces as
+                                # PeerLostError instead of an indefinite stall
+                                out = wd.run(_synced_step, params, batch_stats,
+                                             opt_state, xb, yb, step_idx,
+                                             op="dl.step")
+                                wd.beat("dl.step", step_idx)
+                            else:
+                                out = train_step(params, batch_stats,
+                                                 opt_state, xb, yb, step_idx)
+                        params, batch_stats, opt_state, loss, acc = out
+                        size = train_step._cache_size()
+                        if size != cache_size:
+                            measures.count("compiles", size - cache_size)
+                            compile_steps.append(step_idx)
+                            cache_size = size
+                        with measures.span("lossSync"):
+                            loss_value = float(loss)
+                            action = guard.check(loss_value, step_idx)
+                        if action == "skip":
+                            # drop the poisoned update; the step index still
+                            # advances so the dropout stream stays aligned
+                            # with the data order
+                            params, batch_stats, opt_state = prev
+                            step_idx += 1
+                            measures.count("skipped")
+                            continue
+                        if action == "rollback":
+                            rolled_back = True
+                            measures.count("rolledBack")
+                            break
+                        if step_fn is not None:
+                            with measures.span("stepFn"):
+                                step_fn(step_idx, loss, params, batch_stats,
+                                        opt_state)
+                        step_idx += 1
+                        nsteps += 1
+                        measures.count("steps")
+                        measures.count("samples", len(xb))
+                        losses.append(loss_value)
             if rolled_back:
+                restored = (_restore_checkpoint(store, *prev,
+                                                shardings=shardings)
+                            if store is not None else None)
+                if restored is None:
+                    raise NonFiniteLossError(
+                        "nonfinite_policy='rollback' found no checkpoint "
+                        "to restore (set checkpoint_dir and let at least "
+                        "one epoch complete, or use policy 'skip'/'raise')")
+                params, batch_stats, opt_state, epoch, placed = restored
+                batch_stats = batch_stats or {}
+                if shardings is not None and not placed:
+                    params = apply_tree_shardings(params, param_sh)
+                    batch_stats = apply_tree_shardings(batch_stats, bs_sh)
+                    opt_state = apply_tree_shardings(opt_state, opt_sh)
+                step_idx = epoch * steps_per_epoch
                 continue
             ep = {"epoch": epoch,
                   "loss": float(np.mean(losses)) if losses else float("nan"),
                   "steps": nsteps,
-                  "seconds": time.perf_counter() - t0}
+                  "seconds": time.perf_counter() - t0,
+                  **_epoch_step_times(measures, parts_before,
+                                      epoch_span.start_ns)}
             if valid is not None:
-                ep["val_acc"] = float(self.evaluate(valid[0], valid[1],
-                                                    params=params, batch_stats=batch_stats))
+                with measures.span("trainer.validation"):
+                    ep["val_acc"] = float(self.evaluate(
+                        valid[0], valid[1], params=params,
+                        batch_stats=batch_stats))
             history.append(ep)
             if log_fn:
                 log_fn(ep)
             if store is not None and (epoch + 1) % cfg.save_every_epochs == 0:
-                _save_checkpoint(store, params, batch_stats, opt_state,
-                                 epoch + 1, sharded=zero)
+                with measures.span("trainer.checkpointSave"):
+                    _save_checkpoint(store, params, batch_stats, opt_state,
+                                     epoch + 1, sharded=zero)
             epoch += 1
+        self.stats["measures"] = measures.report()
         self.params, self.batch_stats = params, batch_stats
         self.history = history
         if autoconfig_info:
@@ -676,6 +740,26 @@ class FlaxTrainer:
         if self.loss == "softmax":
             return float((logits.argmax(-1) == np.asarray(y)).mean())
         return -float(np.mean((logits.squeeze(-1) - np.asarray(y)) ** 2))
+
+
+# the children of a ``trainer.step`` span, by their keys in the report, and
+# the history entry each is summed into for its epoch
+_STEP_PARTS = {"trainer.step/dataWait": "data_wait_s",
+               "trainer.step/dispatch": "dispatch_s",
+               "trainer.step/lossSync": "loss_sync_s"}
+
+
+def _epoch_step_times(measures, parts_before: dict, epoch_start_ns: int) -> dict:
+    """What one epoch adds to its ``history`` entry from the span record:
+    the seconds its steps waited for data, dispatched and waited for the
+    loss, and the median step (over the newest ``MAX_RECORDS`` records, which
+    an epoch of more steps than that outruns)."""
+    out = {field: measures.spans.get(key, 0.0) - parts_before[key]
+           for key, field in _STEP_PARTS.items()}
+    steps = [r.end_ns - r.start_ns for r in measures.records
+             if r.name == "trainer.step" and r.start_ns >= epoch_start_ns]
+    out["step_ms_p50"] = float(np.median(steps)) / 1e6 if steps else float("nan")
+    return out
 
 
 def per_device_state_bytes(*trees) -> int:

@@ -91,6 +91,12 @@ class DeepVisionClassifier(Estimator, HasLabelCol, HasPredictionCol):
     pretrainedPath = Param("pretrainedPath", "Local .msgpack/.npz checkpoint of backbone params", str)
     validationFraction = Param("validationFraction", "Holdout fraction for val metrics", float, 0.0)
     smallImages = Param("smallImages", "CIFAR-style stem (3x3 conv, no max-pool)", bool, False)
+    stepFn = Param(
+        "stepFn", "Step hook: fn(step_idx, loss, params, batch_stats, "
+        "opt_state) after every accepted training step, device arrays as "
+        "they are; params/opt_state are donated to the next step, so copy "
+        "inside the call what is kept (FlaxTrainer.fit step_fn)",
+        is_complex=True)
 
     def _fit(self, df: Table) -> "DeepVisionModel":
         images = _resolve_images(df[self.getImageCol()], self.getImageSize() or None)
@@ -122,7 +128,9 @@ class DeepVisionClassifier(Estimator, HasLabelCol, HasPredictionCol):
             nv = max(int(len(X) * vf), 1)
             valid = (X[perm[:nv]], y[perm[:nv]])
             X, y = X[perm[nv:]], y[perm[nv:]]
-        trainer.fit(X, y, valid=valid, log_fn=lambda ep: self._log_base("epoch", ep))
+        trainer.fit(X, y, valid=valid, log_fn=lambda ep: self._log_base("epoch", ep),
+                    step_fn=self.get("stepFn"))
+        self._log_base("trainingMeasures", trainer.stats["measures"])
 
         m = DeepVisionModel(trainer=trainer, classes=classes)
         m.set("backbone", self.getBackbone())

@@ -1308,96 +1308,104 @@ def train_booster(
                 init_score = np.concatenate(
                     [np.asarray(init_score), np.zeros(rem, np.float32)])
     n = X.shape[0]
+    # the span closes when the binned matrix is ready on the device(s), not
+    # when its binning is dispatched
     with measures.span("dataPreparation"):
-        binned = prebinned if prebinned is not None else apply_bins(mapper, X)
-    if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from ..parallel.mesh import DATA_AXIS as _DA
-        row2 = NamedSharding(mesh, P(_DA, None))
-        row1 = NamedSharding(mesh, P(_DA))
-        if multiproc:
-            from ..parallel.mesh import to_global_rows
-            binned = to_global_rows(mesh, P(_DA, None), np.asarray(binned))
-            n = n * jax.process_count()       # n is GLOBAL from here on
-        else:
-            binned = jax.device_put(binned, row2)
-
-    # objective
-    k = cfg.num_class if cfg.objective in ("multiclass", "softmax", "multiclassova") else 1
-    # lambdarank group index; 1-length dummy otherwise (it would replicate at
-    # GLOBAL length onto every device in multi-process mode)
-    gidx_arr = (np.zeros(1, np.int32) if multiproc else jnp.zeros(1, jnp.int32))
-    if cfg.objective == "lambdarank":
-        if group_sizes is None:
-            raise ValueError("lambdarank requires group_sizes")
-        if cfg.label_gain:
-            max_label = int(np.max(y)) if len(y) else 0
-            if max_label >= len(cfg.label_gain):
-                # LightGBM fails fast here too ("Label ... is not less than
-                # the number of label gains") — silent clipping would
-                # optimize the wrong objective
-                raise ValueError(
-                    f"label {max_label} needs a label_gain table of at "
-                    f"least {max_label + 1} entries, got "
-                    f"{len(cfg.label_gain)}")
-        gidx = make_grouped(y, group_sizes)
-        gidx_arr = jnp.asarray(gidx)
-        obj = lambdarank_objective(gidx_arr, cfg.sigmoid,
-                                   cfg.lambdarank_truncation_level,
-                                   cfg.label_gain)
-    else:
-        obj = get_objective(cfg.objective, num_class=k, sigmoid=cfg.sigmoid,
-                            alpha=cfg.alpha, fair_c=cfg.fair_c,
-                            poisson_max_delta_step=cfg.poisson_max_delta_step,
-                            tweedie_variance_power=cfg.tweedie_variance_power)
-
-    if ((cfg.pos_bagging_fraction < 1.0 or cfg.neg_bagging_fraction < 1.0)
-            and cfg.objective not in ("binary",)):
-        # native LightGBM rejects stratified bagging for non-binary objectives
-        raise ValueError("pos_bagging_fraction / neg_bagging_fraction require "
-                         f"objective='binary' (got {cfg.objective!r})")
-    if cfg.boosting_type == "rf" and not (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0
-                                          or cfg.feature_fraction < 1.0):
-        # native LightGBM rejects the same degenerate config (identical trees)
-        raise ValueError("boosting_type='rf' requires bagging (bagging_freq > 0 and "
-                         "bagging_fraction < 1) and/or feature_fraction < 1")
-
-    if multiproc:
-        from jax.sharding import PartitionSpec as P
-
-        from ..parallel.mesh import to_global_rows
-
-        yj = to_global_rows(mesh, P(_DA), y)
-        wj = to_global_rows(mesh, P(_DA), w)
-        valid_mask = to_global_rows(mesh, P(_DA), valid_mask_np)
-        if cfg.boost_from_average:
-            # base score from GLOBAL label stats: jit over the sharded labels
-            # inserts the cross-process reductions (one-shot per fit, so the
-            # throwaway jit wrapper is deliberate)
-            base_g = jax.jit(obj.init_score,  # lint-ok: recompile
-                             out_shardings=NamedSharding(mesh, P()))(yj, wj)
-            base = np.atleast_1d(np.asarray(jax.device_get(base_g), np.float64))
-        else:
-            base = np.zeros(max(k, 1))
-        local_margin = (np.zeros((len(y), k), np.float32)
-                        + base[None, :k].astype(np.float32))
-        score = to_global_rows(mesh, P(_DA, None), local_margin)
-    else:
-        yj, wj = jnp.asarray(y), jnp.asarray(w)
-        valid_mask = jnp.asarray(valid_mask_np)
-        base = (np.atleast_1d(np.asarray(obj.init_score(yj, wj), np.float64))
-                if cfg.boost_from_average else np.zeros(max(k, 1)))
-        # the fixed margin every iteration starts from: base score + init_score
-        init_margin = jnp.zeros((n, k)) + jnp.asarray(base[None, :k], jnp.float32)
-        if init_score is not None:
-            init_margin = init_margin + jnp.asarray(
-                np.asarray(init_score).reshape(n, -1), jnp.float32)
-        score = init_margin
+        binned = (prebinned if prebinned is not None
+                  else _bin_on_device(mapper, X, measures))
         if mesh is not None:
-            score = jax.device_put(score, row2)
-            yj = jax.device_put(yj, row1)
-            wj = jax.device_put(wj, row1)
-            valid_mask = jax.device_put(valid_mask, row1)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from ..parallel.mesh import DATA_AXIS as _DA
+            row2 = NamedSharding(mesh, P(_DA, None))
+            row1 = NamedSharding(mesh, P(_DA))
+            with measures.span("shardRows"):
+                if multiproc:
+                    from ..parallel.mesh import to_global_rows
+                    binned = to_global_rows(mesh, P(_DA, None),
+                                            np.asarray(binned))
+                    n = n * jax.process_count()   # n is GLOBAL from here on
+                else:
+                    binned = jax.device_put(binned, row2)
+                jax.block_until_ready(binned)
+
+    # objective, and the per-row state on the device; the base score's
+    # np.asarray waits for the device, so the span ends with the work done
+    with measures.span("objectiveSetup"):
+        k = cfg.num_class if cfg.objective in ("multiclass", "softmax", "multiclassova") else 1
+        # lambdarank group index; 1-length dummy otherwise (it would replicate at
+        # GLOBAL length onto every device in multi-process mode)
+        gidx_arr = (np.zeros(1, np.int32) if multiproc else jnp.zeros(1, jnp.int32))
+        if cfg.objective == "lambdarank":
+            if group_sizes is None:
+                raise ValueError("lambdarank requires group_sizes")
+            if cfg.label_gain:
+                max_label = int(np.max(y)) if len(y) else 0
+                if max_label >= len(cfg.label_gain):
+                    # LightGBM fails fast here too ("Label ... is not less than
+                    # the number of label gains") — silent clipping would
+                    # optimize the wrong objective
+                    raise ValueError(
+                        f"label {max_label} needs a label_gain table of at "
+                        f"least {max_label + 1} entries, got "
+                        f"{len(cfg.label_gain)}")
+            gidx = make_grouped(y, group_sizes)
+            gidx_arr = jnp.asarray(gidx)
+            obj = lambdarank_objective(gidx_arr, cfg.sigmoid,
+                                       cfg.lambdarank_truncation_level,
+                                       cfg.label_gain)
+        else:
+            obj = get_objective(cfg.objective, num_class=k, sigmoid=cfg.sigmoid,
+                                alpha=cfg.alpha, fair_c=cfg.fair_c,
+                                poisson_max_delta_step=cfg.poisson_max_delta_step,
+                                tweedie_variance_power=cfg.tweedie_variance_power)
+
+        if ((cfg.pos_bagging_fraction < 1.0 or cfg.neg_bagging_fraction < 1.0)
+                and cfg.objective not in ("binary",)):
+            # native LightGBM rejects stratified bagging for non-binary objectives
+            raise ValueError("pos_bagging_fraction / neg_bagging_fraction require "
+                             f"objective='binary' (got {cfg.objective!r})")
+        if cfg.boosting_type == "rf" and not (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0
+                                              or cfg.feature_fraction < 1.0):
+            # native LightGBM rejects the same degenerate config (identical trees)
+            raise ValueError("boosting_type='rf' requires bagging (bagging_freq > 0 and "
+                             "bagging_fraction < 1) and/or feature_fraction < 1")
+
+        if multiproc:
+            from jax.sharding import PartitionSpec as P
+
+            from ..parallel.mesh import to_global_rows
+
+            yj = to_global_rows(mesh, P(_DA), y)
+            wj = to_global_rows(mesh, P(_DA), w)
+            valid_mask = to_global_rows(mesh, P(_DA), valid_mask_np)
+            if cfg.boost_from_average:
+                # base score from GLOBAL label stats: jit over the sharded labels
+                # inserts the cross-process reductions (one-shot per fit, so the
+                # throwaway jit wrapper is deliberate)
+                base_g = jax.jit(obj.init_score,  # lint-ok: recompile
+                                 out_shardings=NamedSharding(mesh, P()))(yj, wj)
+                base = np.atleast_1d(np.asarray(jax.device_get(base_g), np.float64))
+            else:
+                base = np.zeros(max(k, 1))
+            local_margin = (np.zeros((len(y), k), np.float32)
+                            + base[None, :k].astype(np.float32))
+            score = to_global_rows(mesh, P(_DA, None), local_margin)
+        else:
+            yj, wj = jnp.asarray(y), jnp.asarray(w)
+            valid_mask = jnp.asarray(valid_mask_np)
+            base = (np.atleast_1d(np.asarray(obj.init_score(yj, wj), np.float64))
+                    if cfg.boost_from_average else np.zeros(max(k, 1)))
+            # the fixed margin every iteration starts from: base score + init_score
+            init_margin = jnp.zeros((n, k)) + jnp.asarray(base[None, :k], jnp.float32)
+            if init_score is not None:
+                init_margin = init_margin + jnp.asarray(
+                    np.asarray(init_score).reshape(n, -1), jnp.float32)
+            score = init_margin
+            if mesh is not None:
+                score = jax.device_put(score, row2)
+                yj = jax.device_put(yj, row1)
+                wj = jax.device_put(wj, row1)
+                valid_mask = jax.device_put(valid_mask, row1)
 
     trees: List[TreeArrays] = []
     tree_weights: List[float] = []
@@ -1657,24 +1665,26 @@ def train_booster(
                 c = min(chunk, T - done)
 
                 def _run_chunk(_d=done, _c=c):
-                    cc, (st, mv_) = run_scan(
-                        binned, yj, wj, valid_mask, key0, is_cat, mono,
-                        nan_bins, cat_nbins, base_k, gidx_arr, bv_arg, yv_j,
-                        wv_j, gidx_v, *carry, _d, _c)
-                    # device_get INSIDE the guard: this transfer is the host
-                    # sync point where a hung peer's psum would stall forever
-                    return cc, (jax.device_get(st), mv_)
+                    # the wait INSIDE the guard: it is the host sync point
+                    # where a hung peer's psum would stall forever
+                    with measures.span("scanRun"):
+                        return jax.block_until_ready(run_scan(
+                            binned, yj, wj, valid_mask, key0, is_cat, mono,
+                            nan_bins, cat_nbins, base_k, gidx_arr, bv_arg,
+                            yv_j, wv_j, gidx_v, *carry, _d, _c))
 
                 if wd is not None:
                     carry, (stacked_trees, mv) = wd.run(
                         _run_chunk, op="gbdt.chunk")
                 else:
                     carry, (stacked_trees, mv) = _run_chunk()
-                for ti in range(c):
-                    for cls in range(k):
-                        trees.append(jax.tree.map(lambda a: a[ti, cls],
-                                                  stacked_trees))
-                        tree_weights.append(1.0)
+                with measures.span("treesReadback"):
+                    stacked_trees = jax.device_get(stacked_trees)
+                    for ti in range(c):
+                        for cls in range(k):
+                            trees.append(jax.tree.map(lambda a: a[ti, cls],
+                                                      stacked_trees))
+                            tree_weights.append(1.0)
                 done += c
                 stop = False
                 if has_valid:
@@ -1701,33 +1711,32 @@ def train_booster(
                     break
         score = carry[0]
         measures.count("iterations", done)
+        with measures.span("modelAssembly"):
+            best_iter = -1
+            if has_valid:
+                mvals = np.concatenate(mvals_list)
+                tdone = len(mvals)
+                series = mvals if higher_better else -mvals
+                # earliest best index (LightGBM keeps the first best)
+                bests = _best_so_far(series, cfg.improvement_tolerance)
+                stop = tdone - 1
+                if cfg.early_stopping_round > 0:
+                    waited = np.arange(tdone) - bests
+                    hit = np.nonzero(waited >= cfg.early_stopping_round)[0]
+                    if len(hit):
+                        stop = int(hit[0])
+                best_iter = int(bests[stop])
+                best_metric = float(mvals[best_iter])
+                if cfg.early_stopping_round > 0:
+                    cut = (best_iter + 1) * k
+                    trees = trees[:cut]
+                    tree_weights = tree_weights[:cut]
 
-        best_iter = -1
-        if has_valid:
-            mvals = np.concatenate(mvals_list)
-            tdone = len(mvals)
-            series = mvals if higher_better else -mvals
-            # earliest best index (LightGBM keeps the first best)
-            bests = _best_so_far(series, cfg.improvement_tolerance)
-            stop = tdone - 1
-            if cfg.early_stopping_round > 0:
-                waited = np.arange(tdone) - bests
-                hit = np.nonzero(waited >= cfg.early_stopping_round)[0]
-                if len(hit):
-                    stop = int(hit[0])
-            best_iter = int(bests[stop])
-            best_metric = float(mvals[best_iter])
-            if cfg.early_stopping_round > 0:
-                cut = (best_iter + 1) * k
-                trees = trees[:cut]
-                tree_weights = tree_weights[:cut]
-
-        trees = jax.device_get(trees)
-        return Booster(mapper, cfg, trees, tree_weights, base, feature_names,
-                       best_iteration=(best_iter if has_valid else -1),
-                       best_score=(best_metric if has_valid else None),
-                       metadata=_train_metadata(routing_info,
-                                                autoconfig_info, _fit_t0))
+            return Booster(mapper, cfg, trees, tree_weights, base, feature_names,
+                           best_iteration=(best_iter if has_valid else -1),
+                           best_score=(best_metric if has_valid else None),
+                           metadata=_train_metadata(routing_info,
+                                                    autoconfig_info, _fit_t0))
 
     # validation weights converted to device ONCE (per-iteration eval would
     # otherwise redo the H2D transfer every round)
@@ -1986,6 +1995,15 @@ def train_booster(
                    best_score=(best_metric if has_valid else None),
                    metadata=_train_metadata(routing_info,
                                             autoconfig_info, _fit_t0))
+
+
+def _bin_on_device(mapper, X, measures):
+    """Host float rows -> bin ids on the default device, each half timed
+    until it is done. The float32 device copy lives only in here."""
+    with measures.span("copyToDevice"):
+        Xd = jax.block_until_ready(jnp.asarray(X, jnp.float32))
+    with measures.span("binning"):
+        return jax.block_until_ready(apply_bins(mapper, Xd))
 
 
 def _train_fingerprint(cfg, n, nfeat, y, n_init_trees) -> str:

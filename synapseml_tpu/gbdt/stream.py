@@ -1136,7 +1136,7 @@ def train_booster_streamed(
             out.append(_put_vec(v) if resident else v)
         return out
 
-    with measures.span("trainingIteration"):
+    with measures.span("trainingIterations"):
         for t in range(start_iter, cfg.num_iterations):
             sw_list = _tree_sample_weights(t) if sampling else sw_ones
             if do_feat:

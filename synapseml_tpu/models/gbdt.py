@@ -38,6 +38,7 @@ from ..core import (Estimator, HasFeaturesCol, HasGroupCol, HasInitScoreCol,
                     HasLabelCol, HasPredictionCol, HasProbabilityCol,
                     HasRawPredictionCol, HasValidationIndicatorCol, HasWeightCol,
                     Model, Param, Table, feature_matrix)
+from ..core.logging import InstrumentationMeasures
 from ..gbdt.boosting import Booster, BoosterConfig, train_booster
 
 
@@ -417,6 +418,17 @@ class LightGBMClassifier(Estimator, _LightGBMParams, HasProbabilityCol, HasRawPr
     thresholds = Param("thresholds", "Per-class prediction thresholds", list)
 
     def _fit(self, df: Table) -> "LightGBMClassificationModel":
+        measures = InstrumentationMeasures()
+        with measures.span("tablePreparation"):
+            X, y, w, init, cfg, valid, classes = self._prepare(df)
+        booster = self._run_batches(X, y, w, init, cfg, valid, measures)
+        model = LightGBMClassificationModel(booster)
+        model.classes_ = classes.astype(np.float64)
+        self._copy_model_params(model)
+        return model
+
+    def _prepare(self, df: Table):
+        """Columns to arrays, labels to class ids, params to a config."""
         train_df, valid_df = self._split_validation(df)
         X, y, w, init = self._extract_training_arrays(train_df)
         # map arbitrary label values to 0..K-1 (objectives assume contiguous
@@ -450,19 +462,12 @@ class LightGBMClassifier(Estimator, _LightGBMParams, HasProbabilityCol, HasRawPr
             Xv, yv, _, _ = self._extract_training_arrays(valid_df)
             yv = np.searchsorted(classes, yv).astype(np.float32)
             valid = (Xv, yv)
+        return X, y, w, init, cfg, valid, classes
 
-        booster = self._run_batches(X, y, w, init, cfg, valid)
-        model = LightGBMClassificationModel(booster)
-        model.classes_ = classes.astype(np.float64)
-        self._copy_model_params(model)
-        return model
-
-    def _run_batches(self, X, y, w, init, cfg, valid):
+    def _run_batches(self, X, y, w, init, cfg, valid, measures):
         """numBatches warm-started sequential fits (LightGBMBase.scala:39-64),
-        instrumented with phase spans (LightGBMPerformance analog, §5.1)."""
-        from ..core.logging import InstrumentationMeasures
-
-        measures = InstrumentationMeasures()
+        instrumented with phase spans (LightGBMPerformance analog, §5.1);
+        logs the fit's ``trainingMeasures`` record (docs/observability.md)."""
         cats = self._categorical_indexes(self.get("slotNames"))
         init_model = None
         if self.get("modelString"):
@@ -558,16 +563,18 @@ class LightGBMRegressor(Estimator, _LightGBMParams):
     _copy_model_params = LightGBMClassifier._copy_model_params
 
     def _fit(self, df: Table) -> "LightGBMRegressionModel":
-        train_df, valid_df = self._split_validation(df)
-        X, y, w, init = self._extract_training_arrays(train_df)
-        cfg = self._base_config(objective=self.getObjective(),
-                                alpha=self.getAlpha(),
-                                tweedie_variance_power=self.getTweedieVariancePower())
-        valid = None
-        if valid_df is not None and valid_df.num_rows:
-            Xv, yv, _, _ = self._extract_training_arrays(valid_df)
-            valid = (Xv, yv)
-        booster = self._run_batches(X, y, w, init, cfg, valid)
+        measures = InstrumentationMeasures()
+        with measures.span("tablePreparation"):
+            train_df, valid_df = self._split_validation(df)
+            X, y, w, init = self._extract_training_arrays(train_df)
+            cfg = self._base_config(objective=self.getObjective(),
+                                    alpha=self.getAlpha(),
+                                    tweedie_variance_power=self.getTweedieVariancePower())
+            valid = None
+            if valid_df is not None and valid_df.num_rows:
+                Xv, yv, _, _ = self._extract_training_arrays(valid_df)
+                valid = (Xv, yv)
+        booster = self._run_batches(X, y, w, init, cfg, valid, measures)
         model = LightGBMRegressionModel(booster)
         self._copy_model_params(model)
         return model
@@ -597,28 +604,32 @@ class LightGBMRanker(Estimator, _LightGBMParams, HasGroupCol):
     _copy_model_params = LightGBMClassifier._copy_model_params
 
     def _fit(self, df: Table) -> "LightGBMRankerModel":
-        train_df, valid_df = self._split_validation(df)
-        gcol = self.getGroupCol()
-        train_df = train_df.sort_by(gcol)       # group-contiguous layout
-        X, y, w, init = self._extract_training_arrays(train_df)
-        groups = np.asarray(train_df[gcol])
-        _, sizes = np.unique(groups, return_counts=True)
-        cfg = self._base_config(objective="lambdarank",
-                                lambdarank_truncation_level=self.getMaxPosition(),
-                                eval_at=tuple(self.getEvalAt()),
-                                label_gain=tuple(self.get("labelGain") or ()))
-        valid = None
-        if valid_df is not None and valid_df.num_rows:
-            valid_df = valid_df.sort_by(gcol)
-            Xv, yv, _, _ = self._extract_training_arrays(valid_df)
-            _, sv = np.unique(np.asarray(valid_df[gcol]), return_counts=True)
-            valid = (Xv, yv, None, sv)
+        measures = InstrumentationMeasures()
+        with measures.span("tablePreparation"):
+            train_df, valid_df = self._split_validation(df)
+            gcol = self.getGroupCol()
+            train_df = train_df.sort_by(gcol)       # group-contiguous layout
+            X, y, w, init = self._extract_training_arrays(train_df)
+            groups = np.asarray(train_df[gcol])
+            _, sizes = np.unique(groups, return_counts=True)
+            cfg = self._base_config(objective="lambdarank",
+                                    lambdarank_truncation_level=self.getMaxPosition(),
+                                    eval_at=tuple(self.getEvalAt()),
+                                    label_gain=tuple(self.get("labelGain") or ()))
+            valid = None
+            if valid_df is not None and valid_df.num_rows:
+                valid_df = valid_df.sort_by(gcol)
+                Xv, yv, _, _ = self._extract_training_arrays(valid_df)
+                _, sv = np.unique(np.asarray(valid_df[gcol]), return_counts=True)
+                valid = (Xv, yv, None, sv)
         cats = self._categorical_indexes(self.get("slotNames"))
         booster = train_booster(X, y, cfg, sample_weight=w, init_score=init,
                                 categorical_features=cats, group_sizes=sizes,
                                 valid=valid, feature_names=self.get("slotNames"),
                                 fobj=self.get("fobj"),
-                                mapper=self._reference_mapper(X))
+                                mapper=self._reference_mapper(X),
+                                measures=measures)
+        self._log_base("trainingMeasures", measures.report())
         model = LightGBMRankerModel(booster)
         self._copy_model_params(model)
         return model

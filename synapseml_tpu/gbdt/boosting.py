@@ -28,7 +28,8 @@ import numpy as np
 from jax import lax
 
 from ..core import tuned as _tuned
-from ..ops.quantize import BinMapper, apply_bins, bin_threshold_to_value, compute_bin_mapper
+from ..ops.quantize import (BinMapper, apply_bins, bin_threshold_to_value,
+                            bins_by_compare, compute_bin_mapper)
 
 # default_factory marker for engine knobs resolved via core/tuned.py: lets
 # __post_init__ distinguish "user passed nothing" from an explicit value
@@ -2003,7 +2004,10 @@ def _bin_on_device(mapper, X, measures):
     with measures.span("copyToDevice"):
         Xd = jax.block_until_ready(jnp.asarray(X, jnp.float32))
     with measures.span("binning"):
-        return jax.block_until_ready(apply_bins(mapper, Xd))
+        binned = jax.block_until_ready(apply_bins(mapper, Xd))
+    measures.count("binnedValuesCompare" if bins_by_compare(mapper)
+                   else "binnedValuesSearch", X.size)
+    return binned
 
 
 def _train_fingerprint(cfg, n, nfeat, y, n_init_trees) -> str:

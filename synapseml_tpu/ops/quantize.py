@@ -4,13 +4,19 @@ The reference computes LightGBM bin boundaries on the driver from a row sample a
 broadcasts a serialized reference dataset to all workers (LightGBMBase.scala:509-550,
 dataset/ReferenceDatasetUtils.scala, dataset/SampledData.scala). Here the bin
 boundaries are computed host-side with numpy from a sample (exact same role), and
-binning itself is a jitted XLA op so the (N, F) → (N, F) uint8/uint16 quantized
-matrix is produced TPU-resident.
+binning itself (:func:`apply_bins`) is one jitted XLA program, so the (N, F) →
+(N, F) uint8/uint16 quantized matrix is produced TPU-resident, with nothing
+else of that size in the device's memory on the way. A value's bin is found by
+counting the feature's boundaries below it, one dense compare-and-add pass
+over the boundary axis; only a mapper with more than
+``COMPARE_MAX_BOUNDARIES`` boundaries a feature is searched instead
+(``jnp.searchsorted``, a gather per round and value). Both give the same
+integers.
 
 Bin semantics (matching LightGBM's BinMapper):
   * boundaries[f] is a sorted vector of bin upper bounds (length <= max_bin - 1);
-    bin(x) = first i with x <= boundaries[f][i]; x beyond all bounds → last
-    real-value bin.
+    bin(x) = first i with x <= boundaries[f][i] = #{i : boundaries[f][i] < x};
+    x beyond all bounds → last real-value bin.
   * Features containing NaN get a DEDICATED missing bin at index
     ``num_bins[f] - 1`` (missing_type=NaN); the split finder then learns the
     missing direction per split (``default_left``), matching LightGBM's
@@ -159,7 +165,8 @@ def compute_bin_mapper(
             # (LightGBM minDataPerBin): drop a boundary when the bin it
             # closes is under-filled
             # right-closed counting (x <= boundary belongs to the LEFT bin),
-            # matching apply_bins' searchsorted side='left' semantics
+            # matching apply_bins: a value's bin is the number of boundaries
+            # strictly below it, which is searchsorted(side='left')
             counts = np.bincount(np.searchsorted(b, col, side="left"),
                                  minlength=b.size + 1)
             keep = []
@@ -312,12 +319,54 @@ class StreamingQuantileSketch:
             cat_presence=self._cat_pres)
 
 
-@partial(jax.jit, static_argnames=("out_dtype",))
-def _apply_bins_numeric(X: jnp.ndarray, boundaries: jnp.ndarray, out_dtype=jnp.uint8):
-    def bin_one_feature(col, bounds):
-        return jnp.searchsorted(bounds, col, side="left")
+# Where apply_bins stops counting and starts searching. By operations a
+# count is one dense compare-and-add per boundary and value, the search
+# ceil(log2(boundaries + 1)) dependent gathers per value. On the chip a gather
+# round costs what some ten thousand compare-and-adds do (PERF.md section 6,
+# PR 26: 3.5 M x 28 values counted in 84 ms at 254 boundaries and 489 ms at
+# 4,095, searched in 8.5 s and 12.7 s), so there the count wins at any width.
+# It is the backends with cheap gathers that set the threshold: on the CPU
+# the count costs 3 times the search at 254 boundaries and 17 times at 1,023.
+# 512 keeps every uint8 mapper, the default max_bin of 255 among them, and
+# the first uint16 ones on the dense path, and leaves the rare wide mapper,
+# whose count grows with its width, to the search.
+COMPARE_MAX_BOUNDARIES = 512
 
-    binned = jax.vmap(bin_one_feature, in_axes=(1, 0), out_axes=1)(X, boundaries)
+
+def bins_by_compare(mapper: BinMapper) -> bool:
+    """Whether :func:`apply_bins` counts boundaries (True) or searches them
+    (False) for this mapper: decided by its boundary count alone."""
+    return mapper.boundaries.shape[1] <= COMPARE_MAX_BOUNDARIES
+
+
+@partial(jax.jit, static_argnames=("has_categorical", "out_dtype"))
+def _apply_bins(X, boundaries, real_limit, nanbin, nan_mask, is_categorical,
+                cat_cap, has_categorical=False, out_dtype=jnp.uint8):
+    """The whole of :func:`apply_bins` as one program. Everything a mapper
+    holds is an argument, so a new mapper of the same shape compiles nothing."""
+    nb = boundaries.shape[1]
+    if nb <= COMPARE_MAX_BOUNDARIES:
+        # bin(x) = #{k : boundaries[f, k] < x}: searchsorted(side="left") for
+        # every x but NaN, which counts 0 here. The boundary axis is the
+        # reduction's major axis, so the (nb, N, F) comparison lives in
+        # registers only; summed in the output's own width, which holds nb,
+        # the count needs no wider (N, F) array either
+        count = jnp.sum(boundaries.T[:, None, :] < X[None, :, :], axis=0,
+                        dtype=out_dtype)
+    else:
+        count = jax.vmap(partial(jnp.searchsorted, side="left"),
+                         in_axes=(0, 1), out_axes=1)(boundaries, X)
+    isnan = jnp.isnan(X)
+    # NaN sorts after every boundary, as the search has it
+    binned = jnp.where(isnan, nb, count.astype(jnp.int32))
+    # clamp real values into the feature's real-value bin range
+    binned = jnp.minimum(binned, real_limit[None, :])
+    # NaN -> dedicated NaN bin (num_bins-1) for has_nan features
+    binned = jnp.where(isnan & nan_mask[None, :], nanbin[None, :], binned)
+    if has_categorical:
+        ident = jnp.clip(jnp.where(isnan, 0.0, X), 0, cat_cap).astype(jnp.int32)
+        ident = jnp.minimum(ident, nanbin[None, :])
+        binned = jnp.where(is_categorical[None, :], ident, binned)
     return binned.astype(out_dtype)
 
 
@@ -325,26 +374,14 @@ def apply_bins(mapper: BinMapper, X) -> jnp.ndarray:
     """(N, F) raw floats → (N, F) bin ids. Non-NaN overflow clamps into the
     last REAL-value bin; NaN goes to the feature's dedicated NaN bin when it
     has one (else the last bin, the legacy always-right behavior)."""
-    dtype = jnp.uint8 if mapper.max_bin <= 256 else jnp.uint16
-    X = jnp.asarray(X, jnp.float32)
-    binned = _apply_bins_numeric(X, jnp.asarray(mapper.boundaries), dtype)
-    nan_mask = jnp.asarray(mapper.nan_mask)
-    isnan = jnp.isnan(X)
-    # clamp real values into the feature's real-value bin range
-    real_limit = jnp.asarray(
-        mapper.num_bins - 1 - mapper.nan_mask.astype(np.int32), np.int32)
-    binned = jnp.minimum(binned.astype(jnp.int32), real_limit[None, :])
-    # NaN → dedicated NaN bin (num_bins-1) for has_nan features
-    nanbin = jnp.asarray(mapper.num_bins - 1, np.int32)
-    binned = jnp.where(isnan & nan_mask[None, :], nanbin[None, :], binned)
-    binned = binned.astype(dtype)
-    if mapper.is_categorical.any():
-        cats = jnp.asarray(mapper.is_categorical)
-        limit = jnp.asarray(mapper.num_bins - 1, binned.dtype)
-        ident = jnp.clip(jnp.nan_to_num(X, nan=0.0), 0, mapper.max_bin - 1).astype(binned.dtype)
-        ident = jnp.minimum(ident, limit[None, :])
-        binned = jnp.where(cats[None, :], ident, binned)
-    return binned
+    nan_mask = mapper.nan_mask
+    nanbin = np.asarray(mapper.num_bins, np.int32) - 1
+    return _apply_bins(
+        jnp.asarray(X, jnp.float32), mapper.boundaries,
+        nanbin - nan_mask.astype(np.int32), nanbin, nan_mask,
+        mapper.is_categorical, np.float32(mapper.max_bin - 1),
+        has_categorical=bool(mapper.is_categorical.any()),
+        out_dtype=jnp.uint8 if mapper.max_bin <= 256 else jnp.uint16)
 
 
 @partial(jax.jit, static_argnames=("n_rows", "out_dtype"))

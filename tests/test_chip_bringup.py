@@ -192,6 +192,26 @@ def test_flash_kernels_compile_for_v5e(v5e, shape, dt, causal):
         x, x, x, ml, ml, v5e(shape, jnp.float32), i32, i32).compile()
 
 
+@pytest.mark.parametrize("boundaries,out_dtype", [(254, jnp.uint8),
+                                                  (512, jnp.uint16)])
+@pytest.mark.parametrize("has_categorical", [False, True])
+def test_dense_binning_has_no_full_size_temporary_on_v5e(
+        v5e, boundaries, out_dtype, has_categorical):
+    """``apply_bins`` at the benchmark cell's size: the float32 rows in, the
+    bins out, and beside them nothing of (N, F) in the device's memory."""
+    from synapseml_tpu.ops.quantize import COMPARE_MAX_BOUNDARIES, _apply_bins
+
+    assert boundaries <= COMPARE_MAX_BOUNDARIES
+    n, f = 3_500_000, 28
+    per_feature = lambda dt: v5e((f,), dt)
+    compiled = _apply_bins.lower(
+        v5e((n, f), jnp.float32), v5e((f, boundaries), jnp.float32),
+        per_feature(jnp.int32), per_feature(jnp.int32),
+        per_feature(jnp.bool_), per_feature(jnp.bool_), v5e((), jnp.float32),
+        has_categorical=has_categorical, out_dtype=out_dtype).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * f
+
+
 # ---------------------------------------------------------------------------
 # compile cache placement
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from synapseml_tpu.core.logging import InstrumentationMeasures, SpanRecord
+from synapseml_tpu.ops.quantize import compute_bin_mapper
 
 # sha256 of train_booster's model string on _booster_data(), 5 iterations,
 # seed 7, recorded from the parent commit (3f00fef) before the spans moved
@@ -308,10 +309,27 @@ def test_binning_spans_close_after_the_wait(monkeypatch):
         asarray=make("copyToDevice"), float32=np.float32))
     monkeypatch.setattr(boosting, "apply_bins", make("binning"))
     m = Spy()
-    out = boosting._bin_on_device(None, np.zeros((4, 2), np.float32), m)
+    X = np.zeros((4, 2), np.float32)
+    out = boosting._bin_on_device(compute_bin_mapper(X), X, m)
     assert out is made["binning"]
     assert waited_at_close == {"copyToDevice": True, "binning": True}
     assert [r.name for r in m.records] == ["copyToDevice", "binning"]
+
+
+@pytest.mark.parametrize("max_bin,key", [(255, "binnedValuesCompare"),
+                                         (2048, "binnedValuesSearch")])
+def test_fit_counts_the_values_binned_by_each_path(max_bin, key):
+    """The path follows the mapper's boundary count (``max_bin`` - 1), and
+    the fit's record says how many values went down it."""
+    from synapseml_tpu.gbdt import BoosterConfig, train_booster
+
+    X, y = _booster_data(n=1500)
+    m = InstrumentationMeasures()
+    train_booster(X, y, BoosterConfig(num_iterations=2, seed=7,
+                                      max_bin=max_bin), measures=m)
+    counted = {k: v for k, v in m.report().items()
+               if k.startswith("count:binnedValues")}
+    assert counted == {f"count:{key}": X.shape[0] * X.shape[1]}
 
 
 def test_booster_on_a_mesh_times_the_row_placement():

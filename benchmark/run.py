@@ -118,11 +118,31 @@ def _device(chips: int, rehearsal: bool):
 
 
 def _memory_peak(devs) -> int:
+    """The fullest device's peak: its arrays (``peak_bytes_in_use``) and
+    what the runtime set aside for the compiled programs' temporaries
+    (``peak_bytes_reserved``), which the TPU's allocator keeps in a
+    reservation of its own and leaves out of the first number."""
     peak = 0
     for d in devs:
         stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0))
+                   + int(stats.get("peak_bytes_reserved", 0)))
     return peak
+
+
+def use_compile_cache() -> None:
+    """The compile cache: where the environment says, else one fixed
+    directory inside the checkout; everything is cached, however small. The
+    variable is set too: the program sets no directory of its own where it
+    finds it (core/compile_cache.py)."""
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        ROOT, ".bench_cache", "jax")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", cache)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 def _merge_rehearsal(d: dict) -> dict:
@@ -152,18 +172,9 @@ def main(argv=None) -> int:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={cell['chips']}")
 
-    # the compile cache: where the environment says, else one fixed
-    # directory inside the checkout; everything is cached, however small.
-    # The variable is set too: the program sets no directory of its own
-    # where it finds it (core/compile_cache.py)
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-        ROOT, ".bench_cache", "jax")
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
+    use_compile_cache()
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     devs = _device(int(cell["chips"]), args.rehearsal)
     compiles = CompileEvents()
 
@@ -209,7 +220,8 @@ def main(argv=None) -> int:
     per_layer_ctx = {
         "entry": entry, "config": config, "chips": int(cell["chips"]),
         "compile_s": compile_s, "device_kind": devs[0].device_kind,
-        "trees": entry.trees() if args.trace else None,
+        "trees": (entry.trees() if args.trace and hasattr(entry, "trees")
+                  else None),
     }
     entry.release()
     ref_mod = _load_module("references", cell["config"])

@@ -12,10 +12,15 @@ import pytest
 from benchmark import run as harness
 
 BENCH = harness._load_json(harness.ROOT, "BENCHMARK.json")
-BOOSTER_CELLS = [w["name"] for w in BENCH["workloads"]
-                 if harness._load_json(harness.HERE, "traffic",
-                                       w["traffic"] + ".json")["entry"]
-                 == "booster_fit"]
+
+
+def _cells(entry):
+    return [w["name"] for w in BENCH["workloads"]
+            if harness._load_json(harness.HERE, "traffic",
+                                  w["traffic"] + ".json")["entry"] == entry]
+
+
+BOOSTER_CELLS = _cells("booster_fit")
 
 
 def _run(capsys, workload, trace=0):
@@ -112,3 +117,100 @@ def test_control_is_not_correct():
     # ones: the rows and the grid are the program's own
     assert control["count_gap"] == 0 and control["grid_gap"] == 0
     assert control["leaf_gap"] > 3 * limits["leaf_gap"]
+
+
+# -- the trainer's cells ----------------------------------------------------------
+
+TRAINER_CELLS = _cells("trainer_fit")
+# each planted fault, and the number that is meant to catch it
+TRAINER_FAULTS = {"state_unchanged": "change_gap", "half_batch": "grad_gap",
+                  "mask_dropped": "grad_difference"}
+
+
+def _break_trainer(monkeypatch, how):
+    """Break the training step underneath the estimator."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    import synapseml_tpu.dl.text as text
+    import synapseml_tpu.dl.trainer as trainer
+
+    real_tx = trainer._make_tx
+    real_ce = optax.softmax_cross_entropy_with_integer_labels
+
+    def make_tx(cfg, total_steps, mask=None):
+        tx = real_tx(cfg, total_steps, mask)
+        if how == "state_unchanged":
+            # a step that hands parameters and moments on as they came
+            return optax.GradientTransformation(
+                tx.init, lambda g, s, p=None: (
+                    jax.tree.map(jnp.zeros_like, g), s))
+        return tx
+
+    monkeypatch.setattr(trainer, "_make_tx", make_tx)
+    if how == "half_batch":
+        # half of the batch left out, the mean taken over the rest
+        monkeypatch.setattr(
+            optax, "softmax_cross_entropy_with_integer_labels",
+            lambda logits, labels: real_ce(logits, labels)[
+                : labels.shape[0] // 2])
+    if how == "mask_dropped":
+        monkeypatch.setattr(text, "PAD_ID", -1)   # no id is padding
+
+
+@pytest.mark.parametrize("workload", TRAINER_CELLS)
+def test_sound_trainer_run_is_correct(capsys, workload):
+    rc, line, out = _run(capsys, workload)
+    assert rc == harness.REHEARSAL_EXIT
+    assert line["correct"] is True and line["rehearsal"] is True
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {"loss_gap", "grad_gap", "grad_difference",
+                                     "change_gap", "step_count_gap",
+                                     "window_compiles"}
+    for name, c in line["compared"].items():
+        assert c["value"] <= c["limit"], name
+        assert f"compared {name}:" in out.err
+    assert "compile events in the window: 0" in out.out
+    assert line["attempted"] >= 1 and line["failed"] == 0
+    assert set(line["metrics"]) == {"setup_s", "train_samples_per_s_chip"}
+
+
+@pytest.mark.parametrize("how", sorted(TRAINER_FAULTS))
+@pytest.mark.parametrize("workload", TRAINER_CELLS)
+def test_planted_trainer_fault_is_not_correct(capsys, monkeypatch, workload,
+                                              how):
+    _break_trainer(monkeypatch, how)
+    _, line, _ = _run(capsys, workload)
+    assert line["correct"] is False
+    caught = line["compared"][TRAINER_FAULTS[how]]
+    assert caught["value"] > caught["limit"]
+    # the optimizer's own count stands still with its state, and only then
+    assert (line["compared"]["step_count_gap"]["value"] > 0) == (
+        how == "state_unchanged")
+
+
+def test_trainer_control_and_stand_ins_are_not_correct():
+    """The reference with float8 operands in the program's place, and every
+    fault planted in the reference, at the rehearsal's size."""
+    _, _, config, traffic = harness.load_cell(TRAINER_CELLS[0], True)
+    ref_mod = harness._load_module("references", "bert_base_ft")
+    made = harness._load_module("entries", "trainer_fit")
+    entry = made.Entry(config, traffic, 2147483659, 1)
+    entry.setup()
+    entry.unit()
+    inputs = entry.check_inputs()
+    entry.release()
+    limits = config["limits"]
+    plans = ref_mod.stand_in_plans(config)
+    assert plans["control"] == {"value_type": "float8_e4m3fn"}
+    ref = ref_mod.Reference(config, inputs["texts"], inputs["labels"],
+                            inputs["seed"], inputs["batch"], inputs["steps"])
+    sound = ref_mod.check(config, inputs, reference=ref)
+    assert all(sound[k] <= limits[k] for k in sound)
+    for name, how in plans.items():
+        numbers = ref_mod.check(config, inputs, how, reference=ref)
+        assert any(numbers[k] > limits[k] for k in numbers), name
+    unchanged = ref_mod.check(config, inputs, plans["state_unchanged"],
+                              reference=ref)
+    assert unchanged["grad_gap"] == 1.0 and unchanged["change_gap"] == 1.0

@@ -14,6 +14,11 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
+def _rate(workload: dict) -> str:
+    return harness._load_json(harness.HERE, "traffic",
+                              workload["traffic"] + ".json")["rate_metric"]
+
+
 def test_every_cell_finds_its_files():
     for w in BENCH["workloads"]:
         cfg = harness._load_json(harness.HERE, "configs",
@@ -23,7 +28,14 @@ def test_every_cell_finds_its_files():
         assert harness._load_module("entries", traffic["entry"]) is not None
         ref = harness._load_module("references", w["config"])
         assert hasattr(ref, "check")
-        assert set(cfg["limits"]) >= {"gain_gap", "leaf_gap", "loss_gap"}
+        # what each entry's reference compares, beside the exact numbers
+        compared = {"booster_fit": {"gain_gap", "leaf_gap", "loss_gap"},
+                    "trainer_fit": {"loss_gap", "grad_gap", "grad_difference",
+                                    "change_gap", "step_count_gap"}}
+        assert set(cfg["limits"]) >= compared[traffic["entry"]]
+        assert all(v >= 0 for v in cfg["limits"].values())
+        assert os.path.exists(os.path.join(harness.HERE, "data",
+                                           cfg["rehearsal_trace"]))
         assert traffic["rate_metric"] in {m["name"]
                                           for m in BENCH["end_to_end"]}
 
@@ -59,7 +71,7 @@ def test_no_cell_is_named_in_the_harness_code():
         c["name"] for c in BENCH["configs"]} | {
         w["traffic"] for w in BENCH["workloads"]}
     for name in ("run.py", "trace.py", "counts.py", "kernels.py",
-                 "tables.py", "peaks.py"):
+                 "tables.py", "texts.py", "trainer_record.py", "peaks.py"):
         with open(os.path.join(harness.HERE, name)) as f:
             code = f.read()
         for w in words:
@@ -76,6 +88,14 @@ def test_applies():
         BENCH["workloads"][0]["name"], rehearsal=True)
     assert config["numIterations"] == config["rehearsal"]["numIterations"]
     assert config["table"]["features"] == 28      # merged, not replaced
+    # a per-layer metric without a list is read in every cell that reports
+    # the end-to-end metric it moves, and in no other
+    listless = [m for m in BENCH["per_layer"] if "workloads" not in m]
+    for w in BENCH["workloads"]:
+        rate = _rate(w)
+        for m in listless:
+            assert harness._applies(m, w["name"], {"setup_s", rate}) == (
+                m["moves"] in ("setup_s", rate))
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
@@ -94,3 +114,27 @@ def test_traced_rehearsal_reads_the_recorded_trace(capsys, workload):
     d = line["device"]
     assert d["platform"] == "cpu" and 0 < d["busy_s"] <= d["window_s"]
     assert len(line["breakdown"]["device_ops"]) <= 10
+
+
+def test_every_cell_reports_setup_another_end_to_end_and_a_per_layer_metric():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    for w in BENCH["workloads"]:
+        rate = _rate(w)
+        assert w["name"] in e2e[rate].get("workloads", [w["name"]])
+        assert any(harness._applies(m, w["name"], {"setup_s", rate})
+                   and m["moves"] == rate for m in BENCH["per_layer"])
+    # an end-to-end metric that exists only in some cells lists them
+    assert {_rate(w) for w in BENCH["workloads"]} | {"setup_s"} == set(e2e)
+
+
+def test_memory_peak_counts_arrays_and_the_programs_reservation():
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    devs = [Dev({"peak_bytes_in_use": 10, "peak_bytes_reserved": 5}),
+            Dev({"peak_bytes_in_use": 12}), Dev(None)]
+    assert harness._memory_peak(devs) == 15

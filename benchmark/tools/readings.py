@@ -3,16 +3,36 @@ runs a cell as always (the timed path at the timed size) and then, on the
 same answer, reads the control (the reference in the next lower precision
 put in the program's place) and the planted faults, and writes them with the
 run's own numbers (the lower reading) to FILE. No benchmark run does this.
+
+Where set-up is most of a run, the lower readings of many seeds are read in
+one process, without a window (a training cell's judged steps lie in its
+set-up):
+
+    python3 -m benchmark.tools.readings --workload bert_base_fit \
+        --seeds 11,12,13,14 --stand-ins 2 --out chiprun_out/readings.jsonl
+
+reads the program on every seed and the control and the faults on the first
+``--stand-ins`` of them, one JSON line a seed.
 """
 
 from __future__ import annotations
 
+import argparse
 import copy
 import json
 import os
+import sys
 
 
-def stand_ins(ref_mod, config: dict, traffic: dict, inputs: dict) -> dict:
+def stand_ins(ref_mod, config: dict, traffic: dict, inputs: dict,
+              only=None) -> dict:
+    """{name: numbers} of the control and the faults (``only``: of those
+    named, none for an empty tuple)."""
+    if hasattr(ref_mod, "stand_ins"):
+        # a reference that brings its own control and faults
+        return ref_mod.stand_ins(config, traffic, inputs, only)
+    if only is not None:
+        raise ValueError("this reference's stand-ins are read all or not")
     params = config["params"]
     value_type = params["histogram_values"]
     ref = ref_mod.Reference(params, inputs["X"], inputs["y"])
@@ -48,3 +68,43 @@ def write(path, ref_mod, config, traffic, inputs, seed, program) -> None:
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     with open(path, "w") as f:
         json.dump(row, f, indent=1)
+
+
+def main(argv=None) -> int:
+    from benchmark import run as harness
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True, help="comma-separated")
+    ap.add_argument("--stand-ins", type=int, default=0)
+    ap.add_argument("--rehearsal", action="store_true")
+    ap.add_argument("--out", required=True)
+    args = ap.parse_args(argv)
+    _, cell, config, traffic = harness.load_cell(args.workload,
+                                                 args.rehearsal)
+    harness.use_compile_cache()
+    harness._device(int(cell["chips"]), args.rehearsal)
+    ref_mod = harness._load_module("references", cell["config"])
+    entry_mod = harness._load_module("entries", traffic["entry"])
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
+        entry = entry_mod.Entry(config, traffic, seed, int(cell["chips"]))
+        entry.setup()
+        inputs = entry.check_inputs()
+        entry.release()
+        del entry
+        row = {"seed": seed}
+        if i < args.stand_ins:
+            row.update(stand_ins(ref_mod, config, traffic, inputs))
+        elif hasattr(ref_mod, "stand_ins"):
+            row.update(stand_ins(ref_mod, config, traffic, inputs, ()))
+        # a reference's own stand-ins bring the program's numbers too
+        row.setdefault("program", ref_mod.check(config, inputs))
+        with open(args.out, "a") as f:
+            f.write(json.dumps(row) + "\n")
+        print(json.dumps(row), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

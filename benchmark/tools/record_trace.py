@@ -1,8 +1,12 @@
-"""Record a small trace of one fit on the chip, in the reduced form the tests
-read (benchmark/data), and print what the profiler's file holds:
+"""Record a small trace of one unit of work on the chip, in the reduced form
+the tests read (benchmark/data), and print what the profiler's file holds:
 
     python3 -m benchmark.tools.record_trace --workload higgs_fit \
         --rows 200000 --iterations 2 --out chiprun_out/trace_small.json.gz
+    python3 -m benchmark.tools.record_trace --workload bert_base_fit \
+        --rehearsal --out chiprun_out/trace_trainer_small.json.gz
+
+``--rehearsal`` takes the sizes the cell's files give under ``rehearsal``.
 """
 
 from __future__ import annotations
@@ -20,15 +24,19 @@ from benchmark import trace as tr
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--rows", type=int, required=True)
-    ap.add_argument("--iterations", type=int, required=True)
+    ap.add_argument("--rows", type=int)
+    ap.add_argument("--iterations", type=int)
+    ap.add_argument("--rehearsal", action="store_true")
     ap.add_argument("--out", required=True)
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
 
-    _, cell, config, traffic = harness.load_cell(args.workload)
-    config["table"]["rows_per_chip"] = args.rows
-    config["numIterations"] = args.iterations
+    _, cell, config, traffic = harness.load_cell(args.workload,
+                                                 args.rehearsal)
+    if args.rows:
+        config["table"]["rows_per_chip"] = args.rows
+    if args.iterations:
+        config["numIterations"] = args.iterations
     import jax
 
     entry = harness._load_module("entries", traffic["entry"]).Entry(
@@ -60,7 +68,7 @@ def main(argv=None) -> int:
     trace = tr.clip(trace, *window)
     # host lines: keep the annotations only, the runtime's own events are many
     keep = ("bench.", "trainingIterations", "dataPreparation",
-            "referenceDataset", "LightGBM")
+            "referenceDataset", "LightGBM", "trainer.", "DeepText")
     for plane in trace["planes"]:
         if plane["name"].startswith("/host:CPU"):
             for line in plane["lines"]:
@@ -79,12 +87,15 @@ def main(argv=None) -> int:
                if "hist" in k.lower() or "custom" in k.lower()})
     print("host annotations:", collections.Counter(
         e[0] for e in tr.host_annotations(trace)).most_common(20))
-    print("spans", entry.spans, "fit_s", entry.fit_seconds)
+    print("spans", getattr(entry, "spans", None), "fit_s",
+          getattr(entry, "fit_seconds", None))
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     trace["device_kind"] = jax.devices()[0].device_kind
     tr.save(trace, args.out)
     print("saved", args.out, os.path.getsize(args.out), "bytes")
     shutil.rmtree(tdir, ignore_errors=True)
+    entry.check_inputs()         # an entry that runs a thread ends it here
+    entry.release()
     return 0
 
 

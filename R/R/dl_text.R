@@ -4,6 +4,7 @@
 
 #' DeepTextClassifier
 #' Base of every stage. Subclasses are constructible from kwargs alone plus
+#' @param architecture A layer-type-list backbone in place of the dense encoder, by the source's own config.json keys: hybrid_override_pattern (one of M Mamba-2, E experts, * causal attention for each of numLayers), mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size, conv_kernel, chunk_size, num_key_value_heads, head_dim, n_routed_experts (the router's width), num_experts_per_tok, moe_intermediate_size, moe_shared_expert_intermediate_size, routed_scaling_factor, norm_eps; held_experts lists the expert ids this chip holds (dl/hybrid.py). hiddenSize, numLayers, numHeads, vocabSize and maxTokenLen apply as they do to the encoder
 #' @param batchSize Training batch size
 #' @param checkpoint Local HuggingFace Flax checkpoint dir (optional)
 #' @param hiddenSize Hidden width
@@ -31,6 +32,7 @@ ml_deep_text_classifier <- function(...) {
 
 #' DeepTextModel
 #' A fitted Transformer produced by an Estimator.
+#' @param architecture The layer-type-list backbone the model was trained with (DeepTextClassifier.architecture); unset for the dense encoder
 #' @param hiddenSize Hidden width
 #' @param maxTokenLen Truncation length
 #' @param numHeads Attention heads

@@ -119,6 +119,18 @@ class DeepTextClassifier(Estimator, HasLabelCol, HasPredictionCol):
     seqAttention = Param(
         "seqAttention", "Sequence-attention variant: auto (perfmodel-routed) "
         "/ ring / ulysses", str, "auto")
+    architecture = Param(
+        "architecture", "A layer-type-list backbone in place of the dense "
+        "encoder, by the source's own config.json keys: "
+        "hybrid_override_pattern (one of M Mamba-2, E experts, * causal "
+        "attention for each of numLayers), mamba_num_heads, mamba_head_dim, "
+        "n_groups, ssm_state_size, conv_kernel, chunk_size, "
+        "num_key_value_heads, head_dim, n_routed_experts (the router's "
+        "width), num_experts_per_tok, moe_intermediate_size, "
+        "moe_shared_expert_intermediate_size, routed_scaling_factor, "
+        "norm_eps; held_experts lists the expert ids this chip holds "
+        "(dl/hybrid.py). hiddenSize, numLayers, numHeads, vocabSize and "
+        "maxTokenLen apply as they do to the encoder", dict)
     stepFn = Param(
         "stepFn", "Step hook: fn(step_idx, loss, params, batch_stats, "
         "opt_state) after every accepted training step, device arrays as "
@@ -144,17 +156,28 @@ class DeepTextClassifier(Estimator, HasLabelCol, HasPredictionCol):
             sp = self.getSeqAxisSize() or len(devs)
             dp = max(1, len(devs) // sp)
             mesh = make_mesh({"data": dp, "seq": sp}, devices=devs[: dp * sp])
-        model = TransformerEncoder(
-            vocab_size=self.getVocabSize(), num_layers=self.getNumLayers(),
-            num_heads=self.getNumHeads(), hidden=self.getHiddenSize(),
-            max_len=self.getMaxTokenLen(), num_classes=len(classes),
-            dtype=jnp.bfloat16 if self.getPrecision() == "bfloat16" else jnp.float32,
-            mask_free=seq_on, dropout=0.0 if seq_on else 0.1)
+        dtype = (jnp.bfloat16 if self.getPrecision() == "bfloat16"
+                 else jnp.float32)
+        if self.get("architecture"):
+            if seq_on:
+                raise NotImplementedError(
+                    "seqParallel with an architecture: the layer-type-list "
+                    "backbone has no sequence-sharded mixers")
+            model = _backbone(self, len(classes), dtype)
+        else:
+            model = TransformerEncoder(
+                vocab_size=self.getVocabSize(), num_layers=self.getNumLayers(),
+                num_heads=self.getNumHeads(), hidden=self.getHiddenSize(),
+                max_len=self.getMaxTokenLen(), num_classes=len(classes),
+                dtype=dtype, mask_free=seq_on, dropout=0.0 if seq_on else 0.1)
         cfg = TrainConfig(batch_size=self.getBatchSize(), max_epochs=self.getMaxEpochs(),
                           learning_rate=self.getLearningRate(), optimizer=self.getOptimizer(),
                           compute_dtype=self.getPrecision(), seed=self.getSeed(),
                           seq_parallel=seq_on, seq_attention=self.getSeqAttention())
         trainer = FlaxTrainer(model, cfg, mesh=mesh)
+        if self.get("architecture"):
+            # its parameters do not depend on the length: two positions
+            trainer.init(ids[:1, :2], jit=True)
         trainer.fit(ids, y, log_fn=lambda ep: self._log_base("epoch", ep),
                     step_fn=self.get("stepFn"))
         self._log_base("trainingMeasures", trainer.stats["measures"])
@@ -166,7 +189,7 @@ class DeepTextClassifier(Estimator, HasLabelCol, HasPredictionCol):
         m.set("numLayers", self.getNumLayers())
         m.set("numHeads", self.getNumHeads())
         m.set("hiddenSize", self.getHiddenSize())
-        for p in ("textCol", "predictionCol"):
+        for p in ("textCol", "predictionCol", "architecture"):
             if self.isSet(p):
                 m.set(p, self.get(p))
         return m
@@ -255,6 +278,10 @@ class DeepTextModel(Model, HasPredictionCol):
     seqParallel = Param(
         "seqParallel", "Model was trained mask-free for seq sharding", bool,
         False)
+    architecture = Param(
+        "architecture", "The layer-type-list backbone the model was trained "
+        "with (DeepTextClassifier.architecture); unset for the dense encoder",
+        dict)
 
     # class-level defaults: instances materialized by PipelineStage.load
     # bypass __init__
@@ -318,17 +345,32 @@ class DeepTextModel(Model, HasPredictionCol):
                                                         len(self.classes))
             self.trainer = None
             return
-        model = TransformerEncoder(
-            vocab_size=self.getVocabSize(), num_layers=self.getNumLayers(),
-            num_heads=self.getNumHeads(), hidden=self.getHiddenSize(),
-            max_len=self.getMaxTokenLen(), num_classes=len(self.classes),
-            mask_free=bool(self.getSeqParallel()))
+        if self.get("architecture"):
+            model = _backbone(self, len(self.classes), jnp.float32)
+        else:
+            model = TransformerEncoder(
+                vocab_size=self.getVocabSize(), num_layers=self.getNumLayers(),
+                num_heads=self.getNumHeads(), hidden=self.getHiddenSize(),
+                max_len=self.getMaxTokenLen(), num_classes=len(self.classes),
+                mask_free=bool(self.getSeqParallel()))
         trainer = FlaxTrainer(model, TrainConfig())
-        trainer.init(np.zeros((1, self.getMaxTokenLen()), np.int32))
+        trainer.init(np.zeros((1, self.getMaxTokenLen()), np.int32),
+                     jit=bool(self.get("architecture")))
         with open(os.path.join(path, "params.msgpack"), "rb") as f:
             blob = from_bytes({"params": trainer.params}, f.read())
         trainer.load_params(blob["params"])
         self.trainer = trainer
+
+
+def _backbone(stage, num_classes: int, dtype):
+    """The backbone ``stage.architecture`` describes (estimator or model)."""
+    from .hybrid import HybridArch, HybridBackbone
+
+    arch = HybridArch.from_source(
+        stage.get("architecture"), hidden=stage.getHiddenSize(),
+        layers=stage.getNumLayers(), heads=stage.getNumHeads(),
+        vocab=stage.getVocabSize())
+    return HybridBackbone(arch, num_classes=num_classes, dtype=dtype)
 
 
 def _load_hf(checkpoint: str, num_labels: int, dtype=None):

@@ -189,14 +189,23 @@ class FlaxTrainer:
         self.loss = loss
         self.params = None
         self.batch_stats = None
+        self.counters = {}
         self.measures = InstrumentationMeasures()   # a new one per fit
 
     # --- setup ----------------------------------------------------------
-    def init(self, sample_x):
+    def init(self, sample_x, jit: bool = False):
+        """``jit``: initialise in one compiled program (a model whose
+        eager forward pass would be hundreds of small programs)."""
         rng = jax.random.PRNGKey(self.cfg.seed)
-        variables = self.model.init(rng, jnp.asarray(sample_x[:1]), train=False)
+        init = (jax.jit(self.model.init, static_argnames="train") if jit
+                else self.model.init)
+        variables = init(rng, jnp.asarray(sample_x[:1]), train=False)
         self.params = variables["params"]
         self.batch_stats = variables.get("batch_stats", {})
+        # what the model counts about its own work (``sow`` into
+        # "counters"): running sums that ride along with the train step
+        self.counters = jax.tree.map(jnp.zeros_like,
+                                     unfreeze(variables.get("counters", {})))
         return self
 
     def load_params(self, params, batch_stats=None):
@@ -458,6 +467,9 @@ class FlaxTrainer:
 
         compute_dtype = jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else jnp.float32
         has_bn = bool(self.batch_stats)
+        zero_counters = self.counters
+        mutable = (["batch_stats"] if has_bn else []) + (
+            ["counters"] if zero_counters else [])
         model, loss_kind = self.model, self.loss
 
         def cast_in(xb):
@@ -465,15 +477,19 @@ class FlaxTrainer:
             # stay integral for embedding lookups
             return xb.astype(compute_dtype) if jnp.issubdtype(xb.dtype, jnp.floating) else xb
 
-        def loss_fn(params, batch_stats, xb, yb, rng):
+        def loss_fn(params, batch_stats, xb, yb, rng, counters):
             variables = {"params": params}
             rngs = {"dropout": rng}
             if has_bn:
                 variables["batch_stats"] = batch_stats
+            if zero_counters:
+                variables["counters"] = counters
+            if mutable:
                 logits, mutated = model.apply(variables, cast_in(xb),
-                                              train=True, mutable=["batch_stats"],
+                                              train=True, mutable=mutable,
                                               rngs=rngs)
-                new_bs = mutated["batch_stats"]
+                new_bs = mutated["batch_stats"] if has_bn else batch_stats
+                counters = mutated.get("counters", counters)
             else:
                 logits = model.apply(variables, cast_in(xb), train=True, rngs=rngs)
                 new_bs = batch_stats
@@ -485,18 +501,20 @@ class FlaxTrainer:
             else:
                 loss = jnp.mean((logits.squeeze(-1) - yb) ** 2)
                 acc = -loss
-            return loss, (new_bs, acc)
+            return loss, (new_bs, acc, counters)
 
         accum = max(int(cfg.accum_steps), 1)
         if cfg.batch_size % accum:
             raise ValueError(
                 f"accum_steps={accum} must divide batch_size={cfg.batch_size}")
 
-        def train_step(params, batch_stats, opt_state, xb, yb, step):
+        def train_step(params, batch_stats, opt_state, xb, yb, step,
+                       counters):
             rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), step)
             if accum == 1:
-                (loss, (new_bs, acc)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params, batch_stats, xb, yb, rng)
+                (loss, (new_bs, acc, counters)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, batch_stats, xb, yb, rng,
+                                           counters)
             else:
                 # microbatch accumulation: grads summed in a scan carry (one
                 # optimizer update and ONE ZeRO gather set per global batch)
@@ -504,21 +522,23 @@ class FlaxTrainer:
                 ymb = yb.reshape((accum, yb.shape[0] // accum) + yb.shape[1:])
 
                 def micro(carry, inp):
-                    bs, gacc = carry
+                    bs, gacc, cnt = carry
                     xm, ym, i = inp
-                    (l_m, (bs2, a_m)), g = jax.value_and_grad(
+                    (l_m, (bs2, a_m, cnt)), g = jax.value_and_grad(
                         loss_fn, has_aux=True)(params, bs, xm, ym,
-                                               jax.random.fold_in(rng, i))
-                    return (bs2, jax.tree.map(jnp.add, gacc, g)), (l_m, a_m)
+                                               jax.random.fold_in(rng, i), cnt)
+                    return ((bs2, jax.tree.map(jnp.add, gacc, g), cnt),
+                            (l_m, a_m))
 
-                (new_bs, gsum), (ls, accs) = jax.lax.scan(
-                    micro, (batch_stats, jax.tree.map(jnp.zeros_like, params)),
+                (new_bs, gsum, counters), (ls, accs) = jax.lax.scan(
+                    micro, (batch_stats, jax.tree.map(jnp.zeros_like, params),
+                            counters),
                     (xmb, ymb, jnp.arange(accum)))
                 grads = jax.tree.map(lambda g: g / accum, gsum)
                 loss, acc = ls.mean(), accs.mean()
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-            return params, new_bs, opt_state, loss, acc
+            return params, new_bs, opt_state, loss, acc, counters
 
         # "skip"/"rollback" read the pre-step state AFTER the step ran, so
         # donation is only legal under the default "raise" policy
@@ -530,8 +550,9 @@ class FlaxTrainer:
             rep = NamedSharding(self.mesh, P())
             row_sh = NamedSharding(self.mesh, P(DATA_AXIS))  # prefix spec
             jit_kwargs["in_shardings"] = (param_sh, bs_sh, opt_sh,
-                                          row_sh, row_sh, None)
-            jit_kwargs["out_shardings"] = (param_sh, bs_sh, opt_sh, rep, rep)
+                                          row_sh, row_sh, None, rep)
+            jit_kwargs["out_shardings"] = (param_sh, bs_sh, opt_sh, rep, rep,
+                                           rep)
         train_step = jax.jit(train_step, **jit_kwargs)
 
         history = []
@@ -585,6 +606,7 @@ class FlaxTrainer:
             rng_e = np.random.default_rng([cfg.seed, epoch])
             losses = []
             nsteps = 0
+            counters = zero_counters
             t0 = time.perf_counter()
             rolled_back = False
             batches = self._prefetch(
@@ -613,12 +635,14 @@ class FlaxTrainer:
                                 # PeerLostError instead of an indefinite stall
                                 out = wd.run(_synced_step, params, batch_stats,
                                              opt_state, xb, yb, step_idx,
-                                             op="dl.step")
+                                             counters, op="dl.step")
                                 wd.beat("dl.step", step_idx)
                             else:
                                 out = train_step(params, batch_stats,
-                                                 opt_state, xb, yb, step_idx)
-                        params, batch_stats, opt_state, loss, acc = out
+                                                 opt_state, xb, yb, step_idx,
+                                                 counters)
+                        (params, batch_stats, opt_state, loss, acc,
+                         step_counters) = out
                         size = train_step._cache_size()
                         if size != cache_size:
                             measures.count("compiles", size - cache_size)
@@ -627,6 +651,7 @@ class FlaxTrainer:
                         with measures.span("lossSync"):
                             loss_value = float(loss)
                             action = guard.check(loss_value, step_idx)
+                        counters = step_counters
                         if action == "skip":
                             # drop the poisoned update; the step index still
                             # advances so the dropout stream stays aligned
@@ -670,7 +695,8 @@ class FlaxTrainer:
                   "steps": nsteps,
                   "seconds": time.perf_counter() - t0,
                   **_epoch_step_times(measures, parts_before,
-                                      epoch_span.start_ns)}
+                                      epoch_span.start_ns),
+                  **_epoch_counters(measures, counters)}
             if valid is not None:
                 with measures.span("trainer.validation"):
                     ep["val_acc"] = float(self.evaluate(
@@ -760,6 +786,21 @@ def _epoch_step_times(measures, parts_before: dict, epoch_start_ns: int) -> dict
              if r.name == "trainer.step" and r.start_ns >= epoch_start_ns]
     out["step_ms_p50"] = float(np.median(steps)) / 1e6 if steps else float("nan")
     return out
+
+
+def _epoch_counters(measures, counters) -> dict:
+    """The model's own counters of one epoch, read from the device once:
+    ``{"counters": {name: sum, or the list of a vector's sums}}`` for the
+    ``history`` entry, the scalars also added to ``count:<name>``; nothing
+    for a model that counts nothing."""
+    if not counters:
+        return {}
+    flat = {"/".join(k): np.asarray(v).tolist() for k, v in
+            traverse_util.flatten_dict(jax.device_get(counters)).items()}
+    for name, value in flat.items():
+        if not isinstance(value, list):
+            measures.count(name, value)
+    return {"counters": flat}
 
 
 def per_device_state_bytes(*trees) -> int:

@@ -156,6 +156,7 @@ def phase_kernels(rehearsal: bool) -> dict:
 
     from synapseml_tpu.ops import attention_kernel as ak
     from synapseml_tpu.ops import hist_kernel as hk
+    from synapseml_tpu.ops import partition_kernel as pk
     from synapseml_tpu.parallel.ring_attention import _block_attention
 
     interp = rehearsal            # the CPU can only interpret a TPU kernel
@@ -182,6 +183,12 @@ def phase_kernels(rehearsal: bool) -> dict:
               hk._hist_pallas_range(bT, g, h, m, start, length, B, size,
                                     chunk=C, interpret=interp),
               hk._hist_xla(bT, g * sel, h * sel, m * sel, B), 1e-4)
+        # the split step's stable partition of that window: exact
+        part = pk._partition_check_inputs(0, B, n, FP, 2 * C, size, start,
+                                          length)
+        close(f"stable_partition_rows[f={feats}]",
+              pk.partition_window(*part, B, C, interpret=interp),
+              pk.partition_window_xla(*part), 0.0)
         # level kernel: 8 slots of 1-3 chunks, zero-valued tail padding
         caps = [2, 1, 3, 1, 2, 3, 1, 3]
         bT, g, h, m, starts, slot_row = hk._level_check_inputs(

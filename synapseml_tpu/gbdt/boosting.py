@@ -36,7 +36,7 @@ from ..ops.quantize import (BinMapper, apply_bins, bin_threshold_to_value,
 _TUNED_SENTINEL = "__tuned__"
 from .dataset import Dataset, _is_sparse
 from .grower import (Forest, GrowerConfig, TreeArrays, forest_max_depth,
-                     forest_predict, grow_tree, stack_trees)
+                     forest_predict, grow_tree, split_counter, stack_trees)
 from .objectives import (METRICS, HIGHER_IS_BETTER, Objective, get_objective,
                          lambdarank_objective, make_grouped,
                          map_at_k, metric_kwargs, ndcg_at_k)
@@ -1656,6 +1656,7 @@ def train_booster(
                     state["carry"], n, n_orig, mesh, multiproc,
                     row2 if mesh is not None else None,
                     row1 if mesh is not None else None, score_v0)
+        moved_by = split_counter(grower_cfg, nfeat)
         with measures.span("trainingIterations"):
             wd = current_watchdog()
             while done < T:
@@ -1686,6 +1687,9 @@ def train_booster(
                             trees.append(jax.tree.map(lambda a: a[ti, cls],
                                                       stacked_trees))
                             tree_weights.append(1.0)
+                if moved_by:
+                    measures.count(moved_by,
+                                   int(stacked_trees.num_splits.sum()))
                 done += c
                 stop = False
                 if has_valid:

@@ -55,6 +55,8 @@ from jax import lax
 from ..ops.hist_kernel import (child_histogram, default_chunk,
                                features_padded, pad_bins, range_histogram,
                                segmented_histograms_available)
+from ..ops.partition_kernel import (partition_kernel_available,
+                                    partition_window)
 
 BITS = 32  # bitset word width for categorical splits
 def _chunk() -> int:
@@ -332,6 +334,10 @@ def _aligned_window(start, size: int, np_rows: int, chunk: int):
     when it changes between fresh jit keys."""
     if os.environ.get("SYNAPSEML_TPU_ALIGN_WINDOWS", "1") == "0":
         return jnp.minimum(start, np_rows - size), size
+    return _chunk_window(start, size, np_rows, chunk)
+
+
+def _chunk_window(start, size: int, np_rows: int, chunk: int):
     S = min(size + chunk, np_rows)
     cs0 = jnp.minimum(start, np_rows - S)
     return (cs0 // chunk) * chunk, S
@@ -392,6 +398,58 @@ def _stable_partition_src(key: jnp.ndarray, impl: str) -> jnp.ndarray:
         s = jnp.searchsorted(c, rank, side="left").astype(jnp.int32)
         src = jnp.where(pick == ci, s, src)
     return src
+
+
+def _partition_impl(cfg: GrowerConfig, num_bins_padded: int,
+                    fp: int) -> str:
+    """How a split moves a leaf's rows: ``"kernel"``, one pass of
+    ops/partition_kernel.py, on the TPU backend; elsewhere the XLA primitive
+    that ``cfg.partition_impl`` names, and five gathers."""
+    return ("kernel" if partition_kernel_available(num_bins_padded, fp)
+            else cfg.partition_impl)
+
+
+def _partition_bucket(pos, gs, hs, ms, bT, start, length, fsel, route,
+                      size: int, chunk: int, num_bins_padded: int, impl: str):
+    """One bucket of the split step: stably partition rows [start,
+    start+length) of the sorted arrays by ``route`` (bin values of feature
+    ``fsel`` -> goes right) inside the chunk-aligned window that covers any
+    range of at most ``size`` rows, the way ``impl`` names
+    (:func:`_partition_impl`). Returns the five updated arrays and the left
+    child's row count."""
+    FP, Np = bT.shape
+    use_kernel = impl == "kernel"
+    cs, S = (_chunk_window if use_kernel else _aligned_window)(
+        start, size, Np, chunk)
+    idx = cs + jnp.arange(S, dtype=jnp.int32)
+    binrow = lax.dynamic_slice(bT, (fsel, cs), (1, S))[0]
+    gr = route(binrow)
+    if use_kernel:
+        # two classes give the 4-way key's order: rows before the range
+        # already precede its left rows, rows past it already follow its
+        # right rows
+        past = idx >= start + length
+        inside = (idx >= start) & ~past
+        second = past | (inside & gr)
+        nl_loc = jnp.sum(inside & ~gr, dtype=jnp.int32)
+        put = lambda a, w: lax.dynamic_update_slice(
+            a, w, (0,) * (a.ndim - 1) + (cs,))
+        moved = partition_window(second, cs, bT, pos, gs, hs, ms,
+                                 num_bins_padded, chunk)
+        return tuple(map(put, (pos, gs, hs, ms, bT), moved)) + (nl_loc,)
+    key = jnp.where(idx < start, -1,
+                    jnp.where(idx >= start + length, 2,
+                              gr.astype(jnp.int32)))
+    src = _stable_partition_src(key, impl)
+    nl_loc = jnp.sum(key == 0).astype(jnp.int32)
+
+    def perm1(a):
+        sl = lax.dynamic_slice(a, (cs,), (S,))
+        return lax.dynamic_update_slice(a, sl[src], (cs,))
+
+    blk = lax.dynamic_slice(bT, (0, cs), (FP, S))
+    bT2 = lax.dynamic_update_slice(bT, blk[:, src], (0, cs))
+    return perm1(pos), perm1(gs), perm1(hs), perm1(ms), bT2, nl_loc
 
 
 # ---------------------------------------------------------------------------
@@ -702,9 +760,10 @@ def _node_of_row_from_ranges(s, L: int, Np: int, n: int) -> jnp.ndarray:
     markers = jnp.full(Np, -1, jnp.int32).at[
         jnp.where(own_rows, s.leaf_start, Np)].set(
             jnp.arange(L, dtype=jnp.int32), mode="drop")
-    last_pos = lax.associative_scan(
-        jnp.maximum,
-        jnp.where(markers >= 0, jnp.arange(Np, dtype=jnp.int32), -1))
+    # lax.cummax, not lax.associative_scan(jnp.maximum, ...): the scan's
+    # unrolled tree of slices took the TPU compiler 1,290 s at 3.5 M rows
+    last_pos = lax.cummax(
+        jnp.where(markers >= 0, jnp.arange(Np, dtype=jnp.int32), -1), axis=0)
     node_sorted = markers[jnp.maximum(last_pos, 0)]
     return jnp.zeros(Np, jnp.int32).at[s.pos].set(node_sorted)[:n]
 
@@ -891,32 +950,19 @@ def _grow_tree_impl(binned, grad, hess, in_bag, feature_active, is_categorical,
         **_init_split_state(L, B, bw, hist_root, rg, rf, rb, rdl, rcl, FPo),
     )
 
+    partition_impl = _partition_impl(cfg, B, FP)
+
     def partition(pos, gs, hs, ms, bT, start, length, fsel, bsel, dl, bitset,
                   cat_split, nanbin_f):
         """Stably partition the leaf's range by the split; returns updated
         sorted arrays and the LOCAL left-child row count."""
+        route = lambda binrow: _route_right(binrow, bsel, dl, nanbin_f,
+                                            bitset, cat_split, cfg, bw)
+
         def make_branch(size):
-            def br(args):
-                pos_, gs_, hs_, ms_, bT_ = args
-                cs, S = _aligned_window(start, size, Np, chunk)
-                idx = cs + jnp.arange(S, dtype=jnp.int32)
-                binrow = lax.dynamic_slice(bT_, (fsel, cs), (1, S))[0]
-                gr = _route_right(binrow, bsel, dl, nanbin_f, bitset,
-                                  cat_split, cfg, bw)
-                key = jnp.where(idx < start, -1,
-                                jnp.where(idx >= start + length, 2,
-                                          gr.astype(jnp.int32)))
-                src = _stable_partition_src(key, cfg.partition_impl)
-                nl_loc = jnp.sum(key == 0).astype(jnp.int32)
-
-                def perm1(a):
-                    sl = lax.dynamic_slice(a, (cs,), (S,))
-                    return lax.dynamic_update_slice(a, sl[src], (cs,))
-
-                blk = lax.dynamic_slice(bT_, (0, cs), (FP, S))
-                bT2 = lax.dynamic_update_slice(bT_, blk[:, src], (0, cs))
-                return perm1(pos_), perm1(gs_), perm1(hs_), perm1(ms_), bT2, nl_loc
-            return br
+            return lambda args: _partition_bucket(
+                *args, start, length, fsel, route, size, chunk, B,
+                partition_impl)
 
         bidx = jnp.searchsorted(sizes_arr, length, side="left")
         return lax.switch(jnp.minimum(bidx, len(sizes) - 1),
@@ -1327,6 +1373,17 @@ def grow_tree(
     return _grow_tree_impl(binned, grad, hess, in_bag, feature_active,
                            is_categorical, monotone, nan_bins, cfg, axis_name,
                            node_key, cat_nbins)
+
+
+def split_counter(cfg: GrowerConfig, nfeat: int) -> Optional[str]:
+    """Name of the ``trainingMeasures`` counter for the splits of trees grown
+    under ``cfg``: which path moves a leaf's rows — the partition kernel or
+    the XLA primitives (``partition_impl``). None where no rows move."""
+    if cfg.growth_policy != "leafwise" or cfg.row_layout != "partition":
+        return None
+    impl = _partition_impl(cfg, pad_bins(cfg.num_bins),
+                           features_padded(nfeat))
+    return "splitsPartitionKernel" if impl == "kernel" else "splitsPartitionSort"
 
 
 # ---------------------------------------------------------------------------

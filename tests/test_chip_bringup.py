@@ -3,7 +3,7 @@
 * On the TPU backend a kernel that cannot compile, or disagrees with its
   reference, raises ``KernelError`` naming the kernel — no gate returns
   ``False``/``"xla"`` and no caller quietly takes the XLA path.
-* All five Pallas kernels compile for a chip-less ``v5e:2x2`` topology (this
+* All six Pallas kernels compile for a chip-less ``v5e:2x2`` topology (this
   installation's libtpu compiles without a device; interpret mode, which the
   other kernel tests use, does not check tiling).
 * The compile cache can be placed from outside.
@@ -42,11 +42,12 @@ def backend_says_tpu(monkeypatch):
     TPU every kernel is 'a kernel made to fail'."""
     from synapseml_tpu.ops import attention_kernel as ak
     from synapseml_tpu.ops import hist_kernel as hk
+    from synapseml_tpu.ops import partition_kernel as pk
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cached = (hk._check_hist_kernel, hk._check_range_kernel,
               hk._check_level_kernel, ak._check_flash_kernel,
-              ak._check_flash_block_kernel)
+              ak._check_flash_block_kernel, pk._check_partition_kernel)
     for c in cached:
         c.cache_clear()
     yield
@@ -70,6 +71,7 @@ def _qkv(s=256, h=4):
 def _gates():
     from synapseml_tpu.ops import attention_kernel as ak
     from synapseml_tpu.ops import hist_kernel as hk
+    from synapseml_tpu.ops import partition_kernel as pk
     from synapseml_tpu.parallel import make_mesh
     from synapseml_tpu.parallel.ring_attention import ring_self_attention
     from synapseml_tpu.parallel.ulysses import ulysses_self_attention
@@ -81,6 +83,9 @@ def _gates():
         "segmented_histograms_available": (
             "_hist_pallas_range",
             lambda: hk.segmented_histograms_available(256)),
+        "partition_kernel_available": (
+            "stable_partition_rows",
+            lambda: pk.partition_kernel_available(256, 32)),
         "level_histograms": ("_hist_pallas_level", lambda: hk.level_histograms(
             *_hist_args(), jnp.asarray([0, 1], jnp.int32),
             jnp.zeros(4096, jnp.int32), 256, 2)),
@@ -96,6 +101,7 @@ def _gates():
 
 @pytest.mark.parametrize("gate", ["child_histogram",
                                   "segmented_histograms_available",
+                                  "partition_kernel_available",
                                   "level_histograms", "flash_attention",
                                   "ring_self_attention",
                                   "ulysses_self_attention"])
@@ -172,6 +178,29 @@ def test_hist_kernels_compile_for_v5e(v5e, fp):
                                 chunk=C).compile()
     hk._hist_pallas_level.lower(*rows, v5e((5,), jnp.int32), 256, 5,
                                 chunk=C).compile()
+
+
+@pytest.mark.parametrize("fp,bins,window", [(32, 256, 3_500_032),
+                                            (32, 256, 4096),
+                                            (136, 1024, 6144),
+                                            (1024, 256, 6144),
+                                            (1024, 1024, 6144),
+                                            (2048, 256, 6144),
+                                            (2048, 1024, 6144)])
+def test_partition_kernel_compiles_for_v5e(v5e, fp, bins, window):
+    """The benchmark cell's shapes (its smallest bucket and the whole
+    table); a table whose last feature block is short, with two byte planes
+    a bin; and tables 1,024 and 2,048 features wide, whose VMEM is one
+    feature block's."""
+    from synapseml_tpu.ops import partition_kernel as pk
+
+    n = max(window, 8 * 2048)
+    vec = lambda dt: v5e((n,), dt)
+    pk._partition_pallas.lower(
+        v5e((window,), jnp.bool_), v5e((), jnp.int32),
+        v5e((fp, n), jnp.int32), vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.float32), vec(jnp.float32), num_bins_padded=bins,
+        chunk=pk.partition_chunk(2048)).compile()
 
 
 @pytest.mark.parametrize("shape,dt", [((1, 4096, 8, 64), jnp.bfloat16),

@@ -235,6 +235,64 @@ def test_kernel_checks_pass_on_chip(tpu):
     _check_flash_block_kernel()
 
 
+@pytest.mark.parametrize("window,first_row,start,length", [
+    (4096, 2048 * 700, 2048 * 700 + 777, 1500),        # the smallest bucket
+    (3_500_032, 0, 1234, 2_200_000)])                  # the whole table
+def test_partition_kernel_on_chip(tpu, window, first_row, start, length):
+    """The compiled stable-partition kernel at the benchmark cell's shapes
+    (32 padded features, 3,500,032 rows) against argsort + five gathers,
+    bit for bit; its trace-time check passes or raises KernelError."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from synapseml_tpu.ops.partition_kernel import (
+        _partition_check_inputs, partition_kernel_available,
+        partition_window, partition_window_xla)
+
+    assert partition_kernel_available(256, 32) is True
+    args = _partition_check_inputs(4, 256, 3_500_032, 32, first_row, window,
+                                   start, length)
+    got = jax.jit(lambda *a: partition_window(*a, 256, 2048))(*args)
+    want = jax.jit(partition_window_xla)(*args)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(
+            np.asarray(lax.bitcast_convert_type(a, jnp.int32)),
+            np.asarray(lax.bitcast_convert_type(b, jnp.int32)))
+
+
+@pytest.mark.parametrize("bins,fp", [(1024, 136), (256, 1024),
+                                     (1024, 2048)])
+def test_partition_kernel_check_passes_on_wide_tables(tpu, bins, fp):
+    """The gate's self-check runs at the table's own width: a short last
+    feature block, and 1,024 and 2,048 features (8 and 16 blocks), one and
+    two byte planes a bin. Bit for bit, or KernelError."""
+    from synapseml_tpu.ops.partition_kernel import partition_kernel_available
+
+    assert partition_kernel_available(bins, fp) is True
+
+
+def test_fit_counts_kernel_splits_on_chip(tpu):
+    """On the chip every split of the partition layout goes through the
+    kernel, and the fit's record says so."""
+    from synapseml_tpu.core.logging import InstrumentationMeasures
+    from synapseml_tpu.gbdt import BoosterConfig, train_booster
+
+    rng = np.random.default_rng(2)
+    X = rng.normal(size=(20000, 12)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] > 0).astype(np.float32)
+    m = InstrumentationMeasures()
+    bst = train_booster(X, y, BoosterConfig(objective="binary",
+                                            num_iterations=3,
+                                            row_layout="partition"),
+                        measures=m)
+    counted = {k: v for k, v in m.report().items()
+               if k.startswith("count:splitsPartition")}
+    assert counted == {"count:splitsPartitionKernel":
+                       sum(int(t.num_splits) for t in bst.trees)}
+
+
 def test_level_kernel_on_chip(tpu):
     """The multi-leaf level kernel (depthwise / streamed growth) vs the
     slot-keyed scatter, 136 features wide, uneven slots with padded tails."""

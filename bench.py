@@ -86,128 +86,17 @@ def bench_gbdt():
     # excluded from the timed iteration loop).
     ds = Dataset(X, y).block_until_ready()
 
-    # The engine ships selectable hot-loop designs whose relative speed is a
-    # property of the chip (docs/perf_notes.md); the DEFAULT config is
-    # measured first and guaranteed to report, then the alternates are
-    # sampled — each guarded so a failing/slow alternate can neither kill
-    # the primary metric nor blow the time budget. "value" is the best of
-    # the shipped configs that succeeded; "variant"/"variants" record which.
-    all_variants = {
-        "partition_sort": {"partition_impl": "sort", "row_layout": "partition"},
-        # scan measured 6.6x slower on-chip (docs/measurements.json
-        # 2026-07-31) and was dropped from the sweep; scatter is the
-        # O(n) cumsum+unique-scatter partition (grower.py)
-        "partition_scatter": {"partition_impl": "scatter",
-                              "row_layout": "partition"},
-        # gather: pos-only permutation, smaller child gathered pre-kernel
-        "gather": {"partition_impl": "sort", "row_layout": "gather"},
-        "gather_scatter": {"partition_impl": "scatter",
-                           "row_layout": "gather"},
-        "masked": {"partition_impl": "sort", "row_layout": "masked"},
-        # sort32 combos: every value the tuner can pin must be representable
-        # here, or a tuned default would be mislabeled in the report
-        "partition_sort32": {"partition_impl": "sort32",
-                             "row_layout": "partition"},
-        "gather_sort32": {"partition_impl": "sort32", "row_layout": "gather"},
-    }
-    _d = BoosterConfig()
-    default_name = next(
-        (nm for nm, kw in all_variants.items()
-         if all(getattr(_d, k) == v for k, v in kw.items())),
-        "partition_sort")
-    # default config FIRST (guaranteed to report), alternates sampled after
-    variants = [(default_name, all_variants[default_name])] + [
-        (nm, kw) for nm, kw in all_variants.items() if nm != default_name]
-    sweep_budget = float(os.environ.get("BENCH_GBDT_SWEEP_BUDGET_S", 600))
-    t_sweep = time.perf_counter()
-    results, errors = {}, {}
-    for name, kw in variants:
-        if results and time.perf_counter() - t_sweep > sweep_budget:
-            errors[name] = "skipped: sweep budget exhausted"
-            continue
-        try:
-            cfg_warm = BoosterConfig(objective="binary",
-                                     num_iterations=TIMED_ITERS, **kw)
-            train_booster(ds, None, cfg_warm)  # compile + cache
-            cfg = BoosterConfig(objective="binary",
-                                num_iterations=TIMED_ITERS, seed=1, **kw)
-            t0 = time.perf_counter()
-            booster = train_booster(ds, None, cfg)
-            jax.block_until_ready(booster.trees[-1].leaf_value)
-            results[name] = N_ROWS * TIMED_ITERS / (time.perf_counter() - t0)
-        except Exception as e:  # alternates must never sink the primary
-            errors[name] = str(e)[:120]
-            if not results:
-                raise   # ... unless even the default config failed
-
-    best = max(results, key=results.get)
-    v = results[best]
-    out = {"metric": "gbdt_train_row_iters_per_sec_per_chip",
-           "value": round(v, 1), "unit": "row-iterations/sec/chip",
-           "vs_baseline": round(v / BASELINE_GBDT_ROW_ITERS, 3),
-           "variant": best,
-           "variants": {k: round(r, 1) for k, r in results.items()}}
-    # the DEFAULT config's number is reported alongside the best: best-of-N
-    # is a capability claim, but a regressing default must stay visible
-    out["default_variant"] = default_name
-    if default_name in results:
-        out["value_default"] = round(results[default_name], 1)
-        out["vs_baseline_default"] = round(
-            results[default_name] / BASELINE_GBDT_ROW_ITERS, 3)
-    # effective defaults snapshot FIRST: the persist block below may
-    # rewrite the tuned file, and the report must describe the defaults the
-    # RUN actually used, not the just-written ones
-    from synapseml_tpu.core.tuned import tuned_default, tuned_engine_defaults
-    from synapseml_tpu.ops.hist_kernel import default_chunk
-
-    td = dict(tuned_engine_defaults())
-
-    # the sweep above IS phase-B's end-to-end accounting: when it finds a
-    # variant beating the current default by >3% on real TPU, persist it as
-    # the tuned default (merged with existing pins) — so even a round whose
-    # ONLY chip contact is this bench still flips the defaults for the next
-    # run, instead of leaving the measurement stranded in the report
-    try:
-        from synapseml_tpu.core import tuned as _tuned
-
-        if (_tuned.backend_is_tpu() and best != default_name
-                and default_name in results
-                and results[best] > 1.03 * results[default_name]):
-            import datetime as _dt
-
-            vals = {**_tuned.current_file_values(), **all_variants[best]}
-            p = _tuned.write_tuned_defaults(vals, {
-                "captured_at": _dt.datetime.now(
-                    _dt.timezone.utc).isoformat(timespec="seconds"),
-                "platform": "tpu",
-                "source": "bench.py variant sweep",
-                "winner": best,
-                "train25_row_iters_per_sec":
-                    {k: round(v, 1) for k, v in results.items()}})
-            if p is not None:      # None = operator disabled the mechanism
-                out["tuned_defaults_written"] = all_variants[best]
-    except Exception as e:   # persistence must never sink the measurement
-        print(f"# tuned-defaults persist failed: {e}", file=sys.stderr)
-
-    # auditability of the tune->flip->bench loop: record the EFFECTIVE
-    # engine defaults for this run — env vars outrank the tuned file, so
-    # report resolved values, not the raw file (empty = hardcoded defaults;
-    # snapshot taken before the persist block so a just-written file cannot
-    # misattribute this run's configuration)
-    if td:
-        td["partition_impl"] = _d.partition_impl
-        td["row_layout"] = _d.row_layout
-        if _d.use_segmented is not None:
-            td["use_segmented"] = _d.use_segmented
-        if "hist_chunk" in td:
-            td["hist_chunk"] = default_chunk()
-        if "hist_pack" in td:
-            td["hist_pack"] = tuned_default(
-                "hist_pack", "SYNAPSEML_TPU_HIST_PACK", td["hist_pack"])
-        out["tuned_defaults"] = td
-    if errors:
-        out["variant_errors"] = errors
-    return out
+    train_booster(ds, None, BoosterConfig(
+        objective="binary", num_iterations=TIMED_ITERS))  # compile + cache
+    cfg = BoosterConfig(objective="binary", num_iterations=TIMED_ITERS,
+                        seed=1)
+    t0 = time.perf_counter()
+    booster = train_booster(ds, None, cfg)
+    jax.block_until_ready(booster.trees[-1].leaf_value)
+    v = N_ROWS * TIMED_ITERS / (time.perf_counter() - t0)
+    return {"metric": "gbdt_train_row_iters_per_sec_per_chip",
+            "value": round(v, 1), "unit": "row-iterations/sec/chip",
+            "vs_baseline": round(v / BASELINE_GBDT_ROW_ITERS, 3)}
 
 
 def bench_resnet50_train(batch=32, image=224, warmup=2, steps=8):

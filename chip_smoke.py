@@ -92,7 +92,6 @@ def phase_device(rehearsal: bool, want_count: int) -> dict:
     import jaxlib
 
     from synapseml_tpu import native
-    from synapseml_tpu.core import tuned
     from synapseml_tpu.core.compile_cache import enable_compile_cache
     from synapseml_tpu.gbdt import BoosterConfig
     from synapseml_tpu.ops import hist_kernel as hk
@@ -121,13 +120,9 @@ def phase_device(rehearsal: bool, want_count: int) -> dict:
         "JAX_COMPILATION_CACHE_DIR":
             os.environ.get("JAX_COMPILATION_CACHE_DIR"),
         "gbdt_defaults": {
-            "partition_impl": cfg.partition_impl,
-            "row_layout": cfg.row_layout,
-            "use_segmented": cfg.use_segmented,
             "hist_chunk": hk.default_chunk(),
             "hist_pack": hk._pack_for(hk.pad_bins(cfg.max_bin) // 8,
-                                      hk.FEATURE_BLOCK, None),
-            "tuned_file": tuned.tuned_engine_defaults()},
+                                      hk.FEATURE_BLOCK, None)},
         "native_available": native.available(),
     }
 

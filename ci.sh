@@ -88,10 +88,6 @@ JAX_PLATFORMS=cpu python -m synapseml_tpu.testing.dtypewitness \
     "${_dw_report}"
 rm -f "${_dw_report}"
 
-echo "== perf_tune rehearsal (tune -> flip -> persist on CPU) =="
-XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
-    python -m pytest tests/test_perf_tune_rehearsal.py -x -q -m slow
-
 echo "== preemption-recovery chaos suite (kill -> resume == uninterrupted) =="
 XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
     python -m pytest tests/test_checkpoint_recovery.py -x -q

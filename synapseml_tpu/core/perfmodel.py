@@ -1,9 +1,9 @@
 """Learned performance model behind every auto-configuration knob.
 
-One subsystem replaces the seven independently hand-tuned decision points
-(gbdt kernel variant, wire-dtype ladder, tree-learner routing, bucket-ladder
-geometry, dl ``param_sharding``/``accum_steps``, ``partition_stages`` cuts,
-chunk geometry) with a single measurement-backed model in the spirit of
+One subsystem replaces the independently hand-tuned decision points
+(wire-dtype ladder, tree-learner routing, bucket-ladder geometry, dl
+``param_sharding``/``accum_steps``, ``partition_stages`` cuts, chunk
+geometry) with a single measurement-backed model in the spirit of
 "A Learned Performance Model for Tensor Processing Units" (arXiv:2008.01040):
 
 * a **featurizer** maps a candidate configuration (shapes, dtypes, mesh
@@ -394,9 +394,8 @@ def backfill_training_rows(json_path: Optional[str] = None,
     """Convert legacy ``docs/measurements.json`` replay data to perf rows.
 
     Idempotent: rows carry ``backfilled_from`` = (metric, captured_at) and a
-    second run appends nothing.  Only record families that encode a real
-    A/B are converted: the gbdt kernel-variant sweep and the voting-vs-data
-    collective A/B.
+    second run appends nothing.  Only the record family that encodes a real
+    A/B is converted: the voting-vs-data collective A/B.
     """
     json_path = json_path or MEASUREMENTS_JSON
     jsonl_path = jsonl_path or _journal_path()
@@ -414,19 +413,7 @@ def backfill_training_rows(json_path: Optional[str] = None,
         if src in have:
             continue
         platform = rec.get("platform", "cpu").split("-")[0]
-        if metric == "gbdt_train_row_iters_per_sec_per_chip" and \
-                isinstance(rec.get("variants"), dict):
-            for arm, rate in rec["variants"].items():
-                if not rate:
-                    continue
-                append_training_row(
-                    "gbdt_kernel", arm, {}, 1.0 / float(rate),
-                    platform=platform, captured_at=rec.get("captured_at"),
-                    path=jsonl_path, backfilled_from=list(src),
-                    unit="s/row-iteration")
-                added += 1
-            have.add(src)
-        elif metric == "gbdt_voting_vs_data_parallel_speedup" and \
+        if metric == "gbdt_voting_vs_data_parallel_speedup" and \
                 "mesh" in rec.get("platform", ""):
             # rates are embedded in the unit string: "... voting 3856 r-i/s
             # ... data-parallel 26600 r-i/s ..."
@@ -630,26 +617,6 @@ def h2d_bandwidth() -> Optional[float]:
 # ---------------------------------------------------------------------------
 # per-picker suggestion helpers
 # ---------------------------------------------------------------------------
-
-def suggest_kernel_variant(platform: Optional[str] = None
-                           ) -> Tuple[Optional[Dict[str, str]], Decision]:
-    """Suggest (partition_impl, row_layout) from kernel-variant sweep rows.
-
-    Arms mirror ``tools/perf_tune.py`` variants: ``partition_sort``,
-    ``partition_scan``, ``masked``.  Returns ``(None, decision)`` when the
-    model has nothing confident to say — callers keep their hand-tuned
-    fallback (``sort``/``partition``).
-    """
-    arms = {
-        "partition_sort": {"partition_impl": "sort", "row_layout": "partition"},
-        "partition_scan": {"partition_impl": "scan", "row_layout": "partition"},
-        "masked": {"partition_impl": "sort", "row_layout": "masked"},
-    }
-    cands = [Candidate("gbdt_kernel", arm, {}, config=cfg)
-             for arm, cfg in arms.items()]
-    dec = choose(cands, fallback_arm="partition_sort", platform=platform)
-    return (None if dec.used_fallback else dict(dec.config)), dec
-
 
 def suggest_wire_dtype(n_rows: float, nfeat: float, workers: float,
                        max_bin: float, num_leaves: float,
@@ -936,7 +903,7 @@ __all__ = [
     "append_training_row", "training_rows", "backfill_training_rows",
     "predict_runtime", "predict", "choose",
     "link_bandwidth", "h2d_bandwidth",
-    "suggest_kernel_variant", "suggest_wire_dtype", "suggest_bucket_growth",
+    "suggest_wire_dtype", "suggest_bucket_growth",
     "suggest_param_sharding", "suggest_accum_steps",
     "suggest_pipeline_schedule", "suggest_seq_attention",
     "suggest_stage_cuts", "suggest_chunk_rows",

@@ -1,61 +1,25 @@
-"""Measured engine defaults: the tune→flip→bench loop's persistence layer.
+"""What start-up can observe: the platform query and the measurement store.
 
-The GBDT engine ships several hot-loop designs whose relative speed is a
-property of the chip, not the code (docs/perf_notes.md). ``tools/perf_tune.py``
-measures them ON REAL TPU and writes the winner to ``docs/tuned_defaults.json``;
-this module is the read side consumed by ``BoosterConfig`` /
-``ops.hist_kernel`` default resolution, so one tune pass flips the shipped
-defaults for every subsequent run — no code edit, no human in the loop.
+Two things other layers share. ``initialized_platform`` answers "which
+backend is up?" WITHOUT bringing one up: a chip belongs to one
+process, and a launcher that only builds a config must not take it from the
+child that will train. The in-process measurement store (``measured_or`` …)
+caches micro-probe results (link bandwidth, host-to-device bandwidth,
+selection timing) per process and, with a TTL, on disk.
 
-Precedence (highest wins): explicit constructor arg > ``SYNAPSEML_TPU_*`` env
-var > tuned file > hardcoded fallback.
-
-The tuned file is applied ONLY when the current process is actually running
-the TPU backend: the measurements are chip facts, and CPU tests must not
-change behavior based on a mutable artifact. The backend check never
-*initializes* a backend: a chip belongs to one process, and constructing a
-config object in a launcher must not take it from the child that will train.
-An uninitialized backend reads as "not TPU" and the fallback wins; the
-training path initializes jax first and ``BoosterConfig`` re-resolves then,
-so the file takes effect exactly where it is valid.
-
-Reference analog: LightGBM ships per-device tuned kernel parameters the same
-way (its GPU tree learner's auto-tuned work-group sizes); the reference's JVM
-layer has no equivalent because its native binaries are pre-tuned.
+Nothing here chooses an engine default: the grower decides its split step
+from the backend and the static shapes (gbdt/grower.py), and the remaining
+``SYNAPSEML_TPU_*`` knobs are read where they are used.
 """
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 from typing import Optional
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-DEFAULT_PATH = os.path.join(_REPO, "docs", "tuned_defaults.json")
-
-# keys a tuned file may set, with the values the engine accepts — the write
-# side (tools/perf_tune.py) and read side (BoosterConfig.__post_init__)
-# validate against the same table, so a corrupt/hand-edited file fails loud
-ALLOWED = {
-    "partition_impl": ("sort", "sort32", "scan", "scatter"),
-    "row_layout": ("partition", "masked", "gather"),
-    "use_segmented": (True, False),
-    "hist_chunk": int,
-    # features packed per MXU dot (ops/hist_kernel._pack_for clamps to the
-    # tile constraints; the tuner pins this only on a measured win)
-    "hist_pack": int,
-    # out-of-core ingest geometry (io/ingest.py): rows per streamed chunk
-    # and in-flight chunk depth, resolved env > tuned file > the h2d
-    # bandwidth micro-probe recorded in the measurement store
-    "stream_chunk_rows": int,
-    "stream_depth": int,
-}
-
-
-def _path() -> str:
-    return os.environ.get("SYNAPSEML_TPU_TUNED_DEFAULTS", DEFAULT_PATH)
 
 
 def initialized_platform() -> Optional[str]:
@@ -73,76 +37,8 @@ def initialized_platform() -> Optional[str]:
     return jax.default_backend()
 
 
-def backend_is_tpu() -> bool:
-    return initialized_platform() == "tpu"
-
-
-@functools.lru_cache(maxsize=4)
-def _load(path: str) -> dict:
-    # deliberate trace-time read: tuned defaults must be resolved while the
-    # kernel is being built, and the lru_cache bounds it to once per path
-    try:
-        with open(path) as f:  # lint-ok: blocking-io
-            data = json.load(f)
-        return data if isinstance(data, dict) else {}
-    except (OSError, json.JSONDecodeError):
-        return {}
-
-
-def _value_ok(key: str, v) -> bool:
-    """Type-exact validity for one tuned value. bool is an int subclass, so
-    both directions need explicit guards: hist_chunk=true must not become
-    chunk=1, and use_segmented=1 must not pass as a bool."""
-    allowed = ALLOWED[key]
-    if allowed is int:
-        return isinstance(v, int) and not isinstance(v, bool) and v > 0
-    if all(isinstance(a, bool) for a in allowed):
-        return isinstance(v, bool)
-    return v in allowed
-
-
-def validated_values(raw: dict) -> dict:
-    """The subset of ``raw`` that is a known key with an in-range value —
-    the single filter both the read side (tuned_engine_defaults) and the
-    write-side merge (tools/perf_tune.py) apply, so a corrupt entry the
-    reader silently drops can never crash a later merged write."""
-    return {key: raw[key] for key in ALLOWED
-            if key in raw and _value_ok(key, raw[key])}
-
-
-def current_file_values(path: str = None) -> dict:
-    """Validated values currently in the tuned file, ignoring provenance and
-    the backend gate (for write-side merges and change detection)."""
-    p = path or _path()
-    if p in ("", "0", "off"):
-        return {}
-    return validated_values(_load(p))
-
-
-def tuned_engine_defaults() -> dict:
-    """The validated tuned-default mapping for THIS process, or {} when no
-    file exists, the env disables it, or the backend is not (yet) TPU."""
-    path = _path()
-    if path in ("", "0", "off"):
-        return {}
-    if not backend_is_tpu():
-        return {}
-    return validated_values(_load(path))
-
-
-def tuned_default(key: str, env_var: str, fallback):
-    """One field's resolved default: env var > tuned file > fallback.
-    String env values are returned as-is (validation happens in the consumer's
-    __post_init__ so typos fail with a message naming the variable)."""
-    v = os.environ.get(env_var)
-    if v is not None and v != "":
-        return v
-    return tuned_engine_defaults().get(key, fallback)
-
-
 # ---------------------------------------------------------------------------
-# In-process measurement store. Unlike the tuned FILE above (chip facts,
-# persisted, TPU-gated), these are probe results valid only for the current
+# In-process measurement store: probe results valid only for the current
 # process+mesh — link bandwidth, selection timing — consumed by the
 # distributed-GBDT router and core/perfmodel. First caller pays the probe;
 # later boosters on the same mesh read the cached number.
@@ -277,33 +173,3 @@ def clear_measurements() -> None:
             os.remove(path)
         except OSError:
             pass
-
-
-def write_tuned_defaults(values: dict, provenance: dict,
-                         path: str = None) -> Optional[str]:
-    """Write the measured winners atomically (tmp + replace). Unknown keys
-    and out-of-range values are refused — the write side enforces the same
-    table the read side trusts. Returns the path written, or None when the
-    operator disabled the mechanism (SYNAPSEML_TPU_TUNED_DEFAULTS=0) — the
-    write side honors the same sentinel the read side checks."""
-    path = path or _path()
-    if path in ("", "0", "off"):
-        return None
-    clean = {}
-    for key, v in values.items():
-        allowed = ALLOWED.get(key)
-        if allowed is None:
-            raise ValueError(f"unknown tuned-default key: {key!r}")
-        if not _value_ok(key, v):
-            want = ("positive int (not bool)" if allowed is int
-                    else f"one of {allowed} (type-exact)")
-            raise ValueError(f"tuned default {key}={v!r}: want {want}")
-        clean[key] = v
-    clean["provenance"] = dict(provenance)
-    tmp = f"{path}.{os.getpid()}.tmp"
-    with open(tmp, "w") as f:
-        json.dump(clean, f, indent=1, sort_keys=True)
-        f.write("\n")
-    os.replace(tmp, path)
-    _load.cache_clear()
-    return path

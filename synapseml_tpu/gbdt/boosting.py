@@ -18,7 +18,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
 import time as _time
 from typing import Callable, List, Optional, Sequence
 
@@ -27,13 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..core import tuned as _tuned
 from ..ops.quantize import (BinMapper, apply_bins, bin_threshold_to_value,
                             bins_by_compare, compute_bin_mapper)
 
-# default_factory marker for engine knobs resolved via core/tuned.py: lets
-# __post_init__ distinguish "user passed nothing" from an explicit value
-_TUNED_SENTINEL = "__tuned__"
 from .dataset import Dataset, _is_sparse
 from .grower import (Forest, GrowerConfig, TreeArrays, forest_max_depth,
                      forest_predict, grow_tree, split_counter, stack_trees)
@@ -111,27 +106,6 @@ class BoosterConfig:
     # LightGBM data_parallel's actual wire pattern). Explicit values force.
     tree_learner: str = "auto"
     top_k: int = 20
-    # row-partition primitive inside the grower ("sort" | "sort32" | "scan"
-    # | "scatter"); see GrowerConfig.partition_impl. Default resolution
-    # (core/tuned.py): SYNAPSEML_TPU_PARTITION_IMPL env > the on-chip
-    # measured winner in docs/tuned_defaults.json (written by
-    # tools/perf_tune.py, applied only under the TPU backend) > "sort".
-    # Resolved in __post_init__ (validated there — a typo'd env var /
-    # corrupt file fails fast); when the config is constructed BEFORE the
-    # jax backend initializes, the tuned-file lookup is re-run once at
-    # grower() time so all tuned knobs (incl. hist_kernel's chunk, which
-    # resolves at trace time) apply consistently.
-    partition_impl: str = dataclasses.field(
-        default_factory=lambda: _TUNED_SENTINEL)
-    # grower row layout ("partition" | "masked" | "gather");
-    # see GrowerConfig.row_layout — same tuned-default resolution
-    row_layout: str = dataclasses.field(
-        default_factory=lambda: _TUNED_SENTINEL)
-    # segmented histogram kernel: None = auto (TPU + on-device selftest);
-    # True/False forces — the perf_tune A/B differential. The tuned file may
-    # pin it when the A/B measured a real difference on chip.
-    use_segmented: Optional[bool] = dataclasses.field(
-        default_factory=lambda: _TUNED_SENTINEL)
     # growth policy: "leafwise" (LightGBM parity) | "depthwise"
     # (level-batched opt-in; see grower_depthwise.py)
     growth_policy: str = "leafwise"
@@ -170,19 +144,6 @@ class BoosterConfig:
     eval_at: tuple = ()
 
     def __post_init__(self):
-        self._resolve_tuned()
-        # env/tuned-file-sourced fields are validated HERE, not at trace time
-        # deep inside grow_tree: a typo'd SYNAPSEML_TPU_* value (or a corrupt
-        # docs/tuned_defaults.json) must fail at construction with a message
-        # naming its source (ADVICE r3)
-        for field, env in (("partition_impl", "SYNAPSEML_TPU_PARTITION_IMPL"),
-                           ("row_layout", "SYNAPSEML_TPU_ROW_LAYOUT")):
-            v = getattr(self, field)
-            allowed = _tuned.ALLOWED[field]
-            if v not in allowed:
-                raise ValueError(
-                    f"BoosterConfig.{field}={v!r} is not one of {allowed} "
-                    f"(check the {env} env var / docs/tuned_defaults.json)")
         if self.growth_policy not in ("leafwise", "depthwise"):
             raise ValueError(
                 f"BoosterConfig.growth_policy={self.growth_policy!r} is not "
@@ -198,71 +159,8 @@ class BoosterConfig:
                 f"BoosterConfig.tree_learner={self.tree_learner!r} is not "
                 "one of ('auto', 'serial', 'data', 'voting', 'feature')")
 
-    def _resolve_tuned(self):
-        """Fill sentinel-defaulted engine knobs from env > tuned file >
-        hardcoded. Explicitly passed values are never sentinels, so user
-        intent is never overridden. When the jax backend is not initialized
-        yet, the tuned-file gate is closed (core/tuned.py); the affected
-        fields are remembered and re-resolved ONCE at grower() time — by
-        then the training path has initialized the backend, so construction
-        order can't produce a half-tuned config."""
-        deferred = []
-        closed = not _tuned.backend_is_tpu()
-        untuned = []
-        for field, env, fallback in (
-                ("partition_impl", "SYNAPSEML_TPU_PARTITION_IMPL", "sort"),
-                ("row_layout", "SYNAPSEML_TPU_ROW_LAYOUT", "partition"),
-                ("use_segmented", None, None)):
-            if getattr(self, field) is not _TUNED_SENTINEL:
-                continue
-            v = os.environ.get(env) if env else None
-            if v:
-                setattr(self, field, v)
-                continue
-            td = _tuned.tuned_engine_defaults()
-            setattr(self, field, td.get(field, fallback))
-            if field in ("partition_impl", "row_layout") and field not in td:
-                untuned.append(field)
-            if closed:
-                deferred.append((field, fallback))
-        self._deferred_tuned = deferred
-        self._autoconfig = {}
-        self._suggest_kernel_variant(untuned)
-
-    def _suggest_kernel_variant(self, untuned):
-        """Where neither env nor tuned file pinned the kernel variant, let
-        the learned perf model suggest one from recorded kernel-sweep rows
-        (same arms tools/perf_tune.py measures). Low confidence — e.g. no
-        rows for this platform — keeps the hardcoded fallback, so behavior
-        off-TPU is unchanged. The decision is auditable via
-        Booster.metadata["autoconfig"]["kernel_variant"]."""
-        if not untuned:
-            return
-        from ..core import perfmodel
-
-        variant, dec = perfmodel.suggest_kernel_variant()
-        self._autoconfig["kernel_variant"] = dec.provenance()
-        if variant:
-            for field in untuned:
-                setattr(self, field, variant[field])
-
-    def _finalize_tuned(self):
-        """Re-resolve fields whose tuned-file lookup was skipped because the
-        backend was uninitialized at construction (called from grower())."""
-        if getattr(self, "_deferred_tuned", None) and _tuned.backend_is_tpu():
-            td = _tuned.tuned_engine_defaults()
-            untuned = []
-            for field, fallback in self._deferred_tuned:
-                setattr(self, field, td.get(field, fallback))
-                if field in ("partition_impl", "row_layout") and \
-                        field not in td:
-                    untuned.append(field)
-            self._deferred_tuned = []
-            self._suggest_kernel_variant(untuned)
-
     def grower(self, has_categorical: bool = False,
                feature_shards: int = 1) -> GrowerConfig:
-        self._finalize_tuned()
         lr = 1.0 if self.boosting_type == "rf" else self.learning_rate
         feature_mode = self.tree_learner == "feature" and feature_shards > 1
         return GrowerConfig(
@@ -285,9 +183,6 @@ class BoosterConfig:
             max_cat_threshold=self.max_cat_threshold,
             max_cat_to_onehot=self.max_cat_to_onehot,
             min_data_per_group=self.min_data_per_group,
-            partition_impl=self.partition_impl,
-            row_layout=self.row_layout,
-            use_segmented=self.use_segmented,
             growth_policy=self.growth_policy,
             hist_allreduce_dtype=self.hist_allreduce_dtype,
         )
@@ -855,7 +750,6 @@ def _auto_route(cfg, mesh, binned, nfeat, n_rows, multiproc,
 
         feature_ok = (not has_categorical
                       and cfg.growth_policy == "leafwise"
-                      and cfg.row_layout == "partition"
                       and _fpad(nfeat) % n_workers == 0)
         choice, info = route_parallelism(
             nfeat, cfg.max_bin, cfg.top_k, cfg.num_leaves,
@@ -1459,7 +1353,7 @@ def train_booster(
     # decision provenance for the learned auto-configuration layer
     # (core/perfmodel): every model-made choice — and every fallback — is
     # auditable from Booster.metadata["autoconfig"]
-    autoconfig_info = dict(getattr(cfg, "_autoconfig", None) or {})
+    autoconfig_info = {}
     _fit_t0 = _time.perf_counter()
     if cfg.hist_allreduce_dtype == "auto":
         from .grower import resolve_wire_dtype

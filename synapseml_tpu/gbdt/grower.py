@@ -42,7 +42,6 @@ identical on every device (uniform control flow by construction).
 from __future__ import annotations
 
 import math
-import os
 import sys
 from functools import partial
 from typing import NamedTuple, Optional
@@ -62,8 +61,8 @@ BITS = 32  # bitset word width for categorical splits
 def _chunk() -> int:
     """Kernel row chunk; row counts pad to a multiple of this so the Pallas
     grid divides evenly. Resolved lazily at trace time (after backend init)
-    so the SYNAPSEML_TPU_HIST_CHUNK env / docs/tuned_defaults.json knob
-    takes effect without re-importing the module."""
+    so the SYNAPSEML_TPU_HIST_CHUNK env takes effect without re-importing
+    the module."""
     return default_chunk()
 
 
@@ -87,31 +86,11 @@ class GrowerConfig(NamedTuple):
     min_data_per_group: int = 100  # thin categorical groups excluded
     feature_fraction_bynode: float = 1.0  # per-NODE feature sampling
     has_categorical: bool = False  # static: traces out the categorical path
-    # row-partition primitive: "sort" = stable argsort of the 4-way key
-    # (XLA bitonic sort, O(n log^2 n) compare-exchange stages); "scan" =
-    # cumsum + vectorized binary search for the inverse permutation
-    # (O(n log n) gathers — wins when sort stages dominate the split step)
-    partition_impl: str = "sort"
     # growth policy: "leafwise" (LightGBM-parity best-first; default) or
     # "depthwise" (level-batched opt-in — ~depth heavy steps per tree via
     # ONE multi-leaf histogram pass per level; trees differ from LightGBM's
     # leaf-wise order, quality gated in tests; grower_depthwise.py)
     growth_policy: str = "leafwise"
-    # segmented histogram kernel (scalar-prefetch dynamic block offsets —
-    # no dynamic_slice copy or pre-kernel mask multiply per split):
-    # None = auto (TPU + selftest green), True/False forces (perf_tune A/B)
-    use_segmented: Optional[bool] = None
-    # row layout strategy: "partition" keeps rows physically sorted by leaf
-    # (smaller-child histograms scan only the child's contiguous range);
-    # "masked" never moves rows — each split histograms the full row set with
-    # the child mask folded into the kernel's value factor; "gather" keeps
-    # only the (Np,) pos permutation sorted by leaf and gathers the smaller
-    # child's rows through it right before histogramming (one i32 permute
-    # per split instead of the full (FP, size) two-way data movement).
-    # Masked trades ~12x more rows through the MXU kernel for ZERO
-    # sort/permute work per split; which of the three wins is a measured
-    # property of the chip (tools/perf_tune.py)
-    row_layout: str = "partition"
     # histogram allreduce wire precision ladder: "f32" (default), "bf16"
     # (2/3 wire bytes), or "int8" (blockwise-quantized allreduce — EQuARX,
     # arXiv:2506.17615 — ~2 bytes/elem effective incl. per-block scales).
@@ -129,8 +108,8 @@ class GrowerConfig(NamedTuple):
     # ``feature_shards`` devices keeps only its FP/world slice and the
     # per-leaf best splits are exchanged as tiny (world, 5) candidate
     # rows — LightGBM data_parallel's ACTUAL wire pattern, ~halving
-    # collective bytes). "scatter" requires partition layout + leafwise
-    # growth + numeric-only features + FP % feature_shards == 0.
+    # collective bytes). "scatter" requires leafwise growth +
+    # numeric-only features + FP % feature_shards == 0.
     hist_reduce: str = "allreduce"
     feature_shards: int = 1      # static world size for hist_reduce="scatter"
 
@@ -309,118 +288,32 @@ def _hist_reduce_scatter(x, axis_name, wire_dtype: str = "f32"):
     return jnp.concatenate([gh, cnt], axis=-1)
 
 
-def _aligned_window(start, size: int, np_rows: int, chunk: int):
+def _chunk_window(start, size: int, np_rows: int, chunk: int):
     """Chunk-aligned static window ``[cs, cs+S)`` covering any range
     ``[start, start+len)`` with ``len <= size``: ``S = min(size+chunk,
-    np_rows)`` and ``cs`` rounded down to a chunk boundary.
-
-    Unaligned minor-dim dynamic slices cost lane rotations on TPU; the
-    on-chip grow_tree trace (docs/trace_summary_gbdt.md 2026-08-02) put
-    slice+copy at ~37% of device time while the histogram kernel was ~2%.
-    Aligned windows turn every per-split slice/update into a clean
-    tile-aligned DMA, and make the XLA fallback histogram bit-identical to
-    the segmented Pallas kernel's chunk grouping (ops/hist_kernel.py
+    np_rows)`` and ``cs`` rounded down to a chunk boundary, so every
+    per-split slice and update is a tile-aligned DMA and the sliced
+    histogram groups rows as the segmented kernel does (ops/hist_kernel.py
     ``_range_kernel`` uses this same first-chunk formula). Callers' routing
-    keys / masks already guard rows outside [start, start+len).
-    ``SYNAPSEML_TPU_ALIGN_WINDOWS=0`` restores exact-size unaligned windows
-    (on-chip A/B escape hatch).
-
-    The env var is resolved at TRACE TIME, not per call: this function runs
-    inside ``grow_tree``'s jit trace, so the branch taken here is baked into
-    the compiled executable. Flipping the variable after a config's first
-    trace has no effect on already-cached executables — set it before the
-    first ``grow_tree``/``train_booster`` call of the process (as the
-    cached-kernel selftests do), and expect a retrace, not a runtime switch,
-    when it changes between fresh jit keys."""
-    if os.environ.get("SYNAPSEML_TPU_ALIGN_WINDOWS", "1") == "0":
-        return jnp.minimum(start, np_rows - size), size
-    return _chunk_window(start, size, np_rows, chunk)
-
-
-def _chunk_window(start, size: int, np_rows: int, chunk: int):
+    keys / masks guard the rows outside [start, start+len)."""
     S = min(size + chunk, np_rows)
     cs0 = jnp.minimum(start, np_rows - S)
     return (cs0 // chunk) * chunk, S
 
 
-def _stable_partition_src(key: jnp.ndarray, impl: str) -> jnp.ndarray:
-    """Source indices of the stable partition of ``key`` (values in
-    {-1, 0, 1, 2}) — identical to ``jnp.argsort(key, stable=True)``.
-
-    ``impl='scan'`` computes the inverse permutation directly: per-category
-    cumulative counts give each output slot's rank within its category, and a
-    vectorized binary search finds the rank-th member — O(n log n) gathers
-    instead of the bitonic sort's O(n log^2 n) compare-exchange stages.
-    """
-    if impl == "sort":
-        return jnp.argsort(key, stable=True).astype(jnp.int32)
-    if impl == "sort32":
-        # single-operand composite sort: (key+1) in the top bits, position
-        # in the low bits — ascending order IS the stable partition, and the
-        # bitonic network moves one u32 instead of (key, index) pairs
-        n = key.shape[0]
-        if n > (1 << 29):
-            return jnp.argsort(key, stable=True).astype(jnp.int32)
-        shift = max(n - 1, 1).bit_length()
-        comp = ((key + 1).astype(jnp.uint32) << shift) | jnp.arange(
-            n, dtype=jnp.uint32)
-        return (jnp.sort(comp) & jnp.uint32((1 << shift) - 1)).astype(
-            jnp.int32)
-    if impl == "scatter":
-        # destination rank per element via 4 cumsums, then ONE unique-index
-        # scatter inverts the permutation — O(n) work and no compare-exchange
-        # stages at all; whether XLA's TPU scatter beats its bitonic sort is
-        # a measured property of the chip (tools/perf_tune.py phase 2)
-        n = key.shape[0]
-        iota = jnp.arange(n, dtype=jnp.int32)
-        dst = jnp.zeros(n, jnp.int32)
-        off = jnp.int32(0)
-        for v in (-1, 0, 1, 2):
-            isv = key == v
-            rank = jnp.cumsum(isv, dtype=jnp.int32) - 1
-            dst = jnp.where(isv, off + rank, dst)
-            off = off + rank[-1] + 1 if v != 2 else off
-        return jnp.zeros(n, jnp.int32).at[dst].set(
-            iota, unique_indices=True, mode="promise_in_bounds")
-    if impl != "scan":
-        raise ValueError("partition_impl must be 'sort', 'sort32', 'scan' "
-                         f"or 'scatter', got {impl!r}")
-    n = key.shape[0]
-    j = jnp.arange(n, dtype=jnp.int32)
-    cums = [jnp.cumsum(key == v, dtype=jnp.int32) for v in (-1, 0, 1, 2)]
-    offs = jnp.cumsum(jnp.asarray([0] + [c[-1] for c in cums[:3]]))
-    src = jnp.zeros(n, jnp.int32)
-    pick = jnp.full(n, 3, jnp.int32)
-    for ci in (2, 1, 0):
-        pick = jnp.where(j < offs[ci + 1], ci, pick)
-    for ci, c in enumerate(cums):
-        rank = j - offs[ci] + 1
-        s = jnp.searchsorted(c, rank, side="left").astype(jnp.int32)
-        src = jnp.where(pick == ci, s, src)
-    return src
-
-
-def _partition_impl(cfg: GrowerConfig, num_bins_padded: int,
-                    fp: int) -> str:
-    """How a split moves a leaf's rows: ``"kernel"``, one pass of
-    ops/partition_kernel.py, on the TPU backend; elsewhere the XLA primitive
-    that ``cfg.partition_impl`` names, and five gathers."""
-    return ("kernel" if partition_kernel_available(num_bins_padded, fp)
-            else cfg.partition_impl)
-
-
 def _partition_bucket(pos, gs, hs, ms, bT, start, length, fsel, route,
-                      size: int, chunk: int, num_bins_padded: int, impl: str):
+                      size: int, chunk: int, num_bins_padded: int,
+                      use_kernel: bool):
     """One bucket of the split step: stably partition rows [start,
     start+length) of the sorted arrays by ``route`` (bin values of feature
     ``fsel`` -> goes right) inside the chunk-aligned window that covers any
-    range of at most ``size`` rows, the way ``impl`` names
-    (:func:`_partition_impl`). Returns the five updated arrays and the left
-    child's row count."""
+    range of at most ``size`` rows. ``use_kernel``
+    (``partition_kernel_available``: the TPU backend) moves them in one pass
+    of ops/partition_kernel.py; elsewhere a stable ``argsort`` of the 4-way
+    key and five gathers, the kernel's bit-for-bit reference. Returns the
+    five updated arrays and the left child's row count."""
     FP, Np = bT.shape
-    use_kernel = impl == "kernel"
-    cs, S = (_chunk_window if use_kernel else _aligned_window)(
-        start, size, Np, chunk)
+    cs, S = _chunk_window(start, size, Np, chunk)
     idx = cs + jnp.arange(S, dtype=jnp.int32)
     binrow = lax.dynamic_slice(bT, (fsel, cs), (1, S))[0]
     gr = route(binrow)
@@ -440,7 +333,7 @@ def _partition_bucket(pos, gs, hs, ms, bT, start, length, fsel, route,
     key = jnp.where(idx < start, -1,
                     jnp.where(idx >= start + length, 2,
                               gr.astype(jnp.int32)))
-    src = _stable_partition_src(key, impl)
+    src = jnp.argsort(key, stable=True).astype(jnp.int32)
     nl_loc = jnp.sum(key == 0).astype(jnp.int32)
 
     def perm1(a):
@@ -598,7 +491,7 @@ def _node_mask_fn(cfg: GrowerConfig, featp, f: int, node_key):
 
 
 # ---------------------------------------------------------------------------
-# Tree growth — helpers shared by the "partition" and "masked" row layouts
+# Tree growth — helpers (shared with grower_depthwise.py and stream.py)
 # ---------------------------------------------------------------------------
 
 def _pad_grow_inputs(binned, grad, hess, in_bag, feature_active,
@@ -667,8 +560,9 @@ def _route_right(binrow, bsel, dl, nanbin_f, bitset, cat_split,
 
 def _init_split_state(L: int, B: int, bw: int, hist_root, rg, rf, rb, rdl,
                       rcl, FP: int):
-    """Initial per-leaf split state + tree-structure arrays (shared fields of
-    both layout states): root occupies leaf 0."""
+    """Initial per-leaf split state + tree-structure arrays (the fields the
+    leaf-wise, depth-wise and streamed growers' states share): root occupies
+    leaf 0."""
     z1 = lambda dt, fill=0: jnp.full((max(L - 1, 1),), fill, dt)
     return dict(
         hist=jnp.zeros((L, FP, B, 3), jnp.float32).at[0].set(hist_root),
@@ -707,7 +601,7 @@ def _select_split_leaf(s, cfg: GrowerConfig, L: int):
 def _common_split_updates(s, cfg: GrowerConfig, l, fsel, bsel, gain_l, dl,
                           bitset, cat_split, hist_left, hist_right,
                           bg2, bf2, bb2, bdl2, bcl2, G_l, H_l, C_l):
-    """``_replace`` kwargs shared by both layouts for one split of leaf ``l``:
+    """``_replace`` kwargs for one split of leaf ``l``:
     hist cache, per-leaf best-split state, and tree-structure bookkeeping
     (leaf numbering per LightGBM Tree::Split — left keeps ``l``, right becomes
     ``num_splits + 1``, child pointers ``~leaf``)."""
@@ -854,8 +748,7 @@ def _grow_tree_impl(binned, grad, hess, in_bag, feature_active, is_categorical,
         binned, grad, hess, in_bag, feature_active, is_categorical, monotone,
         nan_bins, FP, Np)
 
-    use_seg = (cfg.use_segmented if cfg.use_segmented is not None
-               else segmented_histograms_available(B))
+    use_seg = segmented_histograms_available(B)
 
     def build_hist(bT, gs, hs, ms, child_start, child_len):
         """Histogram of sorted rows [child_start, child_start+child_len) via
@@ -874,13 +767,11 @@ def _grow_tree_impl(binned, grad, hess, in_bag, feature_active, is_categorical,
                     return range_histogram(bT_, gs_, hs_, ms_, cstart, clen,
                                            B, seg)
                 return br
-
-            bidx = jnp.searchsorted(sizes_arr, child_len, side="left")
         else:
             def make_branch(size):
                 def br(args):
                     bT_, gs_, hs_, ms_, cstart, clen = args
-                    cs, S = _aligned_window(cstart, size, Np, chunk)
+                    cs, S = _chunk_window(cstart, size, Np, chunk)
                     idx = cs + jnp.arange(S, dtype=jnp.int32)
                     mask = ((idx >= cstart)
                             & (idx < cstart + clen)).astype(jnp.float32)
@@ -891,7 +782,7 @@ def _grow_tree_impl(binned, grad, hess, in_bag, feature_active, is_categorical,
                     return child_histogram(bsl, gsl, hsl, msl, B)
                 return br
 
-            bidx = jnp.searchsorted(sizes_arr, child_len, side="left")
+        bidx = jnp.searchsorted(sizes_arr, child_len, side="left")
         hist = lax.switch(jnp.minimum(bidx, len(sizes) - 1),
                           [make_branch(s) for s in sizes],
                           (bT, gs, hs, ms, child_start, child_len))
@@ -950,7 +841,7 @@ def _grow_tree_impl(binned, grad, hess, in_bag, feature_active, is_categorical,
         **_init_split_state(L, B, bw, hist_root, rg, rf, rb, rdl, rcl, FPo),
     )
 
-    partition_impl = _partition_impl(cfg, B, FP)
+    use_kernel = partition_kernel_available(B, FP)
 
     def partition(pos, gs, hs, ms, bT, start, length, fsel, bsel, dl, bitset,
                   cat_split, nanbin_f):
@@ -962,7 +853,7 @@ def _grow_tree_impl(binned, grad, hess, in_bag, feature_active, is_categorical,
         def make_branch(size):
             return lambda args: _partition_bucket(
                 *args, start, length, fsel, route, size, chunk, B,
-                partition_impl)
+                use_kernel)
 
         bidx = jnp.searchsorted(sizes_arr, length, side="left")
         return lax.switch(jnp.minimum(bidx, len(sizes) - 1),
@@ -1025,293 +916,6 @@ def _grow_tree_impl(binned, grad, hess, in_bag, feature_active, is_categorical,
     return _finalize_tree(s, cfg, L), _node_of_row_from_ranges(s, L, Np, n)
 
 
-class _GatherState(NamedTuple):
-    pos: jnp.ndarray             # (Np,) i32: sorted position -> original row
-    leaf_start: jnp.ndarray      # (L,) i32
-    leaf_len: jnp.ndarray        # (L,) i32
-    hist: jnp.ndarray            # (L, FP, B, 3) f32 cache
-    bgain: jnp.ndarray
-    bfeat: jnp.ndarray
-    bbin: jnp.ndarray
-    bdl: jnp.ndarray
-    bcl: jnp.ndarray
-    depth: jnp.ndarray
-    leaf_parent: jnp.ndarray
-    leaf_is_right: jnp.ndarray
-    split_feature: jnp.ndarray
-    split_bin: jnp.ndarray
-    split_gain: jnp.ndarray
-    split_type: jnp.ndarray
-    default_left: jnp.ndarray
-    cat_bitset: jnp.ndarray
-    left_child: jnp.ndarray
-    right_child: jnp.ndarray
-    internal_value: jnp.ndarray
-    internal_count: jnp.ndarray
-    num_splits: jnp.ndarray
-
-
-def _grow_tree_impl_gather(binned, grad, hess, in_bag, feature_active,
-                           is_categorical, monotone, nan_bins,
-                           cfg: GrowerConfig, axis_name: Optional[str],
-                           node_key=None, cat_nbins=None):
-    """row_layout="gather": the third hot-loop design. Rows never move —
-    grad/hess/mask/bins stay in original row order; only the (Np,) ``pos``
-    permutation is maintained sorted-by-leaf. Each split permutes ONE i32
-    vector, and the smaller child's rows are gathered through ``pos`` just
-    before histogramming. Per split this moves O(size) i32 + O(child·FP)
-    gathered bins, vs the partition layout's O(size·FP) two-way permute —
-    same tree bitwise (same split decisions, same stable partition)."""
-    n, f = binned.shape
-    L = cfg.num_leaves
-    B = pad_bins(cfg.num_bins)
-    FP = features_padded(f)
-    chunk = _chunk()     # resolved ONCE per trace: within-trace consistency
-    Np = -(-n // chunk) * chunk
-    bw = (B + BITS - 1) // BITS
-    l1 = jnp.float32(cfg.lambda_l1)
-    l2 = jnp.float32(cfg.lambda_l2)
-    sizes = _bucket_sizes(Np)
-    sizes_arr = jnp.asarray(sizes, jnp.int32)
-
-    bT0, gs0, hs0, ms0, featp, catp, monop, nanp = _pad_grow_inputs(
-        binned, grad, hess, in_bag, feature_active, is_categorical, monotone,
-        nan_bins, FP, Np)
-
-    def build_hist(pos, child_start, child_len):
-        """Histogram of child rows gathered through ``pos``; psum across the
-        data axis if present."""
-        def make_branch(size):
-            def br(args):
-                pos_, cstart, clen = args
-                cs, S = _aligned_window(cstart, size, Np, chunk)
-                idx = cs + jnp.arange(S, dtype=jnp.int32)
-                mask = ((idx >= cstart) & (idx < cstart + clen)
-                        ).astype(jnp.float32)
-                posl = lax.dynamic_slice(pos_, (cs,), (S,))
-                gsl = gs0[posl] * mask
-                hsl = hs0[posl] * mask
-                msl = ms0[posl] * mask
-                bsl = bT0[:, posl]
-                return child_histogram(bsl, gsl, hsl, msl, B)
-            return br
-
-        bidx = jnp.searchsorted(sizes_arr, child_len, side="left")
-        hist = lax.switch(jnp.minimum(bidx, len(sizes) - 1),
-                          [make_branch(s) for s in sizes],
-                          (pos, child_start, child_len))
-        return _maybe_psum(hist, axis_name, cfg.hist_allreduce_dtype)
-
-    nmask = _node_mask_fn(cfg, featp, f, node_key)
-    catb = _pad_cat_nbins(cat_nbins, f, FP, B)
-
-    def best_of(hist_leaf, fmask):
-        return _best_for_leaf(hist_leaf, fmask, catp, monop, nanp, cfg, l1,
-                              l2, catb)
-
-    # ---- root: no gather needed (pos is identity) ------------------------
-    hist_root = _maybe_psum(child_histogram(bT0, gs0, hs0, ms0, B),
-                            axis_name, cfg.hist_allreduce_dtype)
-    rg, rf, rb, rdl, rcl, _ = best_of(hist_root, nmask(jnp.int32(2 * (L - 1))))
-
-    init = _GatherState(
-        pos=jnp.arange(Np, dtype=jnp.int32),
-        leaf_start=jnp.zeros(L, jnp.int32),
-        leaf_len=jnp.zeros(L, jnp.int32).at[0].set(Np),
-        **_init_split_state(L, B, bw, hist_root, rg, rf, rb, rdl, rcl, FP),
-    )
-
-    def partition(pos, start, length, fsel, bsel, dl, bitset, cat_split,
-                  nanbin_f):
-        """Stably partition the leaf's range of ``pos`` by the split;
-        returns (updated pos, LOCAL left-child row count)."""
-        def make_branch(size):
-            def br(pos_):
-                cs, S = _aligned_window(start, size, Np, chunk)
-                idx = cs + jnp.arange(S, dtype=jnp.int32)
-                posl = lax.dynamic_slice(pos_, (cs,), (S,))
-                binrow = bT0[fsel, posl]
-                gr = _route_right(binrow, bsel, dl, nanbin_f, bitset,
-                                  cat_split, cfg, bw)
-                key = jnp.where(idx < start, -1,
-                                jnp.where(idx >= start + length, 2,
-                                          gr.astype(jnp.int32)))
-                src = _stable_partition_src(key, cfg.partition_impl)
-                nl_loc = jnp.sum(key == 0).astype(jnp.int32)
-                return lax.dynamic_update_slice(pos_, posl[src], (cs,)), nl_loc
-            return br
-
-        bidx = jnp.searchsorted(sizes_arr, length, side="left")
-        return lax.switch(jnp.minimum(bidx, len(sizes) - 1),
-                          [make_branch(s) for s in sizes], pos)
-
-    def body(i, s: _GatherState):
-        l, do = _select_split_leaf(s, cfg, L)
-
-        def step(s: _GatherState) -> _GatherState:
-            gain_l, fsel, bsel, dl = s.bgain[l], s.bfeat[l], s.bbin[l], s.bdl[l]
-            start = s.leaf_start[l]
-            length = s.leaf_len[l]
-            hist_parent = s.hist[l]
-            totals = hist_parent[0].sum(axis=0)
-            G_l, H_l, C_l = totals[0], totals[1], totals[2]
-            bitset, cat_split = _winning_cat_bitset(hist_parent, fsel, bsel,
-                                                    catp, cfg, B, bw, catb)
-
-            pos2, nl_loc = partition(s.pos, start, length, fsel, bsel, dl,
-                                     bitset, cat_split, nanp[fsel])
-
-            cl_glob = s.bcl[l]
-            left_small = cl_glob * 2.0 <= C_l
-            child_start = jnp.where(left_small, start, start + nl_loc)
-            child_len = jnp.where(left_small, nl_loc, length - nl_loc)
-            hist_small = build_hist(pos2, child_start, child_len)
-            hist_left = jnp.where(left_small, hist_small,
-                                  hist_parent - hist_small)
-            hist_right = hist_parent - hist_left
-
-            i_node_id = s.num_splits
-            masks2 = jnp.stack([nmask(i_node_id * 2),
-                                nmask(i_node_id * 2 + 1)])
-            bg2, bf2, bb2, bdl2, bcl2, _ = jax.vmap(best_of)(
-                jnp.stack([hist_left, hist_right]), masks2)
-
-            new_right = s.num_splits + 1
-            return s._replace(
-                pos=pos2,
-                leaf_start=s.leaf_start.at[l].set(start)
-                                       .at[new_right].set(start + nl_loc),
-                leaf_len=s.leaf_len.at[l].set(nl_loc)
-                                    .at[new_right].set(length - nl_loc),
-                **_common_split_updates(s, cfg, l, fsel, bsel, gain_l, dl,
-                                        bitset, cat_split, hist_left,
-                                        hist_right, bg2, bf2, bb2, bdl2, bcl2,
-                                        G_l, H_l, C_l),
-            )
-
-        return lax.cond(do, step, lambda s: s, s)
-
-    s = lax.fori_loop(0, L - 1, body, init) if L > 1 else init
-    return _finalize_tree(s, cfg, L), _node_of_row_from_ranges(s, L, Np, n)
-
-
-class _MaskedState(NamedTuple):
-    node: jnp.ndarray            # (Np,) i32 current leaf id per row
-    hist: jnp.ndarray            # (L, FP, B, 3) f32 cache — shared-field block
-    bgain: jnp.ndarray           # (see _init_split_state)
-    bfeat: jnp.ndarray
-    bbin: jnp.ndarray
-    bdl: jnp.ndarray
-    bcl: jnp.ndarray
-    depth: jnp.ndarray
-    leaf_parent: jnp.ndarray
-    leaf_is_right: jnp.ndarray
-    split_feature: jnp.ndarray
-    split_bin: jnp.ndarray
-    split_gain: jnp.ndarray
-    split_type: jnp.ndarray
-    default_left: jnp.ndarray
-    cat_bitset: jnp.ndarray
-    left_child: jnp.ndarray
-    right_child: jnp.ndarray
-    internal_value: jnp.ndarray
-    internal_count: jnp.ndarray
-    num_splits: jnp.ndarray
-
-
-def _grow_tree_impl_masked(binned, grad, hess, in_bag, feature_active,
-                           is_categorical, monotone, nan_bins,
-                           cfg: GrowerConfig, axis_name: Optional[str],
-                           node_key=None, cat_nbins=None):
-    """Masked-row grower: rows never move. Each split routes leaf ``l``'s rows
-    by updating a per-row ``node`` array and histograms the smaller child with
-    the child-membership mask multiplied into the kernel's (g, h, count)
-    factors over the FULL row set. Removes every per-split sort/permute at the
-    cost of a full-N kernel pass per split — the winning trade when the MXU
-    histogram's per-row cost is far below the partition's sort cost
-    (measured: tools/perf_tune.py phases 2-3). Produces bitwise-identical
-    trees to the partitioned grower (tests/test_gbdt_engine.py)."""
-    n, f = binned.shape
-    L = cfg.num_leaves
-    B = pad_bins(cfg.num_bins)
-    FP = features_padded(f)
-    chunk = _chunk()     # resolved ONCE per trace: within-trace consistency
-    Np = -(-n // chunk) * chunk
-    bw = (B + BITS - 1) // BITS
-    l1 = jnp.float32(cfg.lambda_l1)
-    l2 = jnp.float32(cfg.lambda_l2)
-
-    bT0, gs0, hs0, ms0, featp, catp, monop, nanp = _pad_grow_inputs(
-        binned, grad, hess, in_bag, feature_active, is_categorical, monotone,
-        nan_bins, FP, Np)
-
-    def build_hist_masked(sel):
-        hist = child_histogram(bT0, gs0 * sel, hs0 * sel, ms0 * sel, B)
-        return _maybe_psum(hist, axis_name, cfg.hist_allreduce_dtype)
-
-    nmask = _node_mask_fn(cfg, featp, f, node_key)
-    catb = _pad_cat_nbins(cat_nbins, f, FP, B)
-
-    def best_of(hist_leaf, fmask):
-        return _best_for_leaf(hist_leaf, fmask, catp, monop, nanp, cfg, l1,
-                              l2, catb)
-
-    hist_root = build_hist_masked(jnp.ones(Np, jnp.float32))
-    rg, rf, rb, rdl, rcl, _ = best_of(hist_root, nmask(jnp.int32(2 * (L - 1))))
-
-    init = _MaskedState(
-        node=jnp.zeros(Np, jnp.int32),
-        **_init_split_state(L, B, bw, hist_root, rg, rf, rb, rdl, rcl, FP),
-    )
-
-    def body(i, s: _MaskedState):
-        l, do = _select_split_leaf(s, cfg, L)
-
-        def step(s: _MaskedState) -> _MaskedState:
-            gain_l, fsel, bsel, dl = s.bgain[l], s.bfeat[l], s.bbin[l], s.bdl[l]
-            hist_parent = s.hist[l]
-            totals = hist_parent[0].sum(axis=0)
-            G_l, H_l, C_l = totals[0], totals[1], totals[2]
-            bitset, cat_split = _winning_cat_bitset(hist_parent, fsel, bsel,
-                                                    catp, cfg, B, bw, catb)
-
-            # route leaf l's rows: right-goers move to leaf id num_splits+1
-            binrow = lax.dynamic_slice(bT0, (fsel, 0), (1, Np))[0]
-            gr = _route_right(binrow, bsel, dl, nanp[fsel], bitset, cat_split,
-                              cfg, bw)
-            new_right = s.num_splits + 1
-            node2 = jnp.where((s.node == l) & gr, new_right, s.node)
-
-            # build the globally-smaller child; sibling by subtraction
-            cl_glob = s.bcl[l]
-            left_small = cl_glob * 2.0 <= C_l
-            child_id = jnp.where(left_small, l, new_right)
-            sel = (node2 == child_id).astype(jnp.float32)
-            hist_small = build_hist_masked(sel)
-            hist_left = jnp.where(left_small, hist_small,
-                                  hist_parent - hist_small)
-            hist_right = hist_parent - hist_left
-
-            i_node_id = s.num_splits
-            masks2 = jnp.stack([nmask(i_node_id * 2),
-                                nmask(i_node_id * 2 + 1)])
-            bg2, bf2, bb2, bdl2, bcl2, _ = jax.vmap(best_of)(
-                jnp.stack([hist_left, hist_right]), masks2)
-
-            return s._replace(
-                node=node2,
-                **_common_split_updates(s, cfg, l, fsel, bsel, gain_l, dl,
-                                        bitset, cat_split, hist_left,
-                                        hist_right, bg2, bf2, bb2, bdl2, bcl2,
-                                        G_l, H_l, C_l),
-            )
-
-        return lax.cond(do, step, lambda s: s, s)
-
-    s = lax.fori_loop(0, L - 1, body, init) if L > 1 else init
-    return _finalize_tree(s, cfg, L), s.node[:n]
-
-
 @partial(jax.jit, static_argnames=("cfg", "axis_name"))
 def grow_tree(
     binned: jnp.ndarray,         # (N, F) uint8/uint16 bin ids
@@ -1336,10 +940,10 @@ def grow_tree(
         raise ValueError("hist_reduce must be 'allreduce' or 'scatter', "
                          f"got {cfg.hist_reduce!r}")
     if cfg.hist_reduce == "scatter" and cfg.feature_shards > 1:
-        if cfg.growth_policy != "leafwise" or cfg.row_layout != "partition":
+        if cfg.growth_policy != "leafwise":
             raise ValueError(
                 "hist_reduce='scatter' (feature-parallel) supports only "
-                "leafwise growth with the partition row layout")
+                "leafwise growth")
         if cfg.has_categorical:
             raise ValueError("hist_reduce='scatter' does not support "
                              "categorical features (the winning split's "
@@ -1356,20 +960,6 @@ def grow_tree(
     if cfg.growth_policy != "leafwise":
         raise ValueError("growth_policy must be 'leafwise' or 'depthwise', "
                          f"got {cfg.growth_policy!r}")
-    if cfg.row_layout == "masked":
-        return _grow_tree_impl_masked(binned, grad, hess, in_bag,
-                                      feature_active, is_categorical, monotone,
-                                      nan_bins, cfg, axis_name, node_key,
-                                      cat_nbins)
-    if cfg.row_layout == "gather":
-        return _grow_tree_impl_gather(binned, grad, hess, in_bag,
-                                      feature_active, is_categorical, monotone,
-                                      nan_bins, cfg, axis_name, node_key,
-                                      cat_nbins)
-    if cfg.row_layout != "partition":
-        raise ValueError(
-            "row_layout must be 'partition', 'masked' or 'gather', "
-            f"got {cfg.row_layout!r}")
     return _grow_tree_impl(binned, grad, hess, in_bag, feature_active,
                            is_categorical, monotone, nan_bins, cfg, axis_name,
                            node_key, cat_nbins)
@@ -1378,12 +968,13 @@ def grow_tree(
 def split_counter(cfg: GrowerConfig, nfeat: int) -> Optional[str]:
     """Name of the ``trainingMeasures`` counter for the splits of trees grown
     under ``cfg``: which path moves a leaf's rows — the partition kernel or
-    the XLA primitives (``partition_impl``). None where no rows move."""
-    if cfg.growth_policy != "leafwise" or cfg.row_layout != "partition":
+    ``argsort`` and five gathers. None for depth-wise growth, which has no
+    leaf ranges to partition."""
+    if cfg.growth_policy != "leafwise":
         return None
-    impl = _partition_impl(cfg, pad_bins(cfg.num_bins),
-                           features_padded(nfeat))
-    return "splitsPartitionKernel" if impl == "kernel" else "splitsPartitionSort"
+    use_kernel = partition_kernel_available(pad_bins(cfg.num_bins),
+                                            features_padded(nfeat))
+    return "splitsPartitionKernel" if use_kernel else "splitsPartitionSort"
 
 
 # ---------------------------------------------------------------------------

@@ -113,7 +113,7 @@ class StreamedDataset:
     geometry choice.
 
     ``prepare(config)`` resolves the chunk geometry (io/ingest.py:
-    explicit > env > tuned file > bandwidth micro-probe, capped by the
+    explicit > env > bandwidth micro-probe, capped by the
     ``SYNAPSEML_TPU_STREAM_MEM_BUDGET`` device budget), learns boundaries
     (sketch — or adopts ``mapper``), and re-chunks the stream into uniform
     ``(FP, C)`` feature-major quantized host chunks (the last chunk padded
@@ -321,7 +321,7 @@ class StreamedDataset:
             C += mult - C % mult
         self.chunk_rows = C
         # perfmodel provenance when the probe branch picked the geometry
-        # (None under the explicit/env/tuned bypass)
+        # (None under the explicit/env bypass)
         from ..io import ingest as _ingest
 
         self.chunk_decision = _ingest.last_chunk_decision()
@@ -821,7 +821,7 @@ def train_booster_streamed(
         W = int(dict(mesh.shape).get(_DA_NAME, 1))
 
     _fit_t0 = _time.perf_counter()
-    autoconfig_info = dict(getattr(cfg, "_autoconfig", None) or {})
+    autoconfig_info = {}
 
     with measures.span("streamIngest"):
         data.prepare(cfg, row_multiple=W)

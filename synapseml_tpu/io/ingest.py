@@ -41,8 +41,7 @@ matrix from host memory once per tree level. They now share this layer:
 
 Chunk geometry (:func:`stream_chunk_rows` / :func:`stream_depth`) resolves
 explicit arg > ``SYNAPSEML_TPU_STREAM_CHUNK_ROWS`` / ``_STREAM_DEPTH`` env >
-tuned file (``docs/tuned_defaults.json``, TPU-gated) > a one-time
-host→device bandwidth micro-probe recorded in the ``core/tuned.py``
+a one-time host→device bandwidth micro-probe recorded in the ``core/tuned.py``
 measurement store, capped by the ``SYNAPSEML_TPU_STREAM_MEM_BUDGET`` byte
 budget (the knob the out-of-core bench uses to simulate a 10x-undersized
 device). See docs/out-of-core.md.
@@ -277,7 +276,7 @@ def pump_polling(step: Callable[[], bool], stop: threading.Event,
 
 
 # ---------------------------------------------------------------------------
-# Chunk geometry: explicit > env > tuned file > measured micro-probe
+# Chunk geometry: explicit > env > measured micro-probe
 # ---------------------------------------------------------------------------
 
 _PROBE_BYTES = 4 << 20         # one device_put of 4 MiB prices the link
@@ -320,7 +319,7 @@ _LAST_CHUNK_DECISION = None
 def last_chunk_decision():
     """Provenance dict of the most recent model-resolved chunk geometry
     (``core.perfmodel.suggest_chunk_rows``), or None when the probe branch
-    has not run (explicit/env/tuned bypass) or the model was unavailable."""
+    has not run (explicit/env bypass) or the model was unavailable."""
     return _LAST_CHUNK_DECISION
 
 
@@ -344,7 +343,6 @@ def stream_chunk_rows(row_bytes: int, explicit: Optional[int] = None,
     """Rows per streamed chunk for rows of ``row_bytes`` each.
 
     Resolution: ``explicit`` arg > ``SYNAPSEML_TPU_STREAM_CHUNK_ROWS`` env >
-    tuned file ``stream_chunk_rows`` (TPU-gated, docs/tuned_defaults.json) >
     bandwidth micro-probe (chunk ≈ ``_TARGET_CHUNK_S`` of measured link
     time). ``read_bps``, when given (disk-backed sources), is the measured
     disk read bandwidth: a chunk crosses disk→host then host→device
@@ -363,10 +361,6 @@ def stream_chunk_rows(row_bytes: int, explicit: Optional[int] = None,
         if env:
             rows = int(env)
     if rows is None:
-        v = _tuned.tuned_engine_defaults().get("stream_chunk_rows")
-        if v is not None:
-            rows = int(v)
-    if rows is None:
         plat = _tuned.initialized_platform()
         bw = None
         if plat is None:
@@ -380,7 +374,7 @@ def stream_chunk_rows(row_bytes: int, explicit: Optional[int] = None,
                 bw = 1.0 / (1.0 / bw + 1.0 / float(read_bps))
             rows = int(bw * _TARGET_CHUNK_S / row_bytes)
         # the [min, max] clamp disciplines only the PROBE estimate — an
-        # explicit/env/tuned value is operator intent and wins as given
+        # explicit/env value is operator intent and wins as given
         rows = min(max(rows, _MIN_CHUNK_ROWS), _MAX_CHUNK_ROWS)
         # recorded io_chunk_rows rows (bench_oocore_gbdt) can displace the
         # probe formula; without a measured match the formula IS the model's
@@ -396,17 +390,12 @@ def stream_chunk_rows(row_bytes: int, explicit: Optional[int] = None,
 
 def stream_depth(explicit: Optional[int] = None) -> int:
     """In-flight chunk depth: explicit > ``SYNAPSEML_TPU_STREAM_DEPTH`` env >
-    tuned file ``stream_depth`` > 2 (double buffering)."""
-    from ..core import tuned as _tuned
-
+    2 (double buffering)."""
     if explicit is not None:
         return max(int(explicit), 1)
     env = os.environ.get("SYNAPSEML_TPU_STREAM_DEPTH")
     if env:
         return max(int(env), 1)
-    v = _tuned.tuned_engine_defaults().get("stream_depth")
-    if v is not None:
-        return max(int(v), 1)
     return 2
 
 

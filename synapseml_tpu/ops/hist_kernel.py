@@ -101,14 +101,10 @@ def _eager_selftest(fn):
 
 
 def default_chunk() -> int:
-    """Rows per kernel step. Resolution: SYNAPSEML_TPU_HIST_CHUNK env > the
-    on-chip sweep winner in docs/tuned_defaults.json (tools/perf_tune.py
-    phase D; applied only under the TPU backend — core/tuned.py) > 2048.
-    A malformed env value fails HERE with the variable named, not as a
-    ZeroDivisionError mid-trace (file values are validated on read)."""
-    from ..core import tuned as _tuned
-
-    v = _tuned.tuned_default("hist_chunk", "SYNAPSEML_TPU_HIST_CHUNK", 2048)
+    """Rows per kernel step: SYNAPSEML_TPU_HIST_CHUNK, else 2048. A
+    malformed value fails HERE with the variable named, not as a
+    ZeroDivisionError mid-trace."""
+    v = os.environ.get("SYNAPSEML_TPU_HIST_CHUNK") or 2048
     try:
         c = int(v)
         if c <= 0:
@@ -200,22 +196,11 @@ def _packed_accumulate(bin_ref, out_ref, g1, h1, m1, *, C: int, K1: int,
 def _pack_for(K1: int, FB: int, pack) -> int:
     """Features per dot: fill the 128-row MXU tile (M = PACK*K1) while
     keeping N = PACK*24 within one 128-lane tile; PACK must divide FB.
-    ``pack`` (arg > SYNAPSEML_TPU_HIST_PACK env > tuned file) forces —
-    clamped to the same tile constraints (128 // K1, 5, FB) so a forced
-    value can never lose the one-tile-pass property the kernel docstring
-    promises."""
-    from ..core import tuned as _tuned
-
-    force = pack or _tuned.tuned_default("hist_pack",
-                                         "SYNAPSEML_TPU_HIST_PACK", None)
-    return clamp_pack(int(force) if force else 128, K1, FB)
-
-
-def clamp_pack(want: int, K1: int, FB: int) -> int:
-    """The pure tile clamp shared by _pack_for and the tuner's
-    formula-default computation (tools/perf_tune.py) — one copy of the
-    constraint math, so the two sides cannot desync."""
-    PACK = max(1, min(want, 128 // K1, 5, FB))
+    ``pack`` (the argument, else SYNAPSEML_TPU_HIST_PACK) forces — clamped
+    to the same tile constraints (128 // K1, 5, FB) so a forced value can
+    never lose the one-tile-pass property the kernel docstring promises."""
+    force = pack or os.environ.get("SYNAPSEML_TPU_HIST_PACK")
+    PACK = max(1, min(int(force) if force else 128, 128 // K1, 5, FB))
     while FB % PACK:
         PACK -= 1
     return PACK

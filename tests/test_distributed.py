@@ -7,7 +7,6 @@ inserts replaces LightGBM's ring allreduce), and mesh helpers must compose."""
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from synapseml_tpu.parallel import (DATA_AXIS, allreduce_mean, allreduce_sum,
                                     allreduce_sum_quantized, make_mesh,
@@ -103,18 +102,14 @@ def test_reduce_scatter_sum_quantized_owns_chunks(eight_devices):
     np.testing.assert_allclose(np.asarray(out), want, atol=tol)
 
 
-@pytest.mark.parametrize("layout", ["partition", "gather", "masked"])
-def test_distributed_training_matches_single(binary_data, eight_devices,
-                                             layout):
+def test_distributed_training_matches_single(binary_data, eight_devices):
     """Training with rows device-put onto an 8-device mesh must give the same
-    model as single-device (same histograms → same splits) — for each row
-    layout whose psum placement differs."""
+    model as single-device (same histograms → same splits)."""
     from synapseml_tpu.gbdt import BoosterConfig, train_booster
 
     Xtr, Xte, ytr, _ = binary_data
     n = (len(ytr) // 8) * 8      # even shards, no padding rows
-    cfg = BoosterConfig(objective="binary", num_iterations=5,
-                        row_layout=layout)
+    cfg = BoosterConfig(objective="binary", num_iterations=5)
     b1 = train_booster(Xtr[:n], ytr[:n], cfg)
     p1 = b1.predict(Xte)
 

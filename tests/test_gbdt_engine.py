@@ -194,70 +194,56 @@ def test_dataset_prebinned_matches_raw(binary_data):
     assert len(b2.trees) == 3
 
 
-@pytest.mark.parametrize("impl", ["scan", "scatter", "sort32"])
-def test_partition_impl_matches_sort(binary_data, impl):
-    """Every alternate stable-partition primitive must grow bitwise-identical
-    trees to the argsort-based one (same src permutation by construction)."""
-    X, _, y, _ = binary_data
-    # baseline spelled out: env-flipped defaults must not make this vacuous
-    cfg_s = BoosterConfig(objective="binary", num_iterations=4, num_leaves=15,
-                          partition_impl="sort", row_layout="partition")
-    cfg_c = BoosterConfig(objective="binary", num_iterations=4, num_leaves=15,
-                          partition_impl=impl, row_layout="partition")
-    b_s = train_booster(X, y, cfg_s)
-    b_c = train_booster(X, y, cfg_c)
-    for ts, tc in zip(b_s.trees, b_c.trees):
-        np.testing.assert_array_equal(np.asarray(ts.split_feature),
-                                      np.asarray(tc.split_feature))
-        np.testing.assert_allclose(np.asarray(ts.leaf_value),
-                                   np.asarray(tc.leaf_value), rtol=1e-6)
+@pytest.mark.parametrize("size,np_rows,chunk", [(512, 2048, 256),
+                                                (2048, 2048, 256),
+                                                (384, 1920, 128)])
+def test_chunk_window_covers_every_range_and_is_aligned(size, np_rows, chunk):
+    """The one window rule the split step and the sliced histogram share:
+    static length, chunk-aligned start, inside the table, and covering any
+    range of at most ``size`` rows wherever it starts."""
+    from synapseml_tpu.gbdt.grower import _chunk_window
+
+    for start in (0, 1, chunk - 1, chunk, np_rows // 2 + 7, np_rows - size,
+                  np_rows - 1):
+        length = min(size, np_rows - start)
+        cs, S = _chunk_window(jnp.int32(start), size, np_rows, chunk)
+        cs = int(cs)
+        assert S == min(size + chunk, np_rows)
+        assert cs % chunk == 0 and 0 <= cs and cs + S <= np_rows
+        assert cs <= start and start + length <= cs + S
 
 
-@pytest.mark.parametrize("layout", ["masked", "gather"])
-def test_row_layout_matches_partition(binary_data, layout):
-    """Every alternate row layout (masked: no row movement, full-N masked
-    histograms; gather: pos-only permutation with child gathers) must grow
-    identical trees to the partitioned grower, including NaN routing."""
-    X, _, y, _ = binary_data
-    X = np.array(X)
-    X[::7, 3] = np.nan                 # exercise learned missing direction
-    for extra in ({"num_leaves": 15},
-                  {"num_leaves": 31, "min_data_in_leaf": 5}):
-        cfg_p = BoosterConfig(objective="binary", num_iterations=4,
-                              row_layout="partition", partition_impl="sort",
-                              **extra)
-        cfg_m = BoosterConfig(objective="binary", num_iterations=4,
-                              row_layout=layout, partition_impl="sort",
-                              **extra)
-        b_p = train_booster(X, y, cfg_p)
-        b_m = train_booster(X, y, cfg_m)
-        for tp, tm in zip(b_p.trees, b_m.trees):
-            np.testing.assert_array_equal(np.asarray(tp.split_feature),
-                                          np.asarray(tm.split_feature))
-            np.testing.assert_array_equal(np.asarray(tp.split_bin),
-                                          np.asarray(tm.split_bin))
-            np.testing.assert_array_equal(np.asarray(tp.default_left),
-                                          np.asarray(tm.default_left))
-            np.testing.assert_allclose(np.asarray(tp.leaf_value),
-                                       np.asarray(tm.leaf_value), rtol=1e-5,
-                                       atol=1e-7)
-        np.testing.assert_allclose(b_p.predict(X[:100]), b_m.predict(X[:100]),
-                                   rtol=1e-5)
+def test_split_counter_names_the_path_that_moves_rows():
+    from synapseml_tpu.gbdt.grower import split_counter
+
+    assert split_counter(GrowerConfig(), 28) == "splitsPartitionSort"
+    assert split_counter(GrowerConfig(growth_policy="depthwise"), 28) is None
 
 
-@pytest.mark.parametrize("layout", ["masked", "gather"])
-def test_row_layout_categorical(layout):
-    rng = np.random.default_rng(3)
-    n = 2000
-    cats = rng.integers(0, 10, size=n)
-    y = np.isin(cats, [2, 5, 7]).astype(np.float32)
-    X = np.stack([cats.astype(np.float32),
-                  rng.normal(size=n).astype(np.float32)], 1)
-    cfg = BoosterConfig(objective="binary", num_iterations=8,
-                        row_layout=layout)
-    bst = train_booster(X, y, cfg, categorical_features=[0])
-    p = bst.predict(X)
-    assert ((p > 0.5) == (y > 0.5)).mean() > 0.99
+def test_partition_bucket_off_the_chip_is_a_stable_partition():
+    """The argsort path against plain NumPy: inside the range left rows
+    keep their order before right rows, outside it nothing moves."""
+    from synapseml_tpu.gbdt.grower import _partition_bucket
+
+    rng = np.random.default_rng(4)
+    Np, FP, chunk, size = 1024, 8, 128, 256
+    start, length, fsel, thr = 300, 200, 3, 9
+    bT = rng.integers(0, 16, size=(FP, Np)).astype(np.int32)
+    pos = rng.permutation(Np).astype(np.int32)
+    g, h = (rng.normal(size=Np).astype(np.float32) for _ in range(2))
+    m = (rng.uniform(size=Np) > 0.3).astype(np.float32)
+    out = _partition_bucket(*map(jnp.asarray, (pos, g, h, m, bT)),
+                            jnp.int32(start), jnp.int32(length),
+                            jnp.int32(fsel), lambda binrow: binrow > thr,
+                            size, chunk, 256, False)
+    inside = np.arange(start, start + length)
+    right = bT[fsel, inside] > thr
+    order = np.arange(Np)
+    order[inside] = np.concatenate([inside[~right], inside[right]])
+    for got, want in zip(out[:4], (pos, g, h, m)):
+        np.testing.assert_array_equal(np.asarray(got), want[order])
+    np.testing.assert_array_equal(np.asarray(out[4]), bT[:, order])
+    assert int(out[5]) == int((~right).sum())
 
 
 def test_sparse_csr_input_matches_dense():

@@ -48,8 +48,10 @@ def _make_data(seed, n=400, f=5, nan_frac=0.0, n_cat=0, cat_card=8):
     return X, y, cats
 
 
-def _run_both(X, y, cats, seed, **over):
-    """(engine raw scores, oracle raw scores, engine model, oracle tree)."""
+def _run_both(X, y, cats, seed, mesh=None, **over):
+    """(engine raw scores, oracle raw scores, engine model, oracle tree).
+    With ``mesh`` the engine's rows are sharded over it (data-parallel
+    histograms, one psum a split); the oracle sees the whole table."""
     max_bin = over.pop("max_bin", 32)
     params = dict(num_leaves=over.pop("num_leaves", 8),
                   min_data_in_leaf=over.pop("min_data_in_leaf", 20),
@@ -73,7 +75,7 @@ def _run_both(X, y, cats, seed, **over):
                         max_bin=max_bin, **cat_params,
                         **{k: v for k, v in params.items()
                            if v is not None})
-    booster = train_booster(ds, None, cfg)
+    booster = train_booster(ds, None, cfg, mesh=mesh)
     raw_engine = np.asarray(booster.raw_score(X)).ravel()
 
     mapper = ds.mapper
@@ -221,6 +223,55 @@ class TestCategoricalTrees:
         assert any(l.split is not None and l.split.categorical
                    for l in _iter_nodes(tree.root)), \
             "no categorical split exercised"
+
+
+class TestTablesOfTheRetiredCrossLayoutTests:
+    """The tables the deleted row-layout tests compared three growers on,
+    now against the oracle: one grower is left, so its referee is the
+    plain loop and not a sibling."""
+
+    @pytest.mark.parametrize("extra", [
+        dict(num_leaves=15), dict(num_leaves=31, min_data_in_leaf=5)],
+        ids=["leaves_15", "leaves_31_min_data_5"])
+    def test_nan_column_at_255_bins(self, binary_data, extra):
+        X, _, y, _ = binary_data
+        X = np.array(X)
+        X[::7, 3] = np.nan                 # learned missing direction
+        raw_e, raw_o, booster, tree = _run_both(
+            X, y, [], 0, max_bin=255, min_gain_to_split=1e-3, **extra)
+        _assert_same_tree(raw_e, raw_o, booster, tree)
+        assert len(tree.leaves) >= 6
+
+    def test_ten_categories(self):
+        rng = np.random.default_rng(3)
+        n = 2000
+        cats = rng.integers(0, 10, size=n)
+        y = np.isin(cats, [2, 5, 7]).astype(np.float32)
+        X = np.stack([cats.astype(np.float32),
+                      rng.normal(size=n).astype(np.float32)], 1)
+        raw_e, raw_o, booster, tree = _run_both(X, y, [0], 3,
+                                                min_gain_to_split=0.05)
+        _assert_same_tree(raw_e, raw_o, booster, tree)
+        assert tree.root.split is not None and tree.root.split.categorical
+        # the first split separates the three categories that carry the label
+        p = 1.0 / (1.0 + np.exp(-raw_e))
+        assert ((p > 0.5) == (y > 0.5)).mean() > 0.99
+
+
+class TestMeshAgainstTheOracle:
+    """Rows sharded over the 8-device mesh: the psum of the shards'
+    histograms must lead to the tree the oracle grows on the whole table."""
+
+    @pytest.mark.parametrize("nan_frac", [0.0, 0.15], ids=["plain", "nan"])
+    def test_data_parallel_fit(self, eight_devices, nan_frac):
+        from synapseml_tpu.parallel.mesh import make_mesh
+
+        X, y, cats = _make_data(11, n=800, nan_frac=nan_frac)
+        raw_e, raw_o, booster, tree = _run_both(
+            X, y, cats, 11, mesh=make_mesh(devices=eight_devices),
+            min_gain_to_split=0.05)
+        _assert_same_tree(raw_e, raw_o, booster, tree)
+        assert len(tree.leaves) >= 4
 
 
 def _iter_nodes(node):

@@ -2,7 +2,7 @@
 
 Five property groups:
 
-* **Chunk geometry** — explicit > env > tuned resolution, the
+* **Chunk geometry** — explicit > env > probe resolution, the
   ``SYNAPSEML_TPU_STREAM_MEM_BUDGET`` cap, depth resolution.
 * **ChunkPump** — order/count preservation in both drive modes, producer
   thread joined on every exit path (including early break and source death),

@@ -116,8 +116,8 @@ def test_kernel_matches_argsort_and_five_gathers(case):
     route = _route(kind, B)
     args = (pos, g, h, m, bT, jnp.int32(start), jnp.int32(length),
             jnp.int32(0), route, size, CHUNK, B)
-    want = grower._partition_bucket(*args, "sort")
-    got = grower._partition_bucket(*args, "kernel")
+    want = grower._partition_bucket(*args, False)
+    got = grower._partition_bucket(*args, True)
     assert int(got[5]) == int(want[5])                  # nl_loc
     for name, a, b in zip(("pos", "gs", "hs", "ms", "bT"), got, want):
         assert a.dtype == b.dtype and a.shape == b.shape, name
@@ -138,8 +138,7 @@ def test_two_classes_order_as_the_four_way_key():
         key = np.where(idx < start, -1, np.where(idx >= start + length, 2,
                                                  gr.astype(np.int32)))
         second = (idx >= start + length) | ((idx >= start) & gr)
-        src4 = np.asarray(grower._stable_partition_src(jnp.asarray(key),
-                                                       "sort"))
+        src4 = np.asarray(jnp.argsort(jnp.asarray(key), stable=True))
         v = jnp.arange(S, dtype=jnp.int32)
         got = partition_window_xla(jnp.asarray(second), 0, v[None, :], v,
                                    v.astype(jnp.float32),
@@ -210,11 +209,36 @@ def _categorical_fixture():
     return X, y, [0]
 
 
-@pytest.mark.parametrize("fixture,bagged,max_bin", [
-    ("binary", False, 255), ("binary", True, 255), ("binary", False, 1023),
-    ("categorical", False, 255), ("categorical", True, 255)])
-def test_grow_tree_identical_through_the_kernel(monkeypatch, fixture, bagged,
-                                                max_bin):
+# fixture, bagged, max_bin, GrowerConfig overrides, extras (monotone
+# constraints, a node key for feature_fraction_bynode). The two paths that
+# are left: the kernel (the chip's) and argsort + five gathers (elsewhere).
+GROW_CASES = {
+    "binary": ("binary", False, 255, {}, {}),
+    "binary_bagged": ("binary", True, 255, {}, {}),
+    "binary_bins_1023": ("binary", False, 1023, {}, {}),
+    "categorical": ("categorical", False, 255, {}, {}),
+    "categorical_bagged": ("categorical", True, 255, {}, {}),
+    # what the cross-layout tests fed: the NaN column at the default
+    # min_data_in_leaf with 15 leaves, and 31 leaves of at least 5 rows
+    "nan_15_leaves": ("binary", False, 255, dict(min_data_in_leaf=20), {}),
+    "nan_15_leaves_bagged": ("binary", True, 255,
+                             dict(min_data_in_leaf=20), {}),
+    "nan_31_leaves": ("binary", False, 255, dict(num_leaves=31), {}),
+    "nan_31_leaves_bagged": ("binary", True, 255, dict(num_leaves=31), {}),
+    "bynode_feature_fraction": ("binary", False, 255,
+                                dict(feature_fraction_bynode=0.5),
+                                dict(node_key=7)),
+    "monotone": ("binary", False, 255, {}, dict(monotone=True)),
+    "max_depth_3": ("binary", False, 255, dict(max_depth=3),
+                    dict(min_splits=4)),
+    "two_leaves": ("binary", False, 255, dict(num_leaves=2),
+                   dict(min_splits=1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROW_CASES))
+def test_grow_tree_identical_through_the_kernel(monkeypatch, case):
+    fixture, bagged, max_bin, over, extra = GROW_CASES[case]
     monkeypatch.setenv("SYNAPSEML_TPU_HIST_CHUNK", "128")
     X, y, cat = (_binary_fixture if fixture == "binary"
                  else _categorical_fixture)()
@@ -228,17 +252,21 @@ def test_grow_tree_identical_through_the_kernel(monkeypatch, fixture, bagged,
     bag = jnp.asarray((rng.uniform(size=n) > 0.3) if bagged
                       else np.ones(n), jnp.float32)
     is_cat = jnp.zeros(f, bool).at[jnp.asarray(cat, jnp.int32)].set(True)
-    cfg = GrowerConfig(num_leaves=15, num_bins=max_bin, min_data_in_leaf=5,
-                       has_categorical=bool(cat))
+    cfg = GrowerConfig(**{**dict(num_leaves=15, num_bins=max_bin,
+                                 min_data_in_leaf=5,
+                                 has_categorical=bool(cat)), **over})
     nan_bins = jnp.asarray(mapper.nan_bins, jnp.int32)
+    mono = jnp.asarray(rng.integers(-1, 2, size=f) if extra.get("monotone")
+                       else np.zeros(f), jnp.int32)
+    node_key = (jax.random.key_data(jax.random.PRNGKey(extra["node_key"]))
+                if "node_key" in extra else None)
 
     def grow():
         # the choice is made while tracing, and grow_tree's own jit would
         # answer the second call from the first's trace
         return jax.jit(lambda b, g_, h_, m_: grow_tree.__wrapped__(
-            b, g_, h_, m_, jnp.ones(f, bool), is_cat,
-            jnp.zeros(f, jnp.int32), cfg, nan_bins=nan_bins))(
-                binned, g, h, bag)
+            b, g_, h_, m_, jnp.ones(f, bool), is_cat, mono, cfg,
+            nan_bins=nan_bins, node_key=node_key))(binned, g, h, bag)
 
     tree_x, node_x = grow()
     taken = []
@@ -249,7 +277,7 @@ def test_grow_tree_identical_through_the_kernel(monkeypatch, fixture, bagged,
 
     monkeypatch.setattr(grower, "partition_kernel_available", forced)
     tree_k, node_k = grow()
-    assert taken and int(tree_x.num_splits) >= 8
+    assert taken and int(tree_x.num_splits) >= extra.get("min_splits", 8)
     for field in tree_x._fields:
         # bool and uint32 fields compare as int32
         got, want = (_bits(getattr(t, field).astype(jnp.int32)
@@ -272,24 +300,9 @@ def test_fit_counts_its_splits_under_the_path_that_moved_the_rows():
     X = rng.normal(size=(1500, 6)).astype(np.float32)
     y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
     m = InstrumentationMeasures()
-    bst = train_booster(X, y, BoosterConfig(num_iterations=3, seed=7,
-                                            row_layout="partition"),
+    bst = train_booster(X, y, BoosterConfig(num_iterations=3, seed=7),
                         measures=m)
     counted = {k: v for k, v in m.report().items()
                if k.startswith("count:splitsPartition")}
     assert counted == {"count:splitsPartitionSort":
                        sum(int(t.num_splits) for t in bst.trees)}
-
-
-@pytest.mark.parametrize("layout", ["gather", "masked"])
-def test_layouts_that_move_no_rows_count_no_partition(layout):
-    from synapseml_tpu.core.logging import InstrumentationMeasures
-    from synapseml_tpu.gbdt import BoosterConfig, train_booster
-
-    rng = np.random.default_rng(5)
-    X = rng.normal(size=(600, 4)).astype(np.float32)
-    y = (X[:, 0] > 0).astype(np.float32)
-    m = InstrumentationMeasures()
-    train_booster(X, y, BoosterConfig(num_iterations=2, seed=7,
-                                      row_layout=layout), measures=m)
-    assert not [k for k in m.report() if k.startswith("count:splitsPartition")]

@@ -109,15 +109,17 @@ def test_backfill_is_idempotent(tmp_path, journal):
         {"metric": "unrelated_metric", "value": 1.0},
     ]))
     added = perfmodel.backfill_training_rows(str(legacy), str(journal))
-    assert added == 4   # 2 kernel variants + voting + data
+    # voting + data; the retired kernel-variant sweep's record feeds no
+    # picker and is passed over
+    assert added == 2
     rows = perfmodel.training_rows(path=str(journal))
-    assert {r["kind"] for r in rows} == {"gbdt_kernel", "gbdt_tree_learner"}
+    assert {r["kind"] for r in rows} == {"gbdt_tree_learner"}
     tl = {r["arm"]: r for r in rows if r["kind"] == "gbdt_tree_learner"}
     assert tl["voting"]["observed_s"] == pytest.approx(1 / 3856)
     assert tl["data"]["features"] == {"workers": 8.0, "nfeat": 2000.0}
     # second run appends nothing (backfilled_from dedup)
     assert perfmodel.backfill_training_rows(str(legacy), str(journal)) == 0
-    assert len(perfmodel.training_rows(path=str(journal))) == 4
+    assert len(perfmodel.training_rows(path=str(journal))) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -344,13 +346,8 @@ def test_suggest_sketch_second_pass_budget_rule(journal, monkeypatch):
     assert take is False and dec.source == "disabled"
 
 
-def test_suggest_kernel_variant_fallback(journal):
-    cfg, dec = perfmodel.suggest_kernel_variant(platform="cpu")
-    assert cfg is None and dec.used_fallback   # no sweep rows recorded
-
-
 # ---------------------------------------------------------------------------
-# call-site integration (the seven pickers keep bypass + provenance)
+# call-site integration (the pickers keep bypass + provenance)
 # ---------------------------------------------------------------------------
 
 def test_partition_stages_cost_balanced_cuts():

@@ -81,24 +81,6 @@ def test_bucketed_runner_donates_on_chip(tpu):
     assert after["total_compiles"] == after["warmup_compiles"]
 
 
-def test_grower_layouts_agree_on_chip(tpu):
-    from synapseml_tpu.gbdt import BoosterConfig, train_booster
-
-    rng = np.random.default_rng(2)
-    X = rng.normal(size=(10_000, 8)).astype(np.float32)
-    y = (X[:, 0] > 0).astype(np.float32)
-    b_p = train_booster(X, y, BoosterConfig(objective="binary",
-                                            num_iterations=4))
-    b_m = train_booster(X, y, BoosterConfig(objective="binary",
-                                            num_iterations=4,
-                                            row_layout="masked"))
-    np.testing.assert_array_equal(
-        np.asarray(b_p.trees[0].split_feature),
-        np.asarray(b_m.trees[0].split_feature))
-    np.testing.assert_allclose(b_p.predict(X[:500]), b_m.predict(X[:500]),
-                               rtol=1e-5)
-
-
 def test_onnx_bf16_on_chip(tpu):
     import jax
 
@@ -199,17 +181,35 @@ def test_segmented_kernel_on_chip(tpu):
     np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
 
 
-def test_grower_segmented_matches_sliced_on_chip(tpu):
-    """use_segmented=True and False must grow identical trees on hardware."""
+def test_grower_segmented_matches_sliced_on_chip(tpu, monkeypatch):
+    """The segmented histogram kernel and the sliced path
+    (SYNAPSEML_TPU_SEGMENTED=0) must grow identical trees on hardware."""
+    import jax
+
     from synapseml_tpu.gbdt import BoosterConfig, train_booster
+    from synapseml_tpu.gbdt import boosting, grower
 
     rng = np.random.default_rng(2)
     X = rng.normal(size=(20000, 12)).astype(np.float32)
     y = (X[:, 0] * X[:, 1] > 0).astype(np.float32)
-    b_seg = train_booster(X, y, BoosterConfig(
-        objective="binary", num_iterations=3, use_segmented=True))
-    b_sli = train_booster(X, y, BoosterConfig(
-        objective="binary", num_iterations=3, use_segmented=False))
+    cfg = dict(objective="binary", num_iterations=3)
+
+    def fresh_trace():
+        # the env is read at trace time, and grow_tree's own jit would
+        # answer the second fit from the first's trace
+        boosting._FUSED_RUNNERS.clear()
+        jax.clear_caches()
+
+    def no_segments(*a, **k):
+        raise AssertionError("the sliced path took the segmented kernel")
+
+    fresh_trace()
+    b_seg = train_booster(X, y, BoosterConfig(**cfg))
+    monkeypatch.setenv("SYNAPSEML_TPU_SEGMENTED", "0")
+    monkeypatch.setattr(grower, "range_histogram", no_segments)
+    fresh_trace()
+    b_sli = train_booster(X, y, BoosterConfig(**cfg))
+    fresh_trace()
     for ts, tl in zip(b_seg.trees, b_sli.trees):
         np.testing.assert_array_equal(np.asarray(ts.split_feature),
                                       np.asarray(tl.split_feature))
@@ -274,8 +274,8 @@ def test_partition_kernel_check_passes_on_wide_tables(tpu, bins, fp):
 
 
 def test_fit_counts_kernel_splits_on_chip(tpu):
-    """On the chip every split of the partition layout goes through the
-    kernel, and the fit's record says so."""
+    """On the chip every leaf-wise split goes through the partition kernel,
+    and the fit's record says so."""
     from synapseml_tpu.core.logging import InstrumentationMeasures
     from synapseml_tpu.gbdt import BoosterConfig, train_booster
 
@@ -284,8 +284,7 @@ def test_fit_counts_kernel_splits_on_chip(tpu):
     y = (X[:, 0] * X[:, 1] > 0).astype(np.float32)
     m = InstrumentationMeasures()
     bst = train_booster(X, y, BoosterConfig(objective="binary",
-                                            num_iterations=3,
-                                            row_layout="partition"),
+                                            num_iterations=3),
                         measures=m)
     counted = {k: v for k, v in m.report().items()
                if k.startswith("count:splitsPartition")}
@@ -328,27 +327,6 @@ def test_depthwise_growth_on_chip(tpu, monkeypatch):
     p_k, p_s = b_k.predict(X[:2000]), b_s.predict(X[:2000])
     assert ((p_k > 0.5) == (y[:2000] > 0.5)).mean() > 0.8
     np.testing.assert_allclose(p_k, p_s, atol=5e-3)
-
-
-def test_tuned_defaults_flip_visible_on_chip(tpu):
-    """The tune->flip->bench loop's read side on real hardware: when
-    docs/tuned_defaults.json exists, BoosterConfig() must reflect it under
-    the TPU backend (core/tuned.py gates on the initialized platform)."""
-    import json
-
-    from synapseml_tpu.core import tuned
-    from synapseml_tpu.gbdt import BoosterConfig
-
-    vals = tuned.tuned_engine_defaults()
-    cfg = BoosterConfig()
-    print(f"\nTUNED DEFAULTS in effect: {json.dumps(vals)} -> "
-          f"partition_impl={cfg.partition_impl} row_layout={cfg.row_layout} "
-          f"use_segmented={cfg.use_segmented}", flush=True)
-    for key, env in (("partition_impl", "SYNAPSEML_TPU_PARTITION_IMPL"),
-                     ("row_layout", "SYNAPSEML_TPU_ROW_LAYOUT")):
-        if key in vals and not os.environ.get(env):
-            # env overrides the file by design; assert only the file path
-            assert getattr(cfg, key) == vals[key]
 
 
 def _highest(fn, *args, **kw):

@@ -5,9 +5,8 @@ TensorBoard.
 usual consumer (tensorboard-plugin-profile) is not in this image, so this
 parses the wire format directly — the same self-contained approach as the
 repo's ONNX reader (synapseml_tpu/onnx/protoio.py) — and aggregates XLA op
-durations by name/category. This is the tool that localizes the GBDT
-hot-loop cost on-chip (docs/perf_notes.md round-3: ~250 ms/tree unexplained
-by the kernel+sort model).
+durations by name/category. This is the tool that localized the GBDT
+hot-loop cost on-chip (docs/trace_summary_gbdt.md).
 
 Usage:
   python tools/trace_summary.py /tmp/jaxtrace [--top 30] [--by op|category]

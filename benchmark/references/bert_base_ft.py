@@ -386,7 +386,9 @@ class Reference:
                 first_loss_gap=float(losses[0]),
                 grad_gap_median_leaf=float(np.median(list(grad.values()))),
                 change_gap_median_leaf=float(np.median(list(change.values()))),
-                change_difference=_difference(c_prog, c_ref, moved))
+                change_difference=_difference(c_prog, c_ref, moved),
+                where={"loss_gap": f"step {int(np.argmax(losses))}",
+                       "grad_gap": grad_at, "change_gap": change_at})
         return out
 
 

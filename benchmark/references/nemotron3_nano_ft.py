@@ -8,37 +8,63 @@ of the rows and the initial parameters itself, by the rules the configuration
 states, follows the first ``judged_steps`` optimizer steps from there, and
 compares what the timed fit's hook kept of the same steps:
 
-``loss_gap``        worst of the judged steps: \\|program's loss - reference's\\|
-                    / reference's.
+``first_loss_gap``  the first judged step's loss: \\|program's - reference's\\|
+                    / reference's. Not the worst step's: the first update
+                    moves the loss of four rows anywhere from 0.04 to 6, and
+                    a gap relative to a loss near nought is large with
+                    nothing wrong (0.14 on seed 134403365 at a loss of
+                    0.037, 0.26 on another seed), while the first step reads
+                    at most 0.0015 in a sound run and a batch half left out
+                    or PAD taken for words 0.04 or more. A fault of the
+                    update shows in ``change_gap`` and ``step_count_gap``
+                    (PERF.md section 4).
 ``grad_gap``        the first gradient as the optimizer got it (the first
-                    moment after step 0 over ``1 - b1``): the worst leaf's
-                    \\| ||program's|| - ||reference's|| \\| over the larger of
-                    the reference's norm of that leaf and of the median
-                    leaf's, as the dense configuration's is, over every leaf
-                    but the routed experts' (``experts_up``,
-                    ``experts_down``) of the expert layers after the first.
-                    Those six hang on choices that the program may rightly
+                    moment after step 0 over ``1 - b1``): the **median
+                    leaf's** \\| ||program's|| - ||reference's|| \\| over the
+                    larger of the reference's norm of that leaf and of the
+                    median leaf's. Not the worst leaf's: the step's gradient
+                    is the mean of four rows of two classes, which cancel,
+                    and most in the leaves next to the head, while a row's
+                    rounding does not cancel. On seed 134403365 the mean's
+                    norm is 0.16 of the rows' and the last expert layer's
+                    ``shared_down`` read 0.045, the head 0.035, with nothing
+                    wrong; ``state_reset`` read 0.045 at its worst leaf there
+                    too. The median leaf reads
+                    at most 0.0043 in a sound run, the control 0.035 and
+                    0.065 (PERF.md section 4).
+``routed_gap``      the same gradient's routed experts (``experts_up``,
+                    ``experts_down`` of every expert layer), the eight leaves
+                    as one: the gap of their norms over the reference's.
+                    Their leaves hang on choices that the program may rightly
                     make otherwise: the router's scores are float32 on both
                     sides, but bfloat16 rounding upstream of it moves the
-                    six chosen at 4 % of positions in the first expert
-                    layer and 10 % in the fourth, and a held expert's tokens
-                    with them, so a sound run reads 0.001 to 0.031 there by
-                    the seed and the leaf, where ``topk_altered`` reads 0.01
-                    to 0.05. The first expert layer has one mixer's rounding
-                    above it: a sound run reads 0.002 to 0.011 at its
-                    experts and ``topk_altered`` 0.045 to 0.073 (PERF.md
-                    section 4).
-                    ``grad_difference`` holds the six with the rest.
+                    six chosen at 4 % of positions in the first expert layer
+                    and 10 % in the fourth, so a leaf alone reads up to 0.04
+                    in a sound run; a flip mostly moves a token between
+                    experts, which the sum hardly sees (at most 0.013), while
+                    five chosen for six (``topk_altered``) reads 0.047 and
+                    0.091 and a dropped scaling 0.59.
 ``grad_difference`` the same gradient, all leaves together: ||program's -
                     reference's|| over the root of the rows' squared gradient
                     norms over the rows, not over the norm of their mean. A
                     row's rounding does not know of the other rows: the
                     program's gradient lies 0.08 to 0.12 from the
                     reference's on every seed read, while the mean's norm
-                    runs from 3.1 to 16.4 with the labels of the four rows
+                    runs from 2.0 to 17.8 with the labels of the four rows
                     (two of each class cancel; four of one do not) and a
                     sound run read 0.005 to 0.037 over it. The rows' own
-                    norms, 16 to 37, cancel nothing (PERF.md section 4).
+                    norms cancel nothing (PERF.md section 4).
+``leaf_difference`` the same gradient, a leaf at a time: the worst leaf's
+                    ||program's - reference's|| over the root of that leaf's
+                    own rows' squared gradient norms. The leaves whose
+                    gradient hangs on which experts were chosen are left out:
+                    the routed experts' (``routed_gap`` takes them) and the
+                    routers', whose gradient a flip of the chosen moves by
+                    0.15 to 0.25 of its rows' in a sound run. It sees a fault
+                    confined to a few leaves, which the median leaf and the
+                    sum over all leaves do not: ``state_reset`` reads 0.35
+                    and 0.42 where sound runs read at most 0.051, most often
+                    at a Mamba-2 layer's ``dt_bias`` (PERF.md section 4).
 ``change_gap``      the parameters' change over the judged steps: the **median
                     leaf's** gap by the same measure; leaves whose gradient is
                     nought to rounding in the reference (under a thousandth of
@@ -461,27 +487,28 @@ class Reference:
         return self._grad[value_type]
 
     def loss_and_grad(self, params, step, value_type, how, rows):
-        """The step's loss and gradient, and the root of the blocks' squared
-        gradient norms over the rows: what of the rows' gradients does not
-        cancel between them."""
+        """The step's loss and gradient, and, leaf by leaf, the root of the
+        blocks' squared gradient norms over the rows: what of the rows'
+        gradients does not cancel between them."""
         fn = self._block_grad(value_type)
-        total, grads, apart = 0.0, None, 0.0
+        total, grads, apart = 0.0, None, None
         for lo in range(0, rows, self.block):
             hi = min(lo + self.block, rows)
             part, g = fn(params, jnp.asarray(self.ids[step][lo:hi]),
                          jnp.asarray(self.y[step][lo:hi]), how)
             total = total + part
-            apart = apart + sum(jnp.sum(jnp.square(v))
-                                for v in jax.tree.leaves(g))
+            sq = {k: jnp.sum(jnp.square(v)) for k, v in flatten(g).items()}
+            apart = sq if apart is None else {k: apart[k] + sq[k] for k in sq}
             grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
         return (total / rows, jax.tree.map(lambda g: g / rows, grads),
-                math.sqrt(float(apart)) / rows)
+                {k: math.sqrt(float(v)) / rows for k, v in apart.items()})
 
     def follow(self, how: dict = None) -> dict:
         """The judged steps from the seed: {"losses", "first_gradient",
         "change" (the parameters after the last step less the initial ones),
-        "grad_norms" (of every step, by leaf), "rows_norm" (of the first
-        step: ``loss_and_grad``'s third)}, the trees flat and on the host;
+        "grad_norms" (of every step, by leaf), "rows_norms" (of the first
+        step, by leaf: ``loss_and_grad``'s third)}, the trees flat and on the
+        host;
         ``how`` plants the control or a fault."""
         # the reference itself at ``highest``; a stand-in as the program
         matmul = "highest" if how is None else "default"
@@ -499,14 +526,14 @@ class Reference:
             mu = {k: np.zeros_like(v) for k, v in self._initial.items()}
             nu = {k: np.zeros_like(v) for k, v in self._initial.items()}
             for step in range(self.steps):
-                loss, g, rows_norm = self.loss_and_grad(
+                loss, g, rows_norms = self.loss_and_grad(
                     params, step, value_type, how, rows)
                 out["losses"].append(float(loss))
                 g = flatten(g)
                 out["grad_norms"].append(
                     {k: float(jnp.linalg.norm(v.ravel())) for k, v in g.items()})
                 if step == 0:
-                    out["rows_norm"] = rows_norm
+                    out["rows_norms"] = rows_norms
                     out["first_gradient"] = {k: np.asarray(v)
                                              for k, v in g.items()}
                 if state == "unchanged":
@@ -560,43 +587,70 @@ class Reference:
                 return judged["change"][k]
             return judged["params"][k] - self._initial[k]
 
-        grad = _compare(lambda k: judged["mu"][k] * scale, g_ref, list(g_ref),
-                        ref["rows_norm"])
-        change = _compare(change_of, c_ref, moved)
-        change_at = max(change["gaps"], key=change["gaps"].get)
-        aside = _later_experts(self.cfg)
-        held = {k: v for k, v in grad["gaps"].items() if k not in aside}
-        grad_at = max(held, key=held.get)
+        grad = _leaf_norms(lambda k: judged["mu"][k] * scale, g_ref,
+                           list(g_ref))
+        change = _leaf_norms(change_of, c_ref, moved)
         print(f"reference: losses {ref['losses']}; judged {judged['losses']}; "
-              f"worst leaves {grad_at} {held[grad_at]:.4g} (gradient; set "
-              f"aside: {[round(grad['gaps'][k], 4) for k in aside]}), "
-              f"{change_at} {change['gaps'][change_at]:.4g} (change); "
-              f"{len(g_ref) - len(moved)} leaf(s) "
-              f"of {len(g_ref)} left out of the change: "
-              f"{sorted(set(g_ref) - set(moved))[:4]}", file=sys.stderr)
-        out = {"loss_gap": float(max(losses)),
-               "grad_gap": held[grad_at],
-               "grad_difference": grad["difference"],
-               "change_gap": float(np.median(list(change["gaps"].values())))}
-        if candidates:
-            out.update(
-                first_loss_gap=float(losses[0]),
-                grad_gap_every_leaf=max(grad["gaps"].values()),
-                gradient_norm=_norm(list(ref["grad_norms"][0].values())),
-                rows_norm=ref["rows_norm"],
-                change_gap_worst_leaf=change["gaps"][change_at],
-                change_difference=change["difference"],
-                leaf_gaps={"grad_gap": grad["gaps"],
-                           "change_gap": change["gaps"]})
-        return out
+              f"{len(g_ref) - len(moved)} leaf(s) of {len(g_ref)} left out of "
+              f"the change: {sorted(set(g_ref) - set(moved))[:4]}",
+              file=sys.stderr)
+        return judge(self.cfg, losses, grad, change, ref["rows_norms"],
+                     candidates)
 
 
-def _later_experts(cfg: dict) -> list:
-    """The routed experts' leaves of every expert layer after the first:
-    what ``grad_gap`` sets aside."""
+def judge(cfg: dict, loss_gaps, grad: dict, change: dict, rows_norms: dict,
+          candidates: bool = False) -> dict:
+    """The compared numbers from what was read: the judged steps' loss gaps,
+    {leaf: [program's norm, reference's norm, norm of the difference]} of
+    the first gradient (``grad``) and of the change (``change``, the leaves
+    that move), and {leaf: the root of the rows' squared norms of its first
+    gradient} (``rows_norms``, the reference's). Data in, numbers out: a
+    recorded reading is judged again by the tests as a run judges it."""
+    routed = routed_leaves(cfg)
+    grad_gaps, change_gaps = _gaps(grad), _gaps(change)
+    rows_norm = _root_sum_square(rows_norms.values())
+    chosen = set(routed) | {k for k in grad if k.endswith("/router")}
+    own = {k: d / rows_norms[k] for k, (_, _, d) in grad.items()
+           if k not in chosen}
+    own_at = max(own, key=own.get)
+    out = {"first_loss_gap": float(loss_gaps[0]),
+           "grad_gap": float(np.median(list(grad_gaps.values()))),
+           "routed_gap": _gap_of_sums(grad, routed),
+           "grad_difference": _root_sum_square(
+               d for _, _, d in grad.values()) / rows_norm,
+           "leaf_difference": own[own_at],
+           "change_gap": float(np.median(list(change_gaps.values())))}
+    worst = max(grad_gaps, key=grad_gaps.get)
+    print(f"judged: worst gradient leaf {worst} {grad_gaps[worst]:.4g}; "
+          f"routed leaves { {k: round(grad_gaps[k], 4) for k in routed} }",
+          file=sys.stderr)
+    if candidates:
+        change_at = max(change_gaps, key=change_gaps.get)
+        out.update(
+            loss_gaps=[float(g) for g in loss_gaps],
+            loss_gap_worst_step=float(max(loss_gaps)),
+            grad_gap_worst_leaf=grad_gaps[worst],
+            gradient_norm=_root_sum_square(w for _, w, _ in grad.values()),
+            rows_norm=rows_norm,
+            change_gap_worst_leaf=change_gaps[change_at],
+            leaf_norms={"grad": grad, "change": change},
+            rows_norms=rows_norms,
+            where={"loss_gap_worst_step": f"step {int(np.argmax(loss_gaps))}",
+                   "grad_gap": f"median of {len(grad)} leaves",
+                   "routed_gap": f"{len(routed)} routed leaves",
+                   "change_gap": f"median of {len(change)} leaves",
+                   "leaf_difference": own_at,
+                   "grad_gap_worst_leaf": worst,
+                   "change_gap_worst_leaf": change_at})
+    return out
+
+
+def routed_leaves(cfg: dict) -> list:
+    """The routed experts' leaves of every expert layer: what ``routed_gap``
+    takes together."""
     layers = [i for i, kind in enumerate(cfg["hybrid_override_pattern"])
               if kind == "E"]
-    return [f"layer_{i}/{name}" for i in layers[1:]
+    return [f"layer_{i}/{name}" for i in layers
             for name in ("experts_up", "experts_down")]
 
 
@@ -615,22 +669,32 @@ def _norm(x) -> float:
     return math.sqrt(float(np.sum(np.square(np.ravel(x), dtype=np.float64))))
 
 
-def _compare(got_of, want: dict, leaves, over: float = None) -> dict:
-    """A leaf at a time (a tree is 2.5 GB): ``gaps`` {leaf: | ||got|| -
-    ||want|| | / max(||want||, the median leaf's)} and ``difference``
-    ||got - want|| over all the leaves together, over ``over`` (||want||
-    if None)."""
-    norms, got_norms, apart = {}, {}, 0.0
+def _root_sum_square(values) -> float:
+    return math.sqrt(sum(v * v for v in values))
+
+
+def _leaf_norms(got_of, want: dict, leaves) -> dict:
+    """A leaf at a time (a tree is 2.5 GB): {leaf: [||got||, ||want||,
+    ||got - want||]}."""
+    out = {}
     for k in leaves:
         got = got_of(k)
-        norms[k], got_norms[k] = _norm(want[k]), _norm(got)
-        apart += _norm(got - want[k]) ** 2
-    median = float(np.median(list(norms.values())))
-    if over is None:
-        over = math.sqrt(sum(v ** 2 for v in norms.values()))
-    return {"gaps": {k: abs(got_norms[k] - norms[k]) / max(norms[k], median)
-                     for k in leaves},
-            "difference": math.sqrt(apart) / over}
+        out[k] = [_norm(got), _norm(want[k]), _norm(got - want[k])]
+    return out
+
+
+def _gaps(norms: dict) -> dict:
+    """{leaf: | ||got|| - ||want|| | / max(||want||, the median leaf's)}."""
+    median = float(np.median([w for _, w, _ in norms.values()]))
+    return {k: abs(g - w) / max(w, median) for k, (g, w, _) in norms.items()}
+
+
+def _gap_of_sums(norms: dict, leaves) -> float:
+    """The gap of the norms of ``leaves`` taken together: | ||got|| -
+    ||want|| | / ||want|| over the leaves as one vector."""
+    got = _root_sum_square(norms[k][0] for k in leaves)
+    want = _root_sum_square(norms[k][1] for k in leaves)
+    return abs(got - want) / want
 
 
 def stand_in_plans(config: dict) -> dict:

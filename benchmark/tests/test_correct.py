@@ -165,9 +165,11 @@ def test_sound_trainer_run_is_correct(capsys, workload):
     assert rc == harness.REHEARSAL_EXIT
     assert line["correct"] is True and line["rehearsal"] is True
     assert list(line)[-1] == "compared"
-    assert set(line["compared"]) == {"loss_gap", "grad_gap", "grad_difference",
-                                     "change_gap", "step_count_gap",
-                                     "window_compiles"}
+    limits = harness.load_cell(workload, True)[2]["limits"]
+    assert set(line["compared"]) == set(limits) | {"window_compiles"}
+    assert {"grad_gap", "grad_difference", "change_gap",
+            "step_count_gap"} <= set(limits)
+    assert {"loss_gap", "first_loss_gap"} & set(limits)
     for name, c in line["compared"].items():
         assert c["value"] <= c["limit"], name
         assert f"compared {name}:" in out.err

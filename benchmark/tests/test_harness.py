@@ -30,9 +30,11 @@ def test_every_cell_finds_its_files():
         assert hasattr(ref, "check")
         # what each entry's reference compares, beside the exact numbers
         compared = {"booster_fit": {"gain_gap", "leaf_gap", "loss_gap"},
-                    "trainer_fit": {"loss_gap", "grad_gap", "grad_difference",
+                    "trainer_fit": {"grad_gap", "grad_difference",
                                     "change_gap", "step_count_gap"}}
         assert set(cfg["limits"]) >= compared[traffic["entry"]]
+        # a loss: the worst judged step's, or the first step's
+        assert any(k.endswith("loss_gap") for k in cfg["limits"])
         assert all(v >= 0 for v in cfg["limits"].values())
         assert os.path.exists(os.path.join(harness.HERE, "data",
                                            cfg["rehearsal_trace"]))
@@ -64,6 +66,30 @@ def test_names_units_and_bounds():
     for c in BENCH["configs"]:
         held = harness._load_json(harness.ROOT, c["file"])
         assert set(c["reduced"]) == set(held["reduced"])
+
+
+@pytest.mark.parametrize("values, far", [
+    ([5.796, 5.807, 5.808, 5.793, 5.692, 5.789], 4),
+    ([1.0, 1.1, 1.2, 1.3, 5.0], 4),
+])
+def test_rate_spread_is_the_checks_quartile_spread(values, far):
+    """``benchmark.tools.rates`` reads a spread as the check does: the
+    quartile distance of ``statistics.quantiles(n=4)`` over the median, and
+    again with the run farthest from the median left out."""
+    import statistics
+
+    from benchmark.tools import rates
+
+    def by_hand(v):
+        q1, _, q3 = statistics.quantiles(v, n=4)
+        return (q3 - q1) / statistics.median(v)
+
+    out = rates.spreads(values)
+    assert out["median"] == statistics.median(values)
+    assert out["spread"] == pytest.approx(by_hand(values))
+    rest = values[:far] + values[far + 1:]
+    assert out["spread_without_farthest"] == pytest.approx(by_hand(rest))
+    assert out["spread_without_farthest"] < out["spread"]
 
 
 def test_no_cell_is_named_in_the_harness_code():

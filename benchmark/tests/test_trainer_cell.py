@@ -254,8 +254,42 @@ def test_every_trainer_metric_is_read_from_the_recorded_trace(capsys,
     want = {m["name"] for m in BENCH["per_layer"]
             if harness._applies(m, workload, {"setup_s",
                                               "train_samples_per_s_chip"})}
-    assert {"train_step_mfu", "trainer_step_ms_p50",
-            "trainer_data_wait_share", "trainer_dispatch_share",
-            "device_idle_share.trainer", "compile_s"} <= want
+    assert {"trainer_step_ms_p50", "trainer_data_wait_share",
+            "trainer_dispatch_share", "device_idle_share.trainer",
+            "compile_s"} <= want
+    # the dense encoder's count is read only where its entry lists the cell
+    listed = {m["name"]: m for m in BENCH["per_layer"]}["train_step_mfu"]
+    assert ("train_step_mfu" in want) == (workload in listed["workloads"])
     assert set(line["metrics"]) == want
     assert 0 <= line["metrics"]["device_idle_share.trainer"]["value"] < 100
+
+
+# -- bert_base_fit's limits against what the chip read ------------------------
+
+# the compared numbers of the program on every seed of the sweep, and of the
+# control and the planted faults on the seeds they were read on (PERF.md
+# section 4)
+BERT_READ = harness._load_json(harness.HERE, "data",
+                               "readings_bert_base_fit.json")["seeds"]
+# each stand-in, and the number that is there to catch it
+BERT_CAUGHT_BY = {"control": "grad_difference", "half_batch": "change_gap",
+                  "mask_dropped": "grad_gap", "moment_stale": "change_gap",
+                  "state_unchanged": "change_gap"}
+
+
+@pytest.mark.parametrize("seed", sorted(BERT_READ, key=int))
+def test_every_recorded_bert_sound_run_lies_well_under_every_limit(seed):
+    limits = _cell("bert_base_fit", False)[2]["limits"]
+    numbers = BERT_READ[seed]["program"]
+    assert set(numbers) == set(limits)
+    over = {k: v for k, v in numbers.items() if v > limits[k] / 1.5}
+    assert not over, over
+
+
+@pytest.mark.parametrize("seed, who", [
+    (int(seed), who) for seed in sorted(BERT_READ, key=int)
+    for who in sorted(BERT_READ[seed]) if who != "program"])
+def test_recorded_bert_stand_in_is_caught_by_its_number(seed, who):
+    limits = _cell("bert_base_fit", False)[2]["limits"]
+    caught = BERT_CAUGHT_BY[who]
+    assert BERT_READ[str(seed)][who][caught] >= 1.2 * limits[caught]

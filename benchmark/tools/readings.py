@@ -62,6 +62,33 @@ def stand_ins(ref_mod, config: dict, traffic: dict, inputs: dict,
     return out
 
 
+def nearest(numbers: dict, limits: dict) -> tuple:
+    """(name, value, limit, value over limit, leaf) of the compared number
+    nearest its limit, or farthest past it; an exact number (limit 0) that
+    reads more than 0 is past it by infinity. ``leaf`` is where the number
+    was read (``numbers["where"]``, a reference's candidate), or None."""
+    def ratio(k):
+        if limits[k] > 0:
+            return numbers[k] / limits[k]
+        return float("inf") if numbers[k] > 0 else 0.0
+
+    name = max((k for k in limits if k in numbers), key=ratio)
+    return (name, numbers[name], limits[name], ratio(name),
+            (numbers.get("where") or {}).get(name))
+
+
+def summary(row: dict, limits: dict) -> str:
+    """One line a seed: for the program and each stand-in, the compared
+    number nearest its limit, its leaf and the ratio."""
+    parts = []
+    for who, numbers in row.items():
+        if isinstance(numbers, dict):
+            name, value, limit, ratio, leaf = nearest(numbers, limits)
+            parts.append(f"{who} {name} {value:.4g} at {leaf or '-'} "
+                         f"= {ratio:.3g} x {limit:g}")
+    return f"seed {row['seed']}: " + "; ".join(parts)
+
+
 def write(path, ref_mod, config, traffic, inputs, seed, program) -> None:
     row = {"seed": seed, "program": program,
            **stand_ins(ref_mod, config, traffic, inputs)}
@@ -102,7 +129,7 @@ def main(argv=None) -> int:
         row.setdefault("program", ref_mod.check(config, inputs))
         with open(args.out, "a") as f:
             f.write(json.dumps(row) + "\n")
-        print(json.dumps(row), flush=True)
+        print(summary(row, config["limits"]), flush=True)
     return 0
 
 

@@ -4,7 +4,7 @@
 
 #' DeepTextClassifier
 #' Base of every stage. Subclasses are constructible from kwargs alone plus
-#' @param architecture A layer-type-list backbone in place of the dense encoder, by the source's own config.json keys: hybrid_override_pattern (one of M Mamba-2, E experts, * causal attention for each of numLayers), mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size, conv_kernel, chunk_size, num_key_value_heads, head_dim, n_routed_experts (the router's width), num_experts_per_tok, moe_intermediate_size, moe_shared_expert_intermediate_size, routed_scaling_factor, norm_eps; held_experts lists the expert ids this chip holds (dl/hybrid.py). hiddenSize, numLayers, numHeads, vocabSize and maxTokenLen apply as they do to the encoder
+#' @param architecture A layer-type-list backbone in place of the dense encoder, by the source's own config.json keys. nemotron_h: hybrid_override_pattern (one of M Mamba-2, E experts, * causal attention for each of numLayers), mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size, conv_kernel, chunk_size, num_key_value_heads, head_dim, n_routed_experts (the router's width), num_experts_per_tok, moe_intermediate_size, moe_shared_expert_intermediate_size, routed_scaling_factor, norm_eps. lfm2_moe: layer_types (conv or full_attention for each of numLayers), num_dense_layers, conv_L_cache, intermediate_size, num_key_value_heads, rope_parameters, num_experts (the router's width), num_experts_per_tok, moe_intermediate_size, use_expert_bias, routed_scaling_factor, norm_eps. held_experts lists the expert ids this chip holds; expert_bias_std seeds an untrained expert_bias (dl/hybrid.py). hiddenSize, numLayers, numHeads, vocabSize and maxTokenLen apply as they do to the encoder
 #' @param batchSize Training batch size
 #' @param checkpoint Local HuggingFace Flax checkpoint dir (optional)
 #' @param hiddenSize Hidden width

@@ -1,29 +1,55 @@
-"""Hybrid text backbone: one pre-norm residual stack whose mixers come from a
-layer-type list.
+"""Hybrid text backbone: one pre-norm residual stack whose sublayers come from
+a layer-type list.
 
 ``h = Embed(ids)``; for every character of ``HybridArch.pattern``:
-``h = h + Mixer(RMSNorm(h))``; a last RMSNorm, the mean over each row's
-non-PAD positions and a linear head. Three mixers (the ``nemotron_h`` family's):
+``h = h + Sublayer(RMSNorm(h))``; a last RMSNorm, the mean over each row's
+non-PAD positions and a linear head. The ``nemotron_h`` family's layers are
+one sublayer each, from its ``hybrid_override_pattern``:
 
 ``M``  Mamba-2: ``in_proj`` to gate ``z``, ``xBC`` and ``dt``; a causal
        depth-wise convolution and SiLU over ``xBC``; per head the recurrence
        ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t +
        D x_t`` computed by chunks (``ssd_chunked``); a gated group RMSNorm;
        ``out_proj``.
-``*``  causal grouped-query attention, no positional embedding, by blocks of
-       queries so that no S x S tensor is kept (``causal_attention``).
+``*``  causal grouped-query attention by blocks of queries, so that no S x S
+       tensor is kept (``causal_attention``). Without positions in
+       ``nemotron_h``; with ``HybridArch.qk_norm`` each head's q and k pass
+       through an RMSNorm over ``head_dim``, and with ``HybridArch.rope_theta``
+       through the rotary embedding over all of ``head_dim`` (``rotary``).
 ``E``  sparse experts: a sigmoid router over all the published experts picks
        ``top_k`` a token; the layer is told which experts it holds
        (``HybridArch.held``), computes the pairs whose expert it holds by
-       grouped products (``held_experts_part``) and adds the shared expert.
-       What absent experts would have added is left out: on one chip there is
-       no exchange. No pair is dropped at any load. A PAD position is no
-       token: it is not routed (its routed part is nought; nothing reads it
-       after the last word of a right-padded row).
+       grouped products (``held_experts_part``) and adds the shared expert
+       where it has one (``shared_width`` > 0). What absent experts would
+       have added is left out: on one chip there is no exchange. No pair is
+       dropped at any load. A PAD position is no token: it is not routed
+       (its routed part is nought; nothing reads it after the last word of a
+       right-padded row). An expert is ``relu(x W_up)^2 W_down``, or, with
+       ``HybridArch.gated_experts``, ``(silu(x W_1) * x W_3) W_2`` (SwiGLU,
+       the up-projection the fused ``[W_1 | W_3]``).
 
-Every block is recomputed in the backward pass (``nn.remat``). Parameters are
-float32; products run in ``dtype``; the router, the decay's cumulative sums,
-the scan's state, the softmax and the norms' statistics are float32.
+The ``lfm2_moe`` family's layers are two sublayers each, from its
+``layer_types`` and ``num_dense_layers``: the operator, then the FFN.
+
+``C``  gated short convolution: ``[B; C; x~] = x W_in`` (hidden -> 3 x
+       hidden, no bias); ``u = B * x~``; ``v_t = sum_{j<K} w_j * u_{t-K+1+j}``,
+       a causal depth-wise convolution of kernel ``K = conv_L_cache`` without
+       bias; ``out = (C * v) W_out``. No nonlinearity.
+``*``  as above, with QK-norm and the rotary embedding at ``rope_theta``.
+``F``  dense SwiGLU ``(silu(x W_1) * x W_3) W_2`` at ``intermediate_size``, in
+       the first ``num_dense_layers`` layers.
+``E``  as above with SwiGLU experts, no shared expert and a selection bias
+       (``use_expert_bias``): the router's scores ``s = sigmoid(x W_r)`` in
+       float32; the ``top_k`` largest of ``s + b`` are chosen, where ``b`` is
+       the ``expert_bias`` buffer (a parameter that no step changes: its
+       gradient is stopped), and weighted by ``s_i / (sum_chosen s + 1e-6)``
+       times ``routed_scaling_factor``. Selection and weighting read
+       different numbers.
+
+Every sublayer is recomputed in the backward pass (``nn.remat``). Parameters
+are float32; products run in ``dtype``; the router, the decay's cumulative
+sums, the scan's state, the rotary angles, the softmax and the norms'
+statistics are float32.
 
 The stack counts what it computes into the ``counters`` collection (tokens,
 PAD tokens, pairs the held experts computed, tokens of every held expert);
@@ -47,11 +73,12 @@ from jax import lax
 from . import text as _text    # PAD_ID: the tokenizer's, read when traced
 
 ATTENTION_BLOCK = 512
+SEGMENT_LOADS = 2       # even loads of the held experts a grouped segment holds
 
 
 @dataclasses.dataclass(frozen=True)
 class HybridArch:
-    pattern: str
+    pattern: str            # one character a sublayer (module docstring)
     hidden: int
     vocab: int
     heads: int
@@ -61,34 +88,44 @@ class HybridArch:
     mamba_head_dim: int
     groups: int
     state: int
-    conv: int
+    conv: int               # the depth-wise convolution's kernel (M and C)
     chunk: int
     experts: int            # the router's width: every published expert
     top_k: int
     expert_width: int
-    shared_width: int
+    shared_width: int       # 0: no shared expert
     scaling: float
     held: Tuple[int, ...]   # ids of the experts this chip holds
     eps: float
     dt_min: float = 0.001
     dt_max: float = 0.1
     dt_floor: float = 1e-4
+    dense_width: int = 0    # F's SwiGLU width
+    rope_theta: float = 0.0  # 0: no positions in attention
+    qk_norm: bool = False
+    gated_experts: bool = False   # SwiGLU experts (relu^2 otherwise)
+    expert_bias: bool = False     # selection by s + b, weights from s
+    expert_bias_std: float = 0.0  # the untrained buffer's seeded draw
+    route_eps: float = 1e-20      # added to the chosen scores' sum
 
     @classmethod
     def from_source(cls, src: dict, *, hidden: int, layers: int, heads: int,
                     vocab: int) -> "HybridArch":
-        """From the source's own ``config.json`` keys; ``held_experts`` (ids)
-        is this framework's, default every expert."""
+        """From the source's own ``config.json`` keys: ``nemotron_h``'s
+        ``hybrid_override_pattern`` or ``lfm2_moe``'s ``layer_types``.
+        ``held_experts`` (ids) is this framework's, default every expert;
+        so is ``expert_bias_std``, the scale of the normal draw that seeds
+        an untrained ``expert_bias`` (default 0: zeros, as the family
+        initialises it)."""
+        if "layer_types" in src:
+            return cls._from_lfm2(src, hidden=hidden, layers=layers,
+                                  heads=heads, vocab=vocab)
         pattern = str(src["hybrid_override_pattern"])
         if len(pattern) != layers or set(pattern) - set("ME*"):
             raise ValueError(
                 f"hybrid_override_pattern {pattern!r} must have numLayers="
                 f"{layers} characters of 'M', 'E', '*'")
         experts = int(src.get("n_routed_experts", 0))
-        held = tuple(int(e) for e in src.get("held_experts", range(experts)))
-        if any(not 0 <= e < experts for e in held) or len(set(held)) != len(held):
-            raise ValueError(f"held_experts {held} are not distinct ids "
-                             f"below n_routed_experts={experts}")
         if int(src.get("n_group", 1)) != 1 or int(src.get("topk_group", 1)) != 1:
             raise NotImplementedError("grouped routing (n_group > 1)")
         return cls(
@@ -105,10 +142,54 @@ class HybridArch:
             expert_width=int(src.get("moe_intermediate_size", 0)),
             shared_width=int(src.get("moe_shared_expert_intermediate_size", 0)),
             scaling=float(src.get("routed_scaling_factor", 1.0)),
-            held=held, eps=float(src.get("norm_eps", 1e-5)),
+            held=_held(src, experts), eps=float(src.get("norm_eps", 1e-5)),
             dt_min=float(src.get("time_step_min", 0.001)),
             dt_max=float(src.get("time_step_max", 0.1)),
             dt_floor=float(src.get("time_step_floor", 1e-4)))
+
+    @classmethod
+    def _from_lfm2(cls, src: dict, *, hidden: int, layers: int, heads: int,
+                   vocab: int) -> "HybridArch":
+        """``lfm2_moe``: layer ``i`` is its operator (``C`` for ``conv``,
+        ``*`` for ``full_attention``) then its FFN (``F`` in the first
+        ``num_dense_layers``, ``E`` after)."""
+        types = list(src["layer_types"])
+        ops = {"conv": "C", "full_attention": "*"}
+        if len(types) != layers or set(types) - set(ops):
+            raise ValueError(
+                f"layer_types {types} must have numLayers={layers} entries "
+                "of 'conv', 'full_attention'")
+        rope = dict(src.get("rope_parameters")
+                    or {"rope_theta": src.get("rope_theta", 1e6)})
+        if rope.get("rope_type", "default") != "default" or float(
+                src.get("partial_rotary_factor", 1.0)) != 1.0:
+            raise NotImplementedError("a rotary embedding other than the "
+                                      "default over all of head_dim")
+        if src.get("conv_bias", False):
+            raise NotImplementedError("conv_bias")
+        if not src.get("norm_topk_prob", True):
+            raise NotImplementedError("norm_topk_prob false")
+        dense = int(src.get("num_dense_layers", 0))
+        experts = int(src.get("num_experts", 0))
+        return cls(
+            pattern="".join(ops[t] + ("F" if i < dense else "E")
+                            for i, t in enumerate(types)),
+            hidden=hidden, vocab=vocab, heads=heads,
+            kv_heads=int(src.get("num_key_value_heads", heads)),
+            head_dim=int(src.get("head_dim", hidden // heads)),
+            mamba_heads=0, mamba_head_dim=0, groups=1, state=0,
+            conv=int(src.get("conv_L_cache", 3)), chunk=1,
+            experts=experts, top_k=int(src.get("num_experts_per_tok", 0)),
+            expert_width=int(src.get("moe_intermediate_size", 0)),
+            shared_width=0,
+            scaling=float(src.get("routed_scaling_factor", 1.0)),
+            held=_held(src, experts), eps=float(src.get("norm_eps", 1e-5)),
+            dense_width=int(src.get("intermediate_size", 0)),
+            rope_theta=float(rope["rope_theta"]), qk_norm=True,
+            gated_experts=True,
+            expert_bias=bool(src.get("use_expert_bias", False)),
+            expert_bias_std=float(src.get("expert_bias_std", 0.0)),
+            route_eps=1e-6)
 
     @property
     def d_inner(self) -> int:
@@ -117,6 +198,14 @@ class HybridArch:
     @property
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.groups * self.state
+
+
+def _held(src: dict, experts: int) -> Tuple[int, ...]:
+    held = tuple(int(e) for e in src.get("held_experts", range(experts)))
+    if any(not 0 <= e < experts for e in held) or len(set(held)) != len(held):
+        raise ValueError(f"held_experts {held} are not distinct ids below "
+                         f"the router's {experts} experts")
+    return held
 
 
 def rms_norm(x, weight, eps: float):
@@ -183,12 +272,14 @@ def ssd_chunked(x, dt, a, b, c, chunk: int):
     return y.reshape(bsz, nc * chunk, heads, p)[:, :length]
 
 
-def _causal_depthwise_conv(x, kernel, bias):
-    """x (B, S, C); kernel (K, C): y_t = sum_j kernel[j] x_{t-K+1+j} + bias."""
+def _causal_depthwise_conv(x, kernel, bias=None):
+    """x (B, S, C); kernel (K, C): y_t = sum_j kernel[j] x_{t-K+1+j} (+
+    bias)."""
     k = kernel.shape[0]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     length = x.shape[1]
-    return sum(xp[:, j:j + length] * kernel[j] for j in range(k)) + bias
+    y = sum(xp[:, j:j + length] * kernel[j] for j in range(k))
+    return y if bias is None else y + bias
 
 
 def mamba2_mixer(p: dict, x, arch: HybridArch):
@@ -213,6 +304,17 @@ def mamba2_mixer(p: dict, x, arch: HybridArch):
     yg = yg * lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + arch.eps)
     y = (yg.reshape(bsz, length, arch.d_inner) * p["gate_norm"]).astype(dtype)
     return y @ p["out_proj"].astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# C: the gated short convolution
+# ---------------------------------------------------------------------------
+
+def short_conv_mixer(p: dict, x, arch: HybridArch):
+    dtype = x.dtype
+    b, c, xs = jnp.split(x @ p["in_proj"].astype(dtype), 3, -1)
+    v = _causal_depthwise_conv(b * xs, p["conv_kernel"].astype(dtype))
+    return (c * v) @ p["out_proj"].astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -243,6 +345,22 @@ def causal_attention(q, k, v, block: int = ATTENTION_BLOCK):
          for lo in range(0, length, block)], axis=1)
 
 
+def rotary(x, theta: float):
+    """The rotary embedding over the whole last axis, positions along axis
+    1: ``x * cos + rotate_half(x) * sin`` at angles ``t / theta^(2i / D)``,
+    in float32."""
+    length, d = x.shape[1], x.shape[-1]
+    f32 = jnp.float32
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=f32) / d)
+    angle = jnp.arange(length, dtype=f32)[:, None] * inv[None, :]
+    angle = jnp.concatenate([angle, angle], -1).reshape(
+        (1, length) + (1,) * (x.ndim - 3) + (d,))
+    x32 = x.astype(f32)
+    lo, hi = jnp.split(x32, 2, -1)
+    turned = jnp.concatenate([-hi, lo], -1)
+    return (x32 * jnp.cos(angle) + turned * jnp.sin(angle)).astype(x.dtype)
+
+
 def attention_mixer(p: dict, x, arch: HybridArch):
     dtype = x.dtype
     bsz, length, _ = x.shape
@@ -250,6 +368,11 @@ def attention_mixer(p: dict, x, arch: HybridArch):
     q = (x @ p["q"].astype(dtype)).reshape(bsz, length, kv, arch.heads // kv, hd)
     k = (x @ p["k"].astype(dtype)).reshape(bsz, length, kv, hd)
     v = (x @ p["v"].astype(dtype)).reshape(bsz, length, kv, hd)
+    if arch.qk_norm:
+        q = rms_norm(q, p["q_norm"], arch.eps)
+        k = rms_norm(k, p["k_norm"], arch.eps)
+    if arch.rope_theta:
+        q, k = rotary(q, arch.rope_theta), rotary(k, arch.rope_theta)
     out = causal_attention(q, k, v, min(ATTENTION_BLOCK, length))
     return out.reshape(bsz, length, arch.heads * hd) @ p["o"].astype(dtype)
 
@@ -258,40 +381,59 @@ def attention_mixer(p: dict, x, arch: HybridArch):
 # E: experts
 # ---------------------------------------------------------------------------
 
-def route(x, router, arch: HybridArch):
-    """(ids (T, top_k), weights (T, top_k) float32): sigmoid scores over every
-    published expert in float32, the ``top_k`` largest, their scores divided
-    by their sum and scaled. The family's correction bias is a buffer that a
-    fine-tune leaves at zero; it is left out."""
+def route(x, router, arch: HybridArch, bias=None):
+    """(ids (T, top_k), weights (T, top_k) float32): sigmoid scores ``s``
+    over every published expert in float32; the ``top_k`` largest of ``s``,
+    or of ``s + bias`` where a selection bias is given; the chosen experts'
+    ``s`` divided by their sum (+ ``route_eps``) and scaled. ``nemotron_h``'s
+    correction bias is a buffer that a fine-tune leaves at zero; it is left
+    out there."""
     with jax.named_scope("router"):
         s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
                                    precision=lax.Precision.HIGHEST))
-        top, ids = lax.top_k(s, arch.top_k)
-        return ids, top / (top.sum(-1, keepdims=True) + 1e-20) * arch.scaling
+        if bias is None:
+            top, ids = lax.top_k(s, arch.top_k)
+        else:
+            _, ids = lax.top_k(s + bias, arch.top_k)
+            top = jnp.take_along_axis(s, ids, -1)
+        return ids, top / (top.sum(-1, keepdims=True)
+                           + arch.route_eps) * arch.scaling
 
 
-def _expert(x, up, down):
-    h = jnp.square(jax.nn.relu(x @ up))
-    return h.astype(x.dtype) @ down
+def _activate(h, gated: bool):
+    """SwiGLU's ``silu(h_1) * h_3`` over the fused ``[W_1 | W_3]``
+    up-projection where ``gated``; else ``relu(h)^2``."""
+    if gated:
+        gate, value = jnp.split(h, 2, -1)
+        return jax.nn.silu(gate) * value
+    return jnp.square(jax.nn.relu(h))
 
 
-def _segment(xs, up, down, wt, sizes, valid):
+def _expert(x, up, down, gated: bool = False):
+    return _activate(x @ up, gated).astype(x.dtype) @ down
+
+
+def _segment(xs, up, down, wt, sizes, valid, gated: bool):
     """One segment of sorted pairs: rows of one expert lie together, ``sizes``
-    of them for each held expert; ``valid`` marks the rows that are pairs."""
+    of them for each held expert; ``valid`` marks the rows that are pairs.
+    The rows past the last pair are in no group: the grouped products leave
+    them out, and what they hold there is never read."""
     f32 = jnp.float32
     xs = jnp.where(valid[:, None], xs, 0)
     h = lax.ragged_dot(xs, up, sizes, preferred_element_type=f32)
-    h = jnp.square(jax.nn.relu(h)).astype(xs.dtype)
+    h = _activate(h, gated).astype(xs.dtype)
     ys = lax.ragged_dot(h, down, sizes, preferred_element_type=f32)
-    return jnp.where(valid[:, None], ys * wt[:, None], 0.0)
+    return jnp.where(valid[:, None], ys, 0.0) * wt[:, None]
 
 
 def segment_rows(pairs: int, held: int, experts: int) -> int:
-    """Rows of sorted pairs that one grouped product takes: the held experts'
-    even load, ``pairs * held / experts`` rounded up to eight rows. A segment
-    costs the same however full it is, so a step costs the same at every load
-    up to the even one; the load beyond it runs further segments."""
-    even = -(-pairs * held // experts)
+    """Rows of sorted pairs that one grouped product takes: ``SEGMENT_LOADS``
+    times the held experts' even load, ``2 * pairs * held / experts``
+    rounded up to eight rows, at most ``pairs``. Its rows past the last pair
+    are in no group, so the grouped products skip them: a step whose router
+    sends the held experts up to twice their even share runs one segment,
+    whatever the share; the load beyond it runs further segments."""
+    even = -(-pairs * held * SEGMENT_LOADS // experts)
     return min(pairs, -(-even // 8) * 8)
 
 
@@ -301,17 +443,15 @@ def _segments(starts, counts, total, rows: int, index):
     sizes = (jnp.clip(starts + counts - lo, 0, rows)
              - jnp.clip(starts - lo, 0, rows)).astype(jnp.int32)
     valid = lo + jnp.arange(rows) < total
-    # the rows past the load go with the last group: zeros in, zeros out
-    sizes = sizes.at[-1].add(rows - sizes.sum())
     return lo, sizes, valid, lo < total
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _grouped(rows, x, up, down, wt, tok, starts, counts):
-    return _grouped_fwd(rows, x, up, down, wt, tok, starts, counts)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _grouped(rows, gated, x, up, down, wt, tok, starts, counts):
+    return _grouped_fwd(rows, gated, x, up, down, wt, tok, starts, counts)[0]
 
 
-def _grouped_fwd(rows, x, up, down, wt, tok, starts, counts):
+def _grouped_fwd(rows, gated, x, up, down, wt, tok, starts, counts):
     total = counts.sum()
 
     def body(acc, i):
@@ -320,7 +460,8 @@ def _grouped_fwd(rows, x, up, down, wt, tok, starts, counts):
         def run(acc):
             t = lax.dynamic_slice(tok, (lo,), (rows,))
             w = lax.dynamic_slice(wt, (lo,), (rows,))
-            return acc.at[t].add(_segment(x[t], up, down, w, sizes, valid))
+            return acc.at[t].add(_segment(x[t], up, down, w, sizes, valid,
+                                          gated))
 
         return lax.cond(runs, run, lambda a: a, acc), None
 
@@ -329,7 +470,7 @@ def _grouped_fwd(rows, x, up, down, wt, tok, starts, counts):
     return acc, (x, up, down, wt, tok, starts, counts)
 
 
-def _grouped_bwd(rows, res, g):
+def _grouped_bwd(rows, gated, res, g):
     x, up, down, wt, tok, starts, counts = res
     total = counts.sum()
     f32 = jnp.float32
@@ -342,7 +483,8 @@ def _grouped_bwd(rows, res, g):
             t = lax.dynamic_slice(tok, (lo,), (rows,))
             w = lax.dynamic_slice(wt, (lo,), (rows,))
             _, vjp = jax.vjp(
-                lambda xs, up, down, w: _segment(xs, up, down, w, sizes, valid),
+                lambda xs, up, down, w: _segment(xs, up, down, w, sizes, valid,
+                                                 gated),
                 x[t], up, down, w)
             dxs, du, dd, dw = vjp(g[t])
             return (dx.at[t].add(dxs.astype(f32)), dup + du.astype(f32),
@@ -369,12 +511,15 @@ def held_experts_part(x, ids, weights, up, down, held, experts: int):
     (len(held),) int32.
 
     The pairs are sorted by expert, so that every held expert's rows lie
-    together, and taken a segment (``segment_rows``: the even load of
+    together, and taken a segment (``segment_rows``: two even loads of
     ``len(held)`` of ``experts`` experts) at a time through grouped products
     (``lax.ragged_dot``). Every segment that holds a pair runs, so no pair
     is ever dropped; the segments past the last pair are skipped. x (T,
     hidden); ids, weights (T, top_k), an id of ``experts`` or more is no
-    pair; up (len(held), hidden, width); down (len(held), width, hidden)."""
+    pair; down (len(held), width, hidden); up (len(held), hidden, width) for
+    relu^2 experts, or (len(held), hidden, 2 x width), the fused ``[W_1 |
+    W_3]``, for SwiGLU experts: the layout of ``up`` says which."""
+    gated = up.shape[-1] != down.shape[-2]
     tokens, top_k = ids.shape
     n_held = len(held)
     slot = np.full(int(max(held)) + 2, n_held, np.int32)
@@ -387,7 +532,8 @@ def held_experts_part(x, ids, weights, up, down, held, experts: int):
     pad = (-tokens * top_k) % rows
     tok = jnp.pad((order // top_k).astype(jnp.int32), (0, pad))
     wt = jnp.pad(weights.reshape(-1)[order], (0, pad))
-    return _grouped(rows, x, up, down, wt, tok, starts, counts), counts
+    return (_grouped(rows, gated, x, up, down, wt, tok, starts, counts),
+            counts)
 
 
 def experts_mixer(p: dict, x, arch: HybridArch, real=None):
@@ -396,12 +542,18 @@ def experts_mixer(p: dict, x, arch: HybridArch, real=None):
     dtype = x.dtype
     bsz, length, hidden = x.shape
     flat = x.reshape(bsz * length, hidden)
-    ids, weights = route(flat, p["router"], arch)
+    if arch.expert_bias:
+        ids, weights = route(flat, p["router"], arch,
+                             lax.stop_gradient(p["expert_bias"]))
+    else:
+        ids, weights = route(flat, p["router"], arch)
     if real is not None:
         ids = jnp.where(real.reshape(-1, 1), ids, arch.experts)
     routed, counts = held_experts_part(
         flat, ids, weights, p["experts_up"].astype(dtype),
         p["experts_down"].astype(dtype), arch.held, arch.experts)
+    if not arch.shared_width:
+        return routed.astype(dtype).reshape(bsz, length, hidden), counts
     shared = _expert(flat, p["shared_up"].astype(dtype),
                      p["shared_down"].astype(dtype))
     out = routed.astype(dtype) + shared
@@ -434,9 +586,9 @@ def _uniform(bound: float):
 
 
 class HybridLayer(nn.Module):
-    """``h + Mixer(RMSNorm(h))`` for one character of the pattern; also the
-    tokens each held expert saw (zeros where the mixer has no experts).
-    ``real`` (B, S) marks the positions that hold a token."""
+    """``h + Sublayer(RMSNorm(h))`` for one character of the pattern; also
+    the tokens each held expert saw (zeros where the sublayer has no
+    experts). ``real`` (B, S) marks the positions that hold a token."""
 
     kind: str
     arch: HybridArch
@@ -466,21 +618,41 @@ class HybridLayer(nn.Module):
                 "out_proj": (dense, (a.d_inner, a.hidden))})
             with jax.named_scope("mamba2"):
                 out = mamba2_mixer(p, y, a)
+        elif self.kind == "C":
+            p = params({
+                "in_proj": (dense, (a.hidden, 3 * a.hidden)),
+                "conv_kernel": (_uniform(a.conv ** -0.5), (a.conv, a.hidden)),
+                "out_proj": (dense, (a.hidden, a.hidden))})
+            with jax.named_scope("short_conv"):
+                out = short_conv_mixer(p, y, a)
         elif self.kind == "*":
+            norms = {"q_norm": (ones, (a.head_dim,)),
+                     "k_norm": (ones, (a.head_dim,))} if a.qk_norm else {}
             p = params({
                 "q": (dense, (a.hidden, a.heads * a.head_dim)),
                 "k": (dense, (a.hidden, a.kv_heads * a.head_dim)),
                 "v": (dense, (a.hidden, a.kv_heads * a.head_dim)),
-                "o": (dense, (a.heads * a.head_dim, a.hidden))})
+                "o": (dense, (a.heads * a.head_dim, a.hidden)), **norms})
             with jax.named_scope("attention"):
                 out = attention_mixer(p, y, a)
+        elif self.kind == "F":
+            p = params({"up": (dense, (a.hidden, 2 * a.dense_width)),
+                        "down": (dense, (a.dense_width, a.hidden))})
+            with jax.named_scope("swiglu_ffn"):
+                out = _expert(y, p["up"].astype(y.dtype),
+                              p["down"].astype(y.dtype), gated=True)
         else:
+            up = (2 if a.gated_experts else 1) * a.expert_width
+            bias = {"expert_bias": (nn.initializers.normal(a.expert_bias_std),
+                                    (a.experts,))} if a.expert_bias else {}
+            shared = {"shared_up": (dense, (a.hidden, a.shared_width)),
+                      "shared_down": (dense, (a.shared_width, a.hidden))
+                      } if a.shared_width else {}
             p = params({
-                "router": (dense, (a.hidden, a.experts)),
-                "experts_up": (dense, (len(a.held), a.hidden, a.expert_width)),
+                "router": (dense, (a.hidden, a.experts)), **bias,
+                "experts_up": (dense, (len(a.held), a.hidden, up)),
                 "experts_down": (dense, (len(a.held), a.expert_width, a.hidden)),
-                "shared_up": (dense, (a.hidden, a.shared_width)),
-                "shared_down": (dense, (a.shared_width, a.hidden))})
+                **shared})
             with jax.named_scope("experts"):
                 out, counts = experts_mixer(p, y, a, real)
         return h + out, counts

@@ -121,16 +121,22 @@ class DeepTextClassifier(Estimator, HasLabelCol, HasPredictionCol):
         "/ ring / ulysses", str, "auto")
     architecture = Param(
         "architecture", "A layer-type-list backbone in place of the dense "
-        "encoder, by the source's own config.json keys: "
+        "encoder, by the source's own config.json keys. nemotron_h: "
         "hybrid_override_pattern (one of M Mamba-2, E experts, * causal "
         "attention for each of numLayers), mamba_num_heads, mamba_head_dim, "
         "n_groups, ssm_state_size, conv_kernel, chunk_size, "
         "num_key_value_heads, head_dim, n_routed_experts (the router's "
         "width), num_experts_per_tok, moe_intermediate_size, "
         "moe_shared_expert_intermediate_size, routed_scaling_factor, "
-        "norm_eps; held_experts lists the expert ids this chip holds "
-        "(dl/hybrid.py). hiddenSize, numLayers, numHeads, vocabSize and "
-        "maxTokenLen apply as they do to the encoder", dict)
+        "norm_eps. lfm2_moe: layer_types (conv or full_attention for each "
+        "of numLayers), num_dense_layers, conv_L_cache, intermediate_size, "
+        "num_key_value_heads, rope_parameters, num_experts (the router's "
+        "width), num_experts_per_tok, moe_intermediate_size, "
+        "use_expert_bias, routed_scaling_factor, norm_eps. held_experts "
+        "lists the expert ids this chip holds; expert_bias_std seeds an "
+        "untrained expert_bias (dl/hybrid.py). hiddenSize, numLayers, "
+        "numHeads, vocabSize and maxTokenLen apply as they do to the "
+        "encoder", dict)
     stepFn = Param(
         "stepFn", "Step hook: fn(step_idx, loss, params, batch_stats, "
         "opt_state) after every accepted training step, device arrays as "

@@ -390,8 +390,8 @@ def test_a_pad_position_is_not_routed(small, stack):
 
 
 @pytest.mark.parametrize("pairs, held, experts, rows", [
-    (16384 * 6, 8, 128, 6144),      # the even load of a sixteenth
-    (333, 4, 16, 88),               # 83.25 pairs, to eight rows
+    (16384 * 6, 8, 128, 12288),     # two even loads of a sixteenth
+    (333, 4, 16, 168),              # 166.5 pairs, to eight rows
     (96, 16, 16, 96),               # every expert held: one segment
     (5, 1, 128, 5),                 # never more rows than pairs
 ])
